@@ -31,7 +31,7 @@ import json
 import tempfile
 import time
 
-import common  # noqa: F401  (pins JAX_PLATFORMS=cpu before jax loads)
+import common  # noqa: F401  (compile cache)
 import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager

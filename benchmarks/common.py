@@ -4,23 +4,20 @@ Scaled so every figure reproduces its paper counterpart's *shape* in
 seconds, not hours: RMAT keeps (a=0.45,b=0.25,c=0.15); BA supplies the
 WK/LJ-style heavy in-degree tail; ER is the low-skew control.
 
-Importing this module (before jax) pins JAX_PLATFORMS=cpu when no
-accelerator chips are visible: with libtpu installed but no TPU attached,
-backend autodetect stalls ~5 min on unreachable TPU metadata (the PR 1
-subprocess-test fix, applied to the benchmark entrypoints).  A visible
-TPU (/dev/accel*) or GPU (/dev/nvidia*) leaves the choice to jax.
+Importing this module points JAX's persistent compilation cache at its
+home (``repro.compile_cache``).  It never chooses a platform: JAX uses
+the accelerator when there is one, and CPU runs set
+``JAX_PLATFORMS=cpu`` themselves.
 """
 from __future__ import annotations
 
-import glob
 import os
 import time
 
-if "JAX_PLATFORMS" not in os.environ \
-        and not glob.glob("/dev/accel*") and not glob.glob("/dev/nvidia*"):
-    os.environ["JAX_PLATFORMS"] = "cpu"
-
+from repro.compile_cache import enable_compile_cache
 from repro.graph import generators
+
+enable_compile_cache()
 
 DATASETS = {
     "E14": lambda: generators.erdos_renyi(1 << 14, avg_degree=9.0, seed=1),

@@ -2,7 +2,7 @@
 
 Runs BFS, SSSP, and delta-PageRank on a fixed-seed RMAT partition with
 the flight recorder installed and compares the *exact* per-run totals —
-rounds, messages, pruned deliveries, live grid cells, DMA bytes — against
+rounds, messages, pruned deliveries, live grid cells — against
 the committed baselines in ``benchmarks/baselines/counter_gate.json``.
 Any drift in message counts or planner-mirror grid accounting (the
 numbers PRs 4–7 assert equal to the kernels' ``with_debug`` counters)
@@ -24,7 +24,7 @@ import json
 import pathlib
 import sys
 
-import common  # noqa: F401  (pins JAX_PLATFORMS=cpu before jax loads)
+import common  # noqa: F401  (compile cache)
 import numpy as np
 
 from repro import obs
@@ -50,8 +50,6 @@ def _totals(rounds, run):
         "pruned": sum(r.pruned for r in rs),
         "cells": sum(r.cells for r in rs),
         "launched": sum(r.launched for r in rs),
-        "tile_dmas": sum(r.tile_dmas for r in rs),
-        "dma_bytes": sum(r.dma_bytes for r in rs),
         "shard_messages": [sum(col) for col in zip(
             *(r.shard_messages for r in rs))] if rs else [],
     }

@@ -1,35 +1,29 @@
-"""Engine hot-path benchmark: fused vs unfused relax phase (ISSUE 1),
-the VMEM-tiled fused path (ISSUE 4), and the sparsity-proportional
-worklist launches + delta-PageRank (ISSUE 5).
+"""Engine hot-path benchmark: fused vs unfused relax phase (ISSUE 1)
+and the sparsity-proportional worklist launches + delta-PageRank
+(ISSUE 5).
 
 Runs BFS / SSSP / PageRank / delta-PageRank on a skewed RMAT graph
 through the stacked engine:
 
 * ``fused``    — the frontier-aware relax+reduce Pallas kernel, dense
-  (num_sblk, num_chunks) grid with per-cell early exit, value table
-  pinned in VMEM;
-* ``tiled``    — the same kernel with the VMEM budget forced below the
-  slot table (HBM-tiled double-buffered DMA, per-CHUNK tile lists);
+  (num_sblk, num_chunks) grid with per-cell early exit;
 * ``worklist`` — the 1-D live-cell worklist launch (host-planned each
   round from the frontier): late sparse rounds launch a handful of
   padded cells instead of the full grid;
-* ``wl_tiled`` — worklist × tiled: per-CELL dst-range-filtered tile
-  lists with j-major 2-slot reuse — the DMA bytes drop below the
-  per-chunk baseline (``dst_filter_dma_reduction``);
 * ``unfused``  — the pre-fusion composition (``pallas_mode='reduce'``);
 * ``jnp``      — no Pallas at all, the oracle.
 
 Every worklist round ALSO launches the kernel once with ``with_debug``
-and asserts the kernel-side [executed cells, issued DMAs] counters equal
-the host planner mirror EXACTLY — the provably-exact accounting bar.
+and asserts the kernel-side executed-cell counter equals the host
+planner mirror EXACTLY — the provably-exact accounting bar.
 
 ``pagerank_delta`` runs the push-based residual rounds at the same
 round count as dense PageRank: the frontier shrinks as residuals decay,
-so messages, grid cells, and DMA bytes all drop round over round — the
+so messages and grid cells drop round over round — the
 first time the frontier machinery fires for the sum semiring.
 
 Emits ``BENCH_engine.json`` (rounds, wall/round, messages/s, exact grid
-cells, tiled-vs-pinned and worklist-vs-dense columns) for the perf
+cells, worklist-vs-dense columns) for the perf
 trajectory.
 
 Usage:  PYTHONPATH=src python benchmarks/engine_bench.py [--out PATH]
@@ -41,7 +35,7 @@ import argparse
 import json
 import time
 
-import common  # pins JAX_PLATFORMS=cpu before jax loads; --seed helper
+import common  # --seed / --obs-out helpers, compile cache
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -50,16 +44,16 @@ from common import disp_delta, disp_snap
 from repro.core import actions, engine
 from repro.core.partition import PartitionConfig, build_partition
 from repro.graph import generators
+from repro.kernels import ops as kops
 from repro.kernels.fused_relax_reduce import (
-    _wl_pad_len, fused_grid_cells, fused_relax_reduce_pallas,
-    select_kernel_path,
+    fused_grid_cells, fused_relax_reduce_pallas,
 )
 
 
-def _debug_check(part, sem, gval, gchg, total, worklist, vblk, cells):
+def _debug_check(part, sem, gval, gchg, total, worklist, cells):
     """Launch the fused kernel once more with ``with_debug`` on the exact
-    per-round inputs and assert the kernel-side executed-cell / DMA
-    counters equal the host mirror — exercised by the CI smoke leg."""
+    per-round inputs and assert the kernel-side executed-cell counter
+    equals the host mirror — exercised by the CI smoke leg."""
     args = (gval, jnp.asarray(gchg),
             jnp.asarray(part.edge_src_root_flat.reshape(-1)),
             jnp.asarray(part.edge_w.reshape(-1), jnp.float32),
@@ -68,26 +62,22 @@ def _debug_check(part, sem, gval, gchg, total, worklist, vblk, cells):
     if worklist is not None:
         _, dbg = fused_relax_reduce_pallas(
             *args, total, sem.relax_kind, sem.segment, worklist=worklist,
-            with_debug=True)
+            interpret=kops._interpret(), with_debug=True)
         assert int(dbg[0]) == cells["wl_cells"], (int(dbg[0]), cells)
-        want_dmas = cells["wl_tile_dmas"] if worklist.path == "tiled" else 0
-        assert int(dbg[1]) == want_dmas, (int(dbg[1]), cells)
     else:
         _, dbg = fused_relax_reduce_pallas(
             *args, total, sem.relax_kind, sem.segment,
-            path="tiled" if vblk else "pinned", vblk=vblk, with_debug=True)
+            interpret=kops._interpret(), with_debug=True)
         assert int(dbg[0]) == cells["fused_live"], (int(dbg[0]), cells)
-        if vblk:
-            assert int(dbg[1]) == cells["fused_tile_dmas"]
 
 
 def bench_rounds(sem, part, sources, cfg, max_rounds, fixed_rounds=None,
-                 repeats=5, damping=0.85, vblk=None, delta_tol=None,
+                 repeats=5, damping=0.85, delta_tol=None,
                  check_debug=False):
     """Drive the stacked engine round-by-round (jitted round fn — the
     exact round the shipped runners execute), timing each round
     (best-of-``repeats``, the round fn is pure), mirroring the grid-cell
-    / DMA counts from the frontier, and — for worklist variants —
+    counts from the frontier, and — for worklist variants —
     planning each round's live-cell launch exactly as the host-driven
     runners do."""
     arrays = engine.DeviceArrays.from_partition(part)
@@ -95,12 +85,6 @@ def bench_rounds(sem, part, sources, cfg, max_rounds, fixed_rounds=None,
     total = S * R_max
     planner = engine.launch_planner(part, cfg) if cfg.wants_worklist \
         else None
-    # the mirror must follow the planner's ACTUAL residency — the
-    # REPRO_VMEM_BUDGET env var can tip a nominally-pinned variant onto
-    # the tiled path (that composition is exactly what the CI smoke leg
-    # exercises)
-    if planner is not None and planner.path == "tiled" and vblk is None:
-        vblk = planner.vblk
 
     if delta_tol is not None:      # delta-PageRank residual rounds
         tol_j = jnp.asarray(delta_tol, jnp.float32)
@@ -181,14 +165,13 @@ def bench_rounds(sem, part, sources, cfg, max_rounds, fixed_rounds=None,
         gchg = chg_h.reshape(-1)
         cells = fused_grid_cells(
             part.edge_dst_flat, part.edge_mask, part.edge_src_root_flat,
-            gchg, total, vblk=vblk,
-            grid_mode="worklist" if planner else "dense")
+            gchg, total, grid_mode="worklist" if planner else "dense")
         wl = (engine.plan_round_worklist(planner, cfg, gchg)
               if planner else None)
         if check_debug and cfg.use_pallas and cfg.pallas_mode == "fused" \
                 and cfg.exchange == "dense":
             gval_f, gchg_f = relax_inputs(state)
-            _debug_check(part, sem, gval_f, gchg_f, total, wl, vblk, cells)
+            _debug_check(part, sem, gval_f, gchg_f, total, wl, cells)
         dt = np.inf
         for _ in range(repeats):
             t0 = time.perf_counter()
@@ -207,15 +190,9 @@ def bench_rounds(sem, part, sources, cfg, max_rounds, fixed_rounds=None,
             "grid_total_fused": cells["total_fused"],
             "grid_total_unfused": cells["total_unfused"],
         }
-        if vblk is not None:
-            row["grid_tile_dmas"] = cells["fused_tile_dmas"]
-            row["dma_bytes"] = cells["dma_bytes"]
         if planner is not None:
             row["grid_wl_cells"] = cells["wl_cells"]
             row["grid_wl_launched"] = cells["wl_launched"]
-            if vblk is not None:
-                row["wl_tile_dmas"] = cells["wl_tile_dmas"]
-                row["wl_dma_bytes"] = cells["wl_dma_bytes"]
         rounds.append(row)
     return rounds
 
@@ -234,12 +211,6 @@ def summarize(rounds, cell_key):
         "grid_cells_executed": executed,
         "per_round": rounds,
     }
-    if rounds and "dma_bytes" in rounds[0]:
-        out["tile_dmas_total"] = sum(r["grid_tile_dmas"] for r in rounds)
-        out["dma_bytes_total"] = sum(r["dma_bytes"] for r in rounds)
-    if rounds and "wl_dma_bytes" in rounds[0]:
-        out["wl_tile_dmas_total"] = sum(r["wl_tile_dmas"] for r in rounds)
-        out["wl_dma_bytes_total"] = sum(r["wl_dma_bytes"] for r in rounds)
     if rounds and "grid_wl_cells" in rounds[0]:
         out["wl_cells_total"] = sum(r["grid_wl_cells"] for r in rounds)
         out["wl_launched_total"] = sum(r["grid_wl_launched"]
@@ -249,8 +220,8 @@ def summarize(rounds, cell_key):
 
 def _device_debug_check(part, sem, gval, gchg, total):
     """Launch the fused kernel once in ``grid_mode='device_worklist'``
-    with ``with_debug`` and assert the kernel-side executed-cell / DMA
-    counters equal the host mirror for the device-compacted launch —
+    with ``with_debug`` and assert the kernel-side executed-cell counter
+    equals the host mirror for the device-compacted launch —
     the CI device-worklist smoke leg's assertion."""
     cells = fused_grid_cells(
         part.edge_dst_flat, part.edge_mask, part.edge_src_root_flat,
@@ -262,9 +233,9 @@ def _device_debug_check(part, sem, gval, gchg, total):
         jnp.asarray(part.edge_mask.reshape(-1)),
         jnp.asarray(part.edge_dst_flat.reshape(-1)),
         total, sem.relax_kind, sem.segment,
-        grid_mode="device_worklist", with_debug=True)
+        grid_mode="device_worklist", interpret=kops._interpret(),
+        with_debug=True)
     assert int(dbg[0]) == cells["wl_cells"], (int(dbg[0]), cells)
-    assert int(dbg[1]) == cells["wl_tile_dmas"], (int(dbg[1]), cells)
 
 
 def bench_device_fixpoint(name, sem, part, sources, max_rounds,
@@ -298,7 +269,7 @@ def bench_device_fixpoint(name, sem, part, sources, max_rounds,
     rounds = int(stats.iterations)
     planner = engine.launch_planner(
         part, engine.EngineConfig(use_pallas=True, grid_mode="worklist"))
-    l_pad = _wl_pad_len(planner.total_cells)
+    l_pad = engine._device_l_pad(planner)
     return {
         "rounds": rounds,
         "wall_s_total": wall,
@@ -358,10 +329,7 @@ def main():
             "only). Dense PageRank diffuses every round (predicate #t), "
             "so only the delta-PageRank rounds shrink the sum-semiring "
             "frontier — compare the pagerank_delta rows' messages/cells "
-            "against pagerank at the same round count. wl_tiled's "
-            "per-cell dst-filtered tile lists + j-major reuse cut "
-            "dma_bytes below tiled's per-chunk baseline "
-            "(dst_filter_dma_reduction). dispatches_total / "
+            "against pagerank at the same round count. dispatches_total / "
             "host_syncs_per_round are obs-registry deltas: host-driven "
             "variants pay one dispatch+sync per round; the "
             "device_worklist row runs the WHOLE fixpoint as one traced "
@@ -385,28 +353,14 @@ def main():
     ]
     for name, sem, p, sources, fixed, dtol in jobs:
         entry = {}
-        # budget a quarter of the padded slot table's bytes — always below
-        # the table, so the fused launch takes the tiled path at any
-        # --scale (an absolute floor would fall back to pinned on small
-        # partitions and silently bench the wrong kernel)
         slots = p.S * p.R_max
-        v_pad = -(-slots // 128) * 128
-        budget = v_pad * 4 // 4
-        path, vblk = select_kernel_path(slots, 1, budget)
-        assert path == "tiled", (slots, budget)
-        entry["kernel_budget"] = {"vmem_budget_bytes": budget,
-                                  "vblk": vblk, "slots": slots}
         variants = [
             ("fused", engine.EngineConfig(use_pallas=True),
-             "grid_fused_live", None),
+             "grid_fused_live"),
             ("unfused",
              engine.EngineConfig(use_pallas=True, pallas_mode="reduce"),
-             "grid_range_live", None),
-            ("jnp", engine.EngineConfig(use_pallas=False), None, None),
-            ("tiled",
-             engine.EngineConfig(use_pallas=True,
-                                 vmem_budget_bytes=budget),
-             "grid_fused_live", vblk),
+             "grid_range_live"),
+            ("jnp", engine.EngineConfig(use_pallas=False), None),
         ]
         if args.grid_mode != "dense":
             # the per-round host-planned variants need a host planner —
@@ -419,20 +373,14 @@ def main():
                 ("worklist",
                  engine.EngineConfig(use_pallas=True,
                                      grid_mode=host_mode),
-                 "grid_wl_cells", None),
-                ("wl_tiled",
-                 engine.EngineConfig(use_pallas=True,
-                                     grid_mode=host_mode,
-                                     vmem_budget_bytes=budget),
-                 "grid_wl_cells", vblk),
+                 "grid_wl_cells"),
             ]
-        for label, cfg, cell_key, use_vblk in variants:
+        for label, cfg, cell_key in variants:
             snap = disp_snap()
             rounds = bench_rounds(
                 sem, p, sources, cfg, args.max_rounds, fixed_rounds=fixed,
-                repeats=args.repeats, vblk=use_vblk, delta_tol=dtol,
-                check_debug=label.startswith(("worklist", "wl_", "fused",
-                                              "tiled")))
+                repeats=args.repeats, delta_tol=dtol,
+                check_debug=label in ("worklist", "fused"))
             dd, ds = disp_delta(snap)
             entry[label] = summarize(rounds, cell_key)
             entry[label]["dispatches_total"] = int(dd)
@@ -465,31 +413,8 @@ def main():
                   f"wall/round={dw['wall_s_per_round']*1e3:8.2f}ms "
                   f"msgs/s={dw['messages_per_s']:.3e} "
                   f"dispatches={dw['dispatches_total']}")
-        f, u, t = entry["fused"], entry["unfused"], entry["tiled"]
-        entry["tiled_vs_pinned"] = {
-            "wall_s_per_round_tiled": t["wall_s_per_round"],
-            "wall_s_per_round_pinned": f["wall_s_per_round"],
-            "wall_ratio": t["wall_s_per_round"]
-            / max(f["wall_s_per_round"], 1e-12),
-            "grid_cells_tiled": t["grid_cells_executed"],
-            "grid_cells_pinned": f["grid_cells_executed"],
-            "tile_dmas_total": t.get("tile_dmas_total", 0),
-            "dma_bytes_total": t.get("dma_bytes_total", 0),
-        }
-        if "wl_tiled" in entry:
-            wt = entry["wl_tiled"]
-            # ISSUE-5 acceptance: per-cell dst-range filtering + reuse
-            # strictly <= (and on multi-SBLK partitions <) the per-chunk
-            # tile lists' DMA bytes, at identical round structure
-            entry["dst_filter_dma_reduction"] = {
-                "dma_bytes_per_chunk_lists": t.get("dma_bytes_total", 0),
-                "dma_bytes_per_cell_filtered":
-                    wt.get("wl_dma_bytes_total", 0),
-                "reduction": 1.0 - wt.get("wl_dma_bytes_total", 0)
-                / max(t.get("dma_bytes_total", 1), 1),
-            }
-            assert wt.get("wl_dma_bytes_total", 0) \
-                <= t.get("dma_bytes_total", 0)
+        f, u = entry["fused"], entry["unfused"]
+        if "worklist" in entry:
             wl = entry["worklist"]
             entry["worklist_vs_dense"] = {
                 "cells_launched_worklist": wl["wl_launched_total"],

@@ -31,7 +31,7 @@ import argparse
 import json
 import time
 
-import common  # pins JAX_PLATFORMS=cpu before jax loads; --seed helper
+import common  # compile cache; --seed helper
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -30,7 +30,7 @@ import argparse
 import json
 import time
 
-import common  # pins JAX_PLATFORMS=cpu before jax loads; --seed helper
+import common  # compile cache; --seed helper
 import numpy as np
 
 from repro.apps.pagerank import _pr_graph

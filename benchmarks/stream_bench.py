@@ -27,7 +27,7 @@ import argparse
 import json
 import time
 
-import common  # noqa: F401  (pins JAX_PLATFORMS=cpu before jax loads)
+import common  # noqa: F401  (compile cache)
 import numpy as np
 
 from repro import obs

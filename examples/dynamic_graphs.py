@@ -9,6 +9,9 @@ import numpy as np
 from repro.core.dynamic import DynamicGraph
 from repro.core.partition import PartitionConfig
 from repro.graph import generators, reference
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 g = generators.rmat(12, edge_factor=8, seed=3)
 root = int(np.argmax(g.out_degrees()))

@@ -13,6 +13,9 @@ from repro.apps import bfs
 from repro.core.costmodel import CostModel
 from repro.core.partition import PartitionConfig, build_partition
 from repro.graph import generators, reference
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--scale", type=int, default=13)

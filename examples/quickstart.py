@@ -13,6 +13,9 @@ from repro.apps import bfs, pagerank, sssp
 from repro.core.partition import PartitionConfig, build_partition
 from repro.graph import generators, reference
 from repro.graph.graph import degree_stats
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # 1. a highly skewed graph (RMAT, same generator family as the paper's R18)
 g = generators.rmat(12, edge_factor=16, seed=0).with_random_weights(seed=0)
@@ -152,7 +155,7 @@ print(f"worklist + delta-PageRank ok: BFS bit-identical under sparse "
 # 7. the flight recorder (ISSUE 7): install a recorder, re-run the BFS
 # fixpoint and a small QueryServer burst under it, and render the run
 # summary.  Recording is off by default and costs nothing when off; on,
-# every round's grid-cell / DMA columns are the same planner mirror the
+# every round's grid-cell columns are the same planner mirror the
 # differential tests assert against the kernel's debug counters.
 from repro import obs
 from repro.obs import report

@@ -12,6 +12,9 @@ import numpy as np
 
 from repro.lm.configs import get_config
 from repro.lm.models.model import Model
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 for arch in ("phi3-medium-14b", "granite-moe-1b-a400m", "xlstm-125m"):
     cfg = get_config(arch).reduced()

@@ -14,6 +14,9 @@ from repro.data.pipeline import TokenPipeline
 from repro.lm.models.model import Model
 from repro.lm.train.optimizer import AdamW, cosine_schedule
 from repro.lm.train.trainer import Trainer, TrainerConfig
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=120)

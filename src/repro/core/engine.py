@@ -27,10 +27,10 @@ Two execution paths share the same per-round math:
     (the paper assumes a hardware idle signal; the collective is ours).
 
 With ``EngineConfig.use_pallas`` the per-round relax phase dispatches
-through the fused ``kernels.fused_relax_reduce`` Pallas kernel: one
-VMEM-resident pass, no ``(S, E_max)`` HBM intermediates, and grid cells
-over frontier-dead edge chunks are skipped entirely (the TPU form of the
-paper's diffusion pruning).  Without the flag the same math runs as
+through the fused ``kernels.fused_relax_reduce`` Pallas kernel: the
+gather/relax/mask fusion feeds a blocked segment reduction whose grid
+cells over frontier-dead edge chunks are skipped entirely (the TPU form
+of the paper's diffusion pruning).  Without the flag the same math runs as
 separate jnp ops — the oracle path.
 
 Per-round counters reproduce the paper's Fig-6 statistics: messages
@@ -60,8 +60,8 @@ class EngineConfig:
     exchange: str = "dense"      # 'dense' | 'compact' (targeted messages)
     max_iters: int = 4096
     use_pallas: bool = False     # route the relax phase through Pallas
-    # 'fused'  — one VMEM-resident gather+relax+mask+reduce kernel with
-    #            frontier chunk skip (the hot path; default)
+    # 'fused'  — gather+relax+mask fusion feeding the blocked reduce
+    #            kernel with frontier chunk skip (the hot path; default)
     # 'reduce' — jnp gather/relax/mask + the standalone segment-reduce
     #            kernel (the pre-fusion composition, kept for comparison)
     pallas_mode: str = "fused"
@@ -82,7 +82,7 @@ class EngineConfig:
     #              dense grid drops below WORKLIST_AUTO_THRESHOLD
     # 'device_worklist' — the live-cell list is compacted ON DEVICE
     #              (cumsum-scatter over the frontier chunk bitmap) and
-    #              launched over the pow2-padded full grid with masked
+    #              launched over a static pow2-padded list with masked
     #              tail cells.  Fully traced — composes with
     #              jit/shard_map, so whole fixpoints run through
     #              lax.while_loop with zero host syncs (ISSUE 8)
@@ -93,9 +93,9 @@ class EngineConfig:
     # trajectory per window instead of per round.
     device_window: int = 8
     # SMEM byte budget for the fused kernel's scalar-prefetch tables
-    # (chunk ranges, tile lists, worklist cells).  None disables the
-    # guard; set to the real-TPU SMEM size to make select_kernel_path
-    # warn and widen vblk before a ~100k-chunk launch would overflow.
+    # (chunk ranges, worklist cells).  None disables the guard; set to
+    # the real-TPU SMEM size to make launches warn before a ~100k-chunk
+    # table would overflow.
     smem_budget_bytes: int | None = None
     # Checkpoint cadence for the resilient driver (core.resilient): a
     # crc-verified snapshot of value/frontier state + accounting every K
@@ -103,15 +103,6 @@ class EngineConfig:
     # as before — run_stacked never checkpoints; only the resilient
     # driver reads this knob, so the obs-off path stays trace-identical).
     checkpoint_every: int | None = None
-    # VMEM byte budget for the fused kernel's value-table residency: the
-    # kernel pins the whole padded (S*R_max[, Q]) slot table in VMEM when
-    # it fits the budget, else tiles it out of HBM with per-cell
-    # double-buffered async DMA (see kernels.fused_relax_reduce.
-    # select_kernel_path).  None defers to the REPRO_VMEM_BUDGET env var,
-    # then to DEFAULT_VMEM_BUDGET_BYTES — so paper-scale partitions whose
-    # slot table exceeds VMEM run fused via tiling instead of failing to
-    # compile.
-    vmem_budget_bytes: int | None = None
 
     def __post_init__(self):
         if self.collapse not in ("eager", "deferred"):
@@ -120,10 +111,6 @@ class EngineConfig:
             raise ValueError(f"exchange={self.exchange!r}")
         if self.pallas_mode not in ("fused", "reduce"):
             raise ValueError(f"pallas_mode={self.pallas_mode!r}")
-        if self.vmem_budget_bytes is not None \
-                and self.vmem_budget_bytes <= 0:
-            raise ValueError(
-                f"vmem_budget_bytes={self.vmem_budget_bytes!r}")
         if self.grid_mode not in ("dense", "worklist", "auto",
                                   "device_worklist"):
             raise ValueError(f"grid_mode={self.grid_mode!r}")
@@ -237,18 +224,14 @@ def _pagerank_round_stacked(sem, arrays, cfg, S, R_max, base, damping, val,
 WORKLIST_AUTO_THRESHOLD = 0.25
 
 
-def launch_planner(part: Partition, cfg: EngineConfig, q_pad: int = 1):
+def launch_planner(part: Partition, cfg: EngineConfig):
     """Host-side ``WorklistPlanner`` for the stacked fused launch under
     ``cfg`` — the planner must mirror the exact launch ``relax`` builds:
     dense exchange flattens ``edge_dst_flat`` over ``S*R_max`` segments;
     compact exchange offsets ``edge_dst_compact`` into per-source-shard
-    id windows over ``S*S*P_t``.  ``q_pad`` is the lane-PADDED width of
-    laned launches (sizes the residency choice and the DMA byte mirror).
-    """
-    from repro.kernels.fused_relax_reduce import (
-        EBLK, WorklistPlanner, select_kernel_path, _round_up)
+    id windows over ``S*S*P_t``."""
+    from repro.kernels.fused_relax_reduce import WorklistPlanner
     S, R_max = part.S, part.R_max
-    num_slots = S * R_max
     if cfg.exchange == "compact":
         P_t = part.P_t
         offs = (np.arange(S, dtype=np.int64) * (S * P_t))[:, None]
@@ -257,14 +240,9 @@ def launch_planner(part: Partition, cfg: EngineConfig, q_pad: int = 1):
     else:
         ids = np.asarray(part.edge_dst_flat)
         num_segments = S * R_max
-    n_chunks = _round_up(ids.size, EBLK) // EBLK
-    path, vblk = select_kernel_path(
-        num_slots, q_pad, cfg.vmem_budget_bytes, n_chunks=n_chunks,
-        smem_budget_bytes=cfg.smem_budget_bytes)
     return WorklistPlanner(
         ids, np.asarray(part.edge_mask), np.asarray(part.edge_src_root_flat),
-        num_segments, num_slots=num_slots, path=path, vblk=vblk,
-        lane_width=q_pad, smem_budget_bytes=cfg.smem_budget_bytes)
+        num_segments, smem_budget_bytes=cfg.smem_budget_bytes)
 
 
 def plan_round_worklist(planner, cfg: EngineConfig, gchg,
@@ -285,34 +263,31 @@ def plan_round_worklist(planner, cfg: EngineConfig, gchg,
 
 def _obs_record_round(rec, run, part, cfg, planner, rnd, gchg, frontier,
                       mc, work, wl, info, wall_s):
-    """Build + store one flight-recorder ``RoundRecord``: the grid-cell /
-    DMA columns come from the planner mirror of the launch this round
+    """Build + store one flight-recorder ``RoundRecord``: the grid-cell
+    columns come from the planner mirror of the launch this round
     actually made (WorklistInfo for worklist launches, the dense-grid
     mirror otherwise), plus the per-shard message-volume mirror feeding
     the skew gauge.  Only ever called with a recorder installed — the
     obs-off hot path never reaches here."""
     grid = "dense" if wl is None else "worklist"
+    path = cfg.pallas_mode if cfg.use_pallas else "jnp"
     if planner is not None and cfg.use_pallas \
             and cfg.pallas_mode == "fused":
-        path = planner.path
         if wl is not None:
             cells, launched = info.cells, info.launched
-            tile_dmas, dma_bytes = info.tile_dmas, info.dma_bytes
         else:
             d = planner.dense_mirror(gchg)
             cells, launched = d["cells"], d["launched"]
-            tile_dmas, dma_bytes = d["tile_dmas"], d["dma_bytes"]
     else:
-        path = cfg.pallas_mode if cfg.use_pallas else "jnp"
-        cells = launched = tile_dmas = dma_bytes = 0
+        cells = launched = 0
     shard = exchange.shard_message_mirror(
         part.edge_mask, part.edge_src_root_flat, gchg)
     rec.add_round(
         obs.RoundRecord(
             run=run, round=rnd, frontier=frontier, messages=mc, work=work,
             pruned=mc - min(work, mc), grid=grid, path=path, cells=cells,
-            launched=launched, tile_dmas=tile_dmas, dma_bytes=dma_bytes,
-            wall_s=wall_s, shard_messages=[int(x) for x in shard]),
+            launched=launched, wall_s=wall_s,
+            shard_messages=[int(x) for x in shard]),
         frontier_bitmap=gchg.copy() if rec.keep_frontiers else None)
 
 
@@ -430,7 +405,7 @@ def _run_stacked_hostloop(sem, part, arrays, cfg, init_val, init_changed):
 
     Also the flight-recorder path for ``grid_mode='dense'``: with a
     recorder installed each round appends a ``RoundRecord`` (frontier,
-    messages, planner-mirror cells/DMA, path decision, wall time) —
+    messages, planner-mirror cells, path decision, wall time) —
     recorder-only host work, after the round's existing frontier
     download."""
     S, R_max = part.S, part.R_max
@@ -496,12 +471,12 @@ def _record_device_window(rec, run, part, planner, l_pad, window, it_end,
     semiring (absorbing identity) — they ran on device but count as
     zero rounds, matching the host loop's early exit.  Appends ONE
     per-window ``RoundRecord`` (``window`` field set; per-round cells /
-    DMA / shard mirrors summed over the window's live rounds, so window
+    shard mirrors summed over the window's live rounds, so window
     sums equal the per-round host-driven totals) and returns
     (live_rounds, messages, work, pruned)."""
     k = counts_h.shape[0]
     live_rounds = msgs = work = pruned = 0
-    cells = tile_dmas = dma_bytes = 0
+    cells = 0
     shard_sum = None
     for r in range(k):
         if not ent[r].any():
@@ -514,8 +489,6 @@ def _record_device_window(rec, run, part, planner, l_pad, window, it_end,
         pruned += mc - min(wk, mc)
         d = planner.dense_mirror(ent[r])
         cells += d["cells"]
-        tile_dmas += d["tile_dmas"]
-        dma_bytes += d["dma_bytes"]
         sh = np.asarray(exchange.shard_message_mirror(
             part.edge_mask, part.edge_src_root_flat, ent[r]))
         shard_sum = sh if shard_sum is None else shard_sum + sh
@@ -524,14 +497,21 @@ def _record_device_window(rec, run, part, planner, l_pad, window, it_end,
             obs.RoundRecord(
                 run=run, round=it_end, frontier=int(ent[0].sum()),
                 messages=msgs, work=work, pruned=pruned,
-                grid="device_worklist", path=planner.path, cells=cells,
-                launched=l_pad * live_rounds, tile_dmas=tile_dmas,
-                dma_bytes=dma_bytes, wall_s=wall,
+                grid="device_worklist", path="fused", cells=cells,
+                launched=l_pad * live_rounds, wall_s=wall,
                 shard_messages=([int(x) for x in shard_sum]
                                 if shard_sum is not None else None),
                 window=window),
             frontier_bitmap=ent[0].copy() if rec.keep_frontiers else None)
     return live_rounds, msgs, work, pruned
+
+
+def _device_l_pad(planner) -> int:
+    """Launch length of the stacked device_worklist launch the planner
+    mirrors (one flat edge set)."""
+    from repro.kernels.fused_relax_reduce import EBLK, device_worklist_pad
+    return device_worklist_pad(planner.n_chunks * EBLK,
+                               planner.num_segments)
 
 
 def _run_stacked_deviceloop(sem, part, arrays, cfg, init_val, init_changed):
@@ -546,8 +526,7 @@ def _run_stacked_deviceloop(sem, part, arrays, cfg, init_val, init_changed):
     S, R_max = part.S, part.R_max
     rec = obs.get_recorder()
     planner = launch_planner(part, cfg)
-    from repro.kernels.fused_relax_reduce import _wl_pad_len
-    l_pad = _wl_pad_len(planner.total_cells)
+    l_pad = _device_l_pad(planner)
 
     window_fns: dict = {}
 
@@ -613,10 +592,16 @@ def run_pagerank_stacked(part: Partition, damping: float, iters: int,
     # initial score 1/n on every replica (consistent view)
     val0 = jnp.where(arrays.slot_valid, 1.0 / part.n, 0.0)
     chg = arrays.slot_valid  # PR predicate is #t — always diffuse
+    # the frontier never changes, so a host-planned worklist is planned
+    # once and reused by every traced round
+    wl = (plan_round_worklist(launch_planner(part, cfg), cfg,
+                              np.asarray(chg).reshape(-1))
+          if cfg.wants_worklist else None)
 
     def body(_, val):
         new_val, _ = exchange.pagerank_round_stacked(
-            sem, arrays, cfg, S, R_max, base, damping, val, chg)
+            sem, arrays, cfg, S, R_max, base, damping, val, chg,
+            worklist=wl)
         return new_val
 
     val = lax.fori_loop(0, iters, body, val0)
@@ -767,8 +752,7 @@ def _run_pagerank_delta_deviceloop(sem, part, arrays, cfg, damping, tol_t,
                                  int(pruned))
 
     planner = launch_planner(part, cfg)
-    from repro.kernels.fused_relax_reduce import _wl_pad_len
-    l_pad = _wl_pad_len(planner.total_cells)
+    l_pad = _device_l_pad(planner)
 
     window_fns: dict = {}
 
@@ -822,7 +806,6 @@ def make_sharded_pagerank_delta_fn(S: int, R_max: int, damping: float,
     cfg = _sharded_cfg(cfg, "make_sharded_pagerank_delta_fn")
     axis_names = exchange.axis_tuple(axis_names)
     spec = P(axis_names)
-    from jax.experimental.shard_map import shard_map
 
     in_specs = (DeviceArrays.specs(spec), spec, spec)
 
@@ -836,9 +819,9 @@ def make_sharded_pagerank_delta_fn(S: int, R_max: int, damping: float,
         work = lax.psum(new_chg.sum(), axis_names)
         return new_rank[None], new_delta[None], counts[None], work[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=in_specs,
-        out_specs=(spec, spec, spec, spec), check_rep=False,
+        out_specs=(spec, spec, spec, spec), check_vma=False,
     )
     return jax.jit(fn), NamedSharding(mesh, spec)
 
@@ -866,14 +849,7 @@ def run_pagerank_delta_sharded(part: Partition, damping: float = 0.85,
     delta = jax.device_put(jnp.asarray(init_delta, jnp.float32), sharding)
     it = msgs = work_total = pruned = 0
     rec = obs.get_recorder()
-    rec_path = "jnp"
-    if rec is not None and cfg.use_pallas and cfg.pallas_mode == "fused":
-        from repro.kernels.fused_relax_reduce import select_kernel_path
-        rec_path, _ = select_kernel_path(
-            part.S * part.R_max, 1, cfg.vmem_budget_bytes,
-            smem_budget_bytes=cfg.smem_budget_bytes)
-    elif cfg.use_pallas:
-        rec_path = cfg.pallas_mode
+    rec_path = cfg.pallas_mode if cfg.use_pallas else "jnp"
     # the round's psum'd live-slot count IS the next round's frontier
     # size — only the initial frontier needs a host check
     live = bool(((np.abs(np.asarray(delta)) > tol) & slot_valid).any())
@@ -905,8 +881,7 @@ def run_pagerank_delta_sharded(part: Partition, damping: float = 0.85,
                     run="pagerank_delta_sharded", round=it,
                     frontier=frontier, messages=mc, work=w,
                     pruned=mc - min(w, mc), grid="dense", path=rec_path,
-                    cells=0, launched=0, tile_dmas=0, dma_bytes=0,
-                    wall_s=wall,
+                    cells=0, launched=0, wall_s=wall,
                     shard_messages=[int(x) for x in shard]),
                 frontier_bitmap=gchg.copy() if rec.keep_frontiers
                 else None)
@@ -954,7 +929,6 @@ def make_sharded_fn(sem: Semiring, S: int, R_max: int,
     cfg = _sharded_cfg(cfg, "make_sharded_fn")
     axis_names = exchange.axis_tuple(axis_names)
     spec = P(axis_names)
-    from jax.experimental.shard_map import shard_map
 
     in_specs = (
         DeviceArrays.specs(spec),
@@ -1003,11 +977,11 @@ def make_sharded_fn(sem: Semiring, S: int, R_max: int,
                 arrays_s.sibling_flat, arrays_s.sibling_mask)
         return val[None], jax.tree.map(lambda x: x[None], stats)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=in_specs,
         out_specs=(spec, RunStats(*([spec] * 5))),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn), NamedSharding(mesh, spec)
 
@@ -1040,7 +1014,6 @@ def make_sharded_pagerank_fn(S: int, R_max: int, n: int, damping: float,
     cfg = _sharded_cfg(cfg, "make_sharded_pagerank_fn")
     axis_names = exchange.axis_tuple(axis_names)
     spec = P(axis_names)
-    from jax.experimental.shard_map import shard_map
 
     in_specs = (DeviceArrays.specs(spec),)
     base = (1.0 - damping) / n
@@ -1063,9 +1036,9 @@ def make_sharded_pagerank_fn(S: int, R_max: int, n: int, damping: float,
         val = lax.fori_loop(0, iters, body, val0)
         return val[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=in_specs, out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn), NamedSharding(mesh, spec)
 

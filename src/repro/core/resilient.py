@@ -313,7 +313,6 @@ class LanesTask:
                 worklist=worklist)
 
         self.round_fn = round_fn
-        self.q_pad = lanes_mod._lane_q_pad(self.q)
 
     def init_state(self) -> dict:
         val = self._init_val
@@ -382,7 +381,6 @@ class ShardedTask:
         self.sharding = NamedSharding(mesh, spec)
         S, R_max = part.S, part.R_max
         run_cfg = self.cfg
-        from jax.experimental.shard_map import shard_map
 
         def shard_fn(arrays_l: DeviceArrays, val_l, chg_l):
             arrays_s = jax.tree.map(lambda x: x[0], arrays_l)
@@ -392,10 +390,10 @@ class ShardedTask:
             mc = jax.lax.psum(mc, axis_names)
             return nval[None], nchg[None], mc[None]
 
-        fn = shard_map(
+        fn = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(DeviceArrays.specs(spec), spec, spec),
-            out_specs=(spec, spec, spec), check_rep=False)
+            out_specs=(spec, spec, spec), check_vma=False)
         self._fn = jax.jit(fn)
         arrays = DeviceArrays.from_partition(part)
         self.arrays_dev = jax.tree.map(
@@ -525,8 +523,7 @@ def run_resilient(task, *, chaos: ChaosPlan | None = None,
     report = FixpointReport()
     part = task.part
 
-    planner = (engine.launch_planner(part, cfg,
-                                     q_pad=getattr(task, "q_pad", 1))
+    planner = (engine.launch_planner(part, cfg)
                if (cfg.wants_worklist
                    or (rec is not None and task.records and cfg.use_pallas
                        and cfg.pallas_mode == "fused"))

@@ -76,13 +76,11 @@ def relax(sem: Semiring, cfg, edge_src, edge_w, edge_mask, ids, gval, gchg,
                     "or run with use_pallas=False")
             from repro.kernels import ops as kops
             # the Fig-6 message count rides along for free: it is a
-            # reduction of the same gather that builds the kernel's
-            # frontier chunk bitmap.  The cfg's VMEM budget selects the
-            # value table's residency (pinned vs HBM-tiled DMA).
+            # reduction of the same frontier mask that builds the
+            # kernel's chunk bitmap
             partial, count = kops.fused_relax_reduce(
                 gval, gchg, src, w, mask, idsf, num_segments,
                 relax_kind=sem.relax_kind, kind=sem.segment,
-                vmem_budget_bytes=getattr(cfg, "vmem_budget_bytes", None),
                 worklist=worklist,
                 smem_budget_bytes=getattr(cfg, "smem_budget_bytes", None),
                 grid_mode=grid_mode)
@@ -119,7 +117,6 @@ def relax(sem: Semiring, cfg, edge_src, edge_w, edge_mask, ids, gval, gchg,
         partial, counts = kops.fused_relax_reduce_lanes(
             gval, gchg, unitw, src, w, mask, idsf, num_segments,
             relax_kind=sem.relax_kind, kind=sem.segment,
-            vmem_budget_bytes=getattr(cfg, "vmem_budget_bytes", None),
             worklist=worklist,
             smem_budget_bytes=getattr(cfg, "smem_budget_bytes", None),
             grid_mode=grid_mode)
@@ -213,13 +210,22 @@ def collapse(sem: Semiring, gx, sibling_flat, sibling_mask):
     ``gx``: (V,) or (V, Q) gathered table; sibling tables index the
     leading axis (the lane axis rides along).  Returns the sibling-
     combined table shaped like ``sibling_flat`` (+ Q)."""
-    laned = gx.ndim == 2
-    sib = jnp.take(gx, sibling_flat, axis=0)     # (..., K[, Q])
-    mask = sibling_mask[..., None] if laned else sibling_mask
+    return _sibling_reduce(sem, gx, sibling_flat, sibling_mask)
+
+
+def _sibling_reduce(sem: Semiring, g, sib_idx, sib_mask):
+    """Semiring-combine each slot's K siblings: ``sib_idx``/``sib_mask``
+    are (..., K) tables into the leading axis of ``g`` ((V,) or (V, Q)).
+    The gather runs K-major: a large gather whose minor dim is K (the
+    rhizome fan-out, a handful) takes XLA's TPU compiler minutes at
+    scale-20 sizes, against seconds with K leading."""
+    sib = jnp.take(g, jnp.moveaxis(sib_idx, -1, 0), axis=0)  # (K, ...[, Q])
+    mask = jnp.moveaxis(sib_mask, -1, 0)
+    if g.ndim == 2:
+        mask = mask[..., None]
     sib = jnp.where(mask, sib, _identity(sem, sib.dtype))
-    axis = -2 if laned else -1
-    return (jnp.min(sib, axis=axis) if sem.segment == "min"
-            else jnp.sum(sib, axis=axis))
+    return (jnp.min(sib, axis=0) if sem.segment == "min"
+            else jnp.sum(sib, axis=0))
 
 
 def compact_collapse(sem: Semiring, cand, rz_local, rz_sib_idx, rz_sib_mask,
@@ -244,12 +250,7 @@ def compact_collapse(sem: Semiring, cand, rz_local, rz_sib_idx, rz_sib_mask,
     rz_idx = rz_local[..., None] if laned else rz_local
     compact = jnp.take_along_axis(cand_pad, rz_idx, axis=slot_axis)
     g = gather_fn(compact)                       # (S*R_rz_max[, Q]) flat
-    sib = jnp.take(g, rz_sib_idx, axis=0)        # (..., K[, Q])
-    mask = rz_sib_mask[..., None] if laned else rz_sib_mask
-    sib = jnp.where(mask, sib, _identity(sem, sib.dtype))
-    k_axis = -2 if laned else -1
-    collapsed = (jnp.min(sib, axis=k_axis) if sem.segment == "min"
-                 else jnp.sum(sib, axis=k_axis))
+    collapsed = _sibling_reduce(sem, g, rz_sib_idx, rz_sib_mask)
     idx = tuple(jnp.indices(rz_local.shape)[:-1]) + (rz_local,)
     if sem.segment == "min":
         upd = cand_pad.at[idx].min(collapsed)

@@ -7,12 +7,6 @@ lane-batched ``(V, Q)`` table layouts.  ``core.engine`` and
 ``query.lanes`` are thin drivers over these — the while/fori loop,
 termination collective, and stats bookkeeping live there; the per-round
 math lives here, once.
-
-The ``cfg`` threaded through every composition also carries the fused
-kernel's VMEM budget (``EngineConfig.vmem_budget_bytes``): the relax
-phase pins the value table in VMEM when it fits, else runs the
-HBM-tiled double-buffered-DMA kernel — transparently to every round
-shape here (see ``kernels.fused_relax_reduce.select_kernel_path``).
 """
 from __future__ import annotations
 

@@ -1,26 +1,23 @@
-"""Pallas TPU kernel: fused frontier-aware relax + blocked segment reduce.
+"""Pallas TPU kernel: frontier-aware relax + blocked segment reduce.
 
-One engine round used to run as four separate XLA/Pallas ops, each
-materializing an ``(S, E_max)`` HBM intermediate:
+One engine round is
 
-    src_val = gval[edge_src]                  # dense gather     (HBM f32)
-    active  = edge_mask & gchg[edge_src]      # frontier mask    (HBM bool)
-    msg     = where(active, relax(src_val, w), identity)   #     (HBM f32)
-    inbox   = segment_reduce(msg, edge_dst)   # Pallas kernel
+    active = edge_mask & gchg[edge_src]       # frontier mask
+    msg    = where(active, relax(gval[edge_src], w), identity)
+    inbox  = segment_reduce(msg, edge_dst)
 
-This kernel fuses the whole pipeline into one VMEM-resident pass: the
-gather, semiring relax, frontier masking, and blocked semiring reduction
-all happen inside the grid cell — no per-edge float array ever
-round-trips HBM.  The frontier mask is folded into the value table
-before launch (inactive sources read as the absorbing identity:
-``relax(identity, w) == identity`` for every supported semiring), so the
-cell needs a single VMEM gather.
+The launchers run the first two lines as ONE XLA fusion (frontier
+gather + semiring relax + mask -> the per-edge message array) and the
+kernel runs the reduction with the frontier skips below.  The gather
+stays in XLA on purpose: Mosaic has no general in-kernel dynamic gather,
+and XLA's TPU gather reads the value table straight out of HBM, so the
+table's size never meets a VMEM budget.
 
-Blocking follows ``rhizome_segment_reduce``: the edge axis is tiled into
-``EBLK`` chunks, the segment axis into ``SBLK`` blocks; cell (i, j)
-builds an (EBLK x SBLK) hit mask and reduces over edges (one-hot MXU
-matmul for ``sum``, masked VPU min for ``min``); output block *i* is
-revisited across all *j* and accumulated in place.
+Blocking follows ``rhizome_segment_reduce``: edges ride lane-dense as
+``(E_pad / 128, 128)`` rows cut into ``EBLK``-edge chunks (one
+``(8, 128)`` block), the segment axis into ``SBLK`` blocks; cell (i, j)
+folds the chunk's messages into block *i*'s contribution with VPU
+min / add (``block_contrib``).
 
 Two levels of scalar-prefetched grid-cell skipping (the TPU form of the
 paper's diffusion pruning — work stays proportional to the frontier):
@@ -28,97 +25,53 @@ paper's diffusion pruning — work stays proportional to the frontier):
 1. **Sorted-range skip** — edges are sorted by destination, so chunk *j*
    covers segment ids ``[chunk_lo[j], chunk_hi[j]]``; cells whose segment
    block does not intersect are skipped (static sparsity of the CSR sort).
-2. **Frontier chunk skip** — ``chunk_active[j]`` records whether ANY edge
+2. **Frontier chunk skip** — ``chunk_act[j]`` records whether ANY edge
    in chunk *j* has a changed (diffusing) source this round.  On late
    BFS/SSSP rounds the frontier is a tiny fraction of the graph, so most
    chunks are dead and their grid cells are skipped *entirely* across all
    segment blocks — the paper's "stale diffusions are subsumed" pruning,
-   realized as predicated grid cells.  The bitmap is an O(E/EBLK) scalar
-   vector computed from ``gchg`` by a fused reduction; it is the only
-   per-round edge-proportional traffic besides the kernel's own block DMAs.
+   realized as predicated grid cells.
 
 ``fused_grid_cells`` mirrors the two skip predicates on the host so
-benchmarks/tests can count exactly how many grid cells execute (see
-``benchmarks/engine_bench.py``); with a ``vblk`` it also mirrors the
-tiled path's per-chunk tile counts and DMA issue/byte totals, and the
-kernels' optional ``with_debug`` counters report the *kernel-side*
-executed-cell / issued-DMA totals so the mirror is provably exact
+benchmarks/tests can count exactly how many grid cells execute; the
+kernels' optional ``with_debug`` counter reports the *kernel-side*
+executed-cell total so the mirror is provably exact
 (``tests/test_fused_kernel.py::test_grid_cell_dma_oracle_*``).
 
 Semiring relax is selected statically via ``relax_kind``
 (``Semiring.relax_kind``, single-sourced with the jnp path through
 ``actions.RELAX_FNS``): 'add_w' (min-plus / SSSP), 'add_one' (BFS level
-relax; the weight is ignored), 'mul_w' (plus-times / PageRank).
-Validated against ``ref.fused_relax_reduce_ref`` in interpret mode (CPU);
-the compiled path targets TPU VMEM via BlockSpecs.
+relax; the weight is ignored), 'mul_w' (plus-times / PageRank).  Min
+semirings are bit-identical to ``ref.fused_relax_reduce_ref``; sum
+differs only by float reassociation.
 
-**Scale: budget-based pinned/tiled path selection.**  Two residency
-strategies share the cell math, selected per launch from the slot
-table's footprint against a VMEM budget (``vmem_budget_bytes`` on
-``EngineConfig``, the ``REPRO_VMEM_BUDGET`` env var, or the
-``DEFAULT_VMEM_BUDGET_BYTES`` fallback — see ``select_kernel_path``):
+**Lanes.**  The laned kernels serve Q concurrent queries over one edge
+structure.  Their messages ride transposed, ``(q_pad, E_pad)``, with the
+query axis on the sublanes (``q_pad`` a multiple of ``LANE_PAD``, the
+sublane tile — tail lanes are frontier-dead and masked, so padding never
+changes results); each cell loops over the lanes.
 
-* **pinned** — the whole padded value table rides into VMEM per grid
-  cell (``full_spec``).  Fastest when it fits (one resident copy, zero
-  per-cell DMA), but caps partitions at roughly ``budget / 4`` f32
-  slots (~3M at the 12 MiB default).
-* **tiled**  — the value table stays in HBM (``memory_space=ANY``); the
-  slot axis is cut into ``vblk``-wide tiles and each live grid cell
-  async-copies (``pltpu.make_async_copy``) only the tiles its edge
-  chunk's *frontier-active sources* touch, double-buffered so tile
-  ``t+1``'s DMA overlaps tile ``t``'s relax+reduce.  Per-chunk tile
-  lists ride the scalar prefetch (``chunk_ntiles`` / ``chunk_tiles``),
-  so a sparse frontier pays DMA proportional to the tiles it actually
-  diffuses from — the out-of-core form of the paper's rhizome scaling
-  (slot state larger than any one fast memory).  Tile lists are
-  per-edge-chunk (not per-(i, j) cell): a dst-range filter would shrink
-  DMAs further and is future work.  Note the scalar-prefetch tables are
-  O(E/EBLK) rows (as the pre-existing ``chunk_lo/hi/act`` already were),
-  times ``t_max`` columns for the tile lists — at extreme chunk counts
-  they outgrow real SMEM and belong in an HBM side table (ROADMAP);
-  with the default budget ``t_max`` stays single-digit (vblk is large),
-  so the chunk count, not the tile list, is the binding row dimension.
-
-Both paths are bit-identical for min semirings (sum differs only by
-float reassociation across tile partials).  The laned kernels grow the
-same two paths with the trailing query axis padded to the TPU lane tile
-(``LANE_TILE`` when compiling, a sublane multiple under interpret —
-tail lanes are frontier-dead and masked, so padding never changes
-results; see ``_lane_pad``).
-
-**Sparsity-proportional worklist launches (ISSUE 5).**  The dense grid
-above launches every ``(num_sblk, num_chunks)`` cell and early-exits the
-dead ones — launch cost stays proportional to *total* work even when the
-frontier is four cells wide.  Every kernel variant therefore has a
-**worklist twin**: a host-built (``WorklistPlanner`` / ``plan_worklist``)
-scalar-prefetched list of live ``(i, j)`` cell pairs, launched as a 1-D
-grid over the power-of-two-padded live count.  Each worklist cell writes
-its own ``(SBLK[, Q])`` partial (no out-block revisiting — revisit order
-under a sparse worklist is non-consecutive, which Pallas out pipelining
-does not guarantee), and a host-side scatter-combine folds the partials
-into the inbox; padded cells emit the combine identity, so the scatter
-is exact.  Bit-identical to the dense grid for min semirings; sum
-differs only by scatter reassociation.
-
-The worklist's tiled twin goes further than the per-chunk tile lists
-(the ROADMAP dst-range item): tile lists are built **per cell** — only
-the tiles of frontier-active sources whose edge lands in block *i*'s dst
-range are fetched — and the worklist is ordered j-major so consecutive
-cells sharing an edge chunk reuse tiles still resident in the 2-slot
-VMEM scratch.  The planner simulates exactly the kernel's slot schedule
-(``cell_slot`` / ``cell_fetch``), so the host DMA mirror is exact; a
-cell whose dst-filtered tile list is empty contributes only the identity
-and is dropped from the worklist entirely.
+**Sparsity-proportional worklist launches.**  The dense grid launches
+every ``(num_sblk, num_chunks)`` cell and early-exits the dead ones —
+launch cost stays proportional to *total* work even when the frontier is
+four cells wide.  Every kernel therefore has a **worklist twin**: a
+host-built (``WorklistPlanner`` / ``plan_worklist``) scalar-prefetched
+list of live ``(i, j)`` cell pairs, launched as a 1-D grid over the
+power-of-two-padded live count.  Each worklist cell writes its own
+partial block (no out-block revisiting — revisit order under a sparse
+worklist is non-consecutive, which Pallas out pipelining does not
+guarantee), and a scatter-combine folds the partials into the inbox;
+padded cells emit the combine identity, so the scatter is exact.
+Bit-identical to the dense grid for min semirings; sum differs only by
+scatter reassociation.  The planner's per-cell dst filter drops cells
+whose chunk has no frontier-active edge landing in the cell's block.
 
 Scalar-prefetch tables live in SMEM; ``smem_table_bytes`` prices them
-and ``select_kernel_path`` warns and widens ``vblk`` (shorter tile
-lists) when a configurable ``smem_budget_bytes`` would be exceeded —
-the real-TPU ~100k-chunk regime the ROADMAP flags.
+and launches warn when a configured ``smem_budget_bytes`` is exceeded.
 """
 from __future__ import annotations
 
 import functools
-import os
 import typing
 import warnings
 
@@ -129,37 +82,36 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.actions import RELAX_FNS
+from repro.kernels.rhizome_segment_reduce import (
+    EBLK, LANES, ROWS, SBLK, block_contrib, combine, edge_rows, identity_of,
+)
+from repro.kernels.rhizome_segment_reduce import round_up as _round_up
 
-EBLK = 512   # edge-axis tile
-SBLK = 256   # segment-axis tile (lane-aligned)
-
-LANE_TILE = 128          # TPU lane tile: laned compile pads Q up to this
-INTERPRET_LANE_TILE = 8  # sublane multiple: cheap pad that still exercises
-                         # the masked-tail machinery under interpret-mode CI
-
-DEFAULT_VMEM_BUDGET_BYTES = 12 * 2**20   # ~3/4 of a 16 MiB TPU core VMEM
-VMEM_BUDGET_ENV = "REPRO_VMEM_BUDGET"
+LANE_PAD = 8     # laned launches pad Q to the sublane tile: tail lanes are
+                 # frontier-dead identity rows
 
 WL_PAD = 8      # worklist launches are padded to >= this many cells and
                 # then to a power of two, so jit retraces O(log cells)
                 # times per partition instead of once per distinct count
 
+WL_LAUNCH_CELLS = 32768   # worklist cells per pallas_call: the call's
+                          # wl_i/wl_j prefetch tables (8 bytes a cell) must
+                          # fit SMEM, 1 MiB on a v5e core
+
 RELAX_KINDS = tuple(RELAX_FNS)
 
 # pairings for which the combine identity absorbs under relax —
 # relax(identity, w) == identity — the property the frontier masking
-# relies on (inactive sources are folded into the value table as the
-# identity and must never contribute)
+# relies on (an inactive source must never contribute)
 ABSORBING_PAIRS = frozenset(
     {("add_w", "min"), ("add_one", "min"), ("mul_w", "sum")})
 
-
-def _relax(relax_kind: str, src_val, w):
-    return RELAX_FNS[relax_kind](src_val, w)
-
-
-def _round_up(x: int, m: int) -> int:
-    return -(-max(x, 1) // m) * m
+__all__ = [
+    "EBLK", "SBLK", "LANE_PAD", "WL_PAD", "Worklist", "WorklistInfo",
+    "WorklistPlanner", "build_device_worklist", "device_worklist_pad",
+    "fused_grid_cells", "fused_relax_reduce_lanes_pallas",
+    "fused_relax_reduce_pallas", "plan_worklist", "smem_table_bytes",
+]
 
 
 def _check_pair(relax_kind: str, kind: str):
@@ -171,168 +123,154 @@ def _check_pair(relax_kind: str, kind: str):
             f"(supported: {sorted(ABSORBING_PAIRS)})")
 
 
-# --------------------------------------------------------------------------
-# budget-based pinned/tiled path selection
-# --------------------------------------------------------------------------
-
-def resolve_vmem_budget(vmem_budget_bytes=None) -> int:
-    """The VMEM byte budget the value table must live within: an explicit
-    argument wins, else the ``REPRO_VMEM_BUDGET`` env var (CI forces it
-    tiny to route interpret-mode runs through the tiled path), else
-    ``DEFAULT_VMEM_BUDGET_BYTES``."""
-    if vmem_budget_bytes is not None:
-        return int(vmem_budget_bytes)
-    env = os.environ.get(VMEM_BUDGET_ENV)
-    if env:
-        return int(env)
-    return DEFAULT_VMEM_BUDGET_BYTES
+def _lane_pad(q: int, lane_tile=None) -> int:
+    """Padded lane count: a multiple of ``LANE_PAD`` (the sublane tile the
+    transposed message layout needs) or of an explicit ``lane_tile``."""
+    return _round_up(q, lane_tile or LANE_PAD)
 
 
-def smem_table_bytes(n_chunks: int, t_max: int = 0,
-                     wl_cells: int = 0) -> int:
-    """Byte footprint of the scalar-prefetch tables one fused launch pins
-    in SMEM: the per-chunk ``chunk_lo/hi/act`` rows, plus (tiled) the
-    ``chunk_ntiles``/``chunk_tiles`` tile lists, plus (worklist) the
-    per-cell ``wl_i/wl_j`` pairs, ``nlive``, and — when both — the
-    per-cell ``cell_ntiles``/``cell_tile``/``cell_slot``/``cell_fetch``
-    tables.  All int32.  ``t_max`` is the tile-list width (0 = pinned),
-    ``wl_cells`` the padded worklist length (0 = dense grid)."""
-    rows = 3 * n_chunks                      # chunk_lo + chunk_hi + chunk_act
-    if t_max and not wl_cells:
-        rows += n_chunks * (1 + t_max)       # chunk_ntiles + chunk_tiles
-    if wl_cells:
-        rows += 2 * wl_cells + 1             # wl_i + wl_j + nlive
-        if t_max:
-            rows += wl_cells * (1 + 3 * t_max)   # ntiles + tile/slot/fetch
+def smem_table_bytes(n_chunks: int, wl_cells: int = 0) -> int:
+    """Byte footprint of the scalar-prefetch tables one fused pallas_call
+    pins in SMEM: the dense grid's per-chunk ``chunk_lo/hi/act`` rows, or
+    a worklist call's per-cell ``wl_i/wl_j`` pairs plus ``nlive``
+    (``wl_cells`` is the padded worklist length, launched in slices of at
+    most ``WL_LAUNCH_CELLS``; 0 = dense grid).  All int32."""
+    rows = (2 * min(wl_cells, WL_LAUNCH_CELLS) + 1 if wl_cells
+            else 3 * n_chunks)
     return rows * 4
 
 
-def select_kernel_path(num_slots: int, q_pad: int = 1,
-                       vmem_budget_bytes=None, *, path=None, vblk=None,
-                       n_chunks=None, wl_cells: int = 0,
-                       smem_budget_bytes=None, return_info: bool = False):
-    """Pick the fused kernel's residency strategy for a value table of
-    ``num_slots`` (x ``q_pad`` lanes) f32 slots.
-
-    Returns ``("pinned", None)`` when the whole padded table fits the
-    budget, else ``("tiled", vblk)`` with ``vblk`` the largest 128-multiple
-    slot-tile whose double buffer fits (floored at 128 — the smallest
-    legal tile — even if that overshoots a pathologically small budget).
-    ``path``/``vblk`` force the decision (differential tests pin both
-    sides; benchmarks pin the tile to keep DMA counts comparable).
-
-    With ``n_chunks`` and ``smem_budget_bytes`` the scalar-prefetch table
-    footprint (``smem_table_bytes``; ``wl_cells`` prices a worklist
-    launch on top) joins the decision: a tiled path whose tile lists
-    would overflow the SMEM budget is widened (``vblk`` doubled — fewer,
-    wider tiles shrink ``t_max``) with a warning until the tables fit or
-    one tile covers the table; a still-overflowing chunk count is warned
-    as needing the ROADMAP HBM side table.  ``return_info=True`` appends
-    a dict with the footprint behind the decision.
-    """
-    budget = resolve_vmem_budget(vmem_budget_bytes)
-    v_pad = _round_up(num_slots, 128)
-    if path is None:
-        path = "pinned" if v_pad * q_pad * 4 <= budget else "tiled"
-    if path == "pinned":
-        info = {"path": "pinned", "vblk": None, "smem_table_bytes":
-                smem_table_bytes(n_chunks, 0, wl_cells) if n_chunks else None}
-        if n_chunks is not None and smem_budget_bytes is not None \
-                and info["smem_table_bytes"] > smem_budget_bytes:
-            # pinned launches carry the same chunk_lo/hi/act rows; no
-            # vblk to widen — the overflow needs the ROADMAP HBM side
-            # table, so say so instead of silently compiling
-            warnings.warn(
-                f"fused-kernel scalar-prefetch tables ({n_chunks} chunks"
-                f", wl_cells={wl_cells}) weigh "
-                f"{info['smem_table_bytes']} bytes — over "
-                f"smem_budget_bytes={smem_budget_bytes} on the pinned "
-                "path; the chunk tables belong in an HBM side table "
-                "(ROADMAP)", stacklevel=2)
-        return ("pinned", None, info) if return_info else ("pinned", None)
-    if path != "tiled":
-        raise ValueError(f"unknown kernel path {path!r}")
-    if vblk is None:
-        vblk = max((budget // (2 * q_pad * 4)) // 128 * 128, 128)
-        vblk = min(vblk, v_pad)
-    if vblk % 128 or vblk <= 0:
-        raise ValueError(f"vblk must be a positive multiple of 128; "
-                         f"got {vblk}")
-    vblk = int(vblk)
-    info = {"path": "tiled", "vblk": vblk, "smem_table_bytes": None}
-    if n_chunks is not None and smem_budget_bytes is not None:
-        def footprint(vb):
-            t_max = min(_round_up(num_slots, vb) // vb, EBLK)
-            return smem_table_bytes(n_chunks, t_max, wl_cells)
-        if footprint(vblk) > smem_budget_bytes:
-            vblk0 = vblk
-            while footprint(vblk) > smem_budget_bytes and vblk < v_pad:
-                vblk *= 2    # fewer, wider tiles: halves the t_max rows
-            warnings.warn(
-                f"fused-kernel scalar-prefetch tables ({n_chunks} chunks, "
-                f"wl_cells={wl_cells}) exceed smem_budget_bytes="
-                f"{smem_budget_bytes} at vblk={vblk0}; widened to "
-                f"vblk={vblk} ({footprint(vblk)} table bytes)"
-                + ("" if footprint(vblk) <= smem_budget_bytes else
-                   " — still over budget: the chunk tables themselves "
-                   "outgrow SMEM and belong in an HBM side table "
-                   "(ROADMAP)"),
-                stacklevel=2)
-        info["vblk"] = vblk
-        info["smem_table_bytes"] = footprint(vblk)
-    return ("tiled", vblk, info) if return_info else ("tiled", vblk)
-
-
-def _lane_pad(q: int, interpret: bool, lane_tile=None) -> int:
-    """Padded lane count: up to the 128-lane TPU tile when compiling;
-    under interpret mode a sublane multiple keeps CI cheap while still
-    exercising the masked-tail-lane machinery (the regression tests force
-    ``lane_tile=LANE_TILE`` to prove the full tile)."""
-    tile = lane_tile if lane_tile is not None else (
-        INTERPRET_LANE_TILE if interpret else LANE_TILE)
-    return _round_up(q, tile)
+def _warn_smem(n_chunks: int, wl_cells: int, smem_budget_bytes):
+    if smem_budget_bytes is None:
+        return
+    need = smem_table_bytes(n_chunks, wl_cells)
+    if need > smem_budget_bytes:
+        warnings.warn(
+            f"fused-kernel scalar-prefetch tables ({n_chunks} chunks, "
+            f"wl_cells={wl_cells}) weigh {need} bytes — over "
+            f"smem_budget_bytes={smem_budget_bytes}; the tables belong in "
+            "an HBM side table (ROADMAP)", stacklevel=3)
 
 
 # --------------------------------------------------------------------------
-# kernel bodies — pinned (full table in VMEM per cell)
+# per-edge messages (one XLA fusion) and the scalar-prefetch tables
 # --------------------------------------------------------------------------
 
-def _split_dbg(extras):
-    """Trailing kernel refs: (dbg?, *scratch) -> (dbg | None, scratch)."""
-    if len(extras) % 2:                  # dbg present: odd count
-        return extras[0], extras[1:]
-    return None, extras
+def _edge_messages(gval, gchg, edge_src, edge_w, edge_mask, edge_dst,
+                   relax_kind, kind, e_pad):
+    """Frontier gather + relax + mask, padded to ``e_pad`` edges.  Returns
+    (ids, msg, valid, active) — padding edges carry id 0, the identity
+    message and valid = active = False."""
+    e = edge_src.shape[0]
+    identity = identity_of(kind)
+    active = edge_mask & jnp.take(gchg, edge_src)
+    msg = RELAX_FNS[relax_kind](jnp.take(gval, edge_src), edge_w)
+    msg = jnp.where(active, msg, identity).astype(jnp.float32)
+    pad = (0, e_pad - e)
+    return (jnp.pad(edge_dst.astype(jnp.int32), pad),
+            jnp.pad(msg, pad, constant_values=identity),
+            jnp.pad(edge_mask, pad), jnp.pad(active, pad))
 
 
-def _init_dbg(dbg_ref, i, j):
-    """Zero the [executed cells, issued DMAs] counters at the first cell
-    (the grid is iterated sequentially, row-major)."""
-    if dbg_ref is not None:
-        @pl.when((i == 0) & (j == 0))
-        def _dbg_init():
-            dbg_ref[0] = 0
-            dbg_ref[1] = 0
+def _lane_messages(gval, gchg, lane_unitw, edge_src, edge_w, edge_mask,
+                   edge_dst, relax_kind, kind, e_pad, q_pad):
+    """Laned twin of ``_edge_messages``: (V, Q) tables -> transposed
+    (q_pad, e_pad) messages (tail lanes and padding edges hold the
+    identity), plus ids, validity, the (e_pad,) OR-across-lanes activity
+    and the (Q,) per-lane active-edge counts."""
+    e = edge_src.shape[0]
+    q = gval.shape[1]
+    identity = identity_of(kind)
+    active = edge_mask[None, :] & jnp.take(gchg.T, edge_src, axis=1)
+    sval = jnp.take(gval.T, edge_src, axis=1)                  # (Q, E)
+    if relax_kind == "add_w":
+        unitw = jnp.asarray(lane_unitw, jnp.int32).reshape(q, 1)
+        msg = sval + jnp.where(unitw > 0, jnp.asarray(1.0, edge_w.dtype),
+                               edge_w[None, :])
+    else:                                                      # 'mul_w'
+        msg = sval * edge_w[None, :]
+    msg = jnp.where(active, msg, identity).astype(jnp.float32)
+    pad = (0, e_pad - e)
+    msg = jnp.pad(msg, ((0, q_pad - q), pad), constant_values=identity)
+    counts = active.sum(axis=1, dtype=jnp.int32)
+    return (jnp.pad(edge_dst.astype(jnp.int32), pad), msg,
+            jnp.pad(edge_mask, pad), jnp.pad(active.any(axis=0), pad),
+            counts)
 
 
-def _bump_dbg(dbg_ref, dmas):
-    if dbg_ref is not None:
+def _chunk_tables(ids_p, valid, active):
+    """Scalar-prefetch tables: per-chunk [lo, hi] id range of the valid
+    edges + the any-frontier-active bit."""
+    n_chunks = ids_p.shape[0] // EBLK
+    idc = ids_p.reshape(n_chunks, EBLK)
+    valid = valid.reshape(n_chunks, EBLK)
+    chunk_lo = jnp.where(valid, idc, jnp.iinfo(jnp.int32).max).min(axis=1)
+    chunk_hi = jnp.where(valid, idc, -1).max(axis=1)
+    chunk_act = active.reshape(n_chunks, EBLK).any(axis=1)
+    return chunk_lo, chunk_hi, chunk_act.astype(jnp.int32)
+
+
+# --------------------------------------------------------------------------
+# kernel bodies
+# --------------------------------------------------------------------------
+
+def _bump_dbg(dbg, first):
+    """Executed-cell counter (the ``with_debug`` output): zeroed at the
+    first grid step (the grid runs sequentially), bumped per live cell."""
+    if not dbg:
+        return lambda: None
+    dbg_ref, = dbg
+
+    @pl.when(first)
+    def _zero():
+        dbg_ref[0] = 0
+
+    def bump():
         dbg_ref[0] += 1
-        dbg_ref[1] += dmas
+    return bump
 
 
-def _kernel(chunk_lo_ref, chunk_hi_ref, chunk_act_ref,
-            ids_ref, src_ref, w_ref, mask_ref, gval_ref,
-            out_ref, *extras, relax_kind, kind):
-    dbg_ref, _ = _split_dbg(extras)
+def _cell(ids_ref, msg_ref, out_ref, seg0, kind, laned):
+    """Combine one live cell's contribution into ``out_ref``: (1, SBLK)
+    unlaned; (q_pad, SBLK) laned, one row per query lane."""
+    if not laned:
+        contrib = block_contrib(ids_ref, lambda r: msg_ref[r:r + 1, :],
+                                seg0, kind)
+        out_ref[...] = combine(kind, out_ref[...], contrib)
+        return
+
+    # Mosaic loads and stores sublane-aligned groups only, so lanes go
+    # LANE_PAD at a time: one aligned (LANE_PAD, ...) window per group,
+    # static row picks inside it, and the group's rows reassembled by
+    # sublane select before one aligned store
+    row_id = jax.lax.broadcasted_iota(jnp.int32, (LANE_PAD, SBLK), 0)
+
+    def group(g, carry):
+        rows = pl.ds(pl.multiple_of(g * LANE_PAD, LANE_PAD), LANE_PAD)
+        block = out_ref[rows, :]
+        for k in range(LANE_PAD):
+            contrib = block_contrib(
+                ids_ref,
+                lambda r: msg_ref[rows, pl.ds(r * LANES, LANES)][k:k + 1, :],
+                seg0, kind)
+            block = jnp.where(row_id == k, combine(kind, block, contrib),
+                              block)
+        out_ref[rows, :] = block
+        return carry
+
+    jax.lax.fori_loop(0, out_ref.shape[0] // LANE_PAD, group, 0)
+
+
+def _kernel_dense(chunk_lo_ref, chunk_hi_ref, chunk_act_ref, ids_ref,
+                  msg_ref, out_ref, *dbg, kind, laned):
     i = pl.program_id(0)  # segment block
     j = pl.program_id(1)  # edge chunk
-
-    identity = jnp.inf if kind == "min" else 0.0
-    _init_dbg(dbg_ref, i, j)
+    bump = _bump_dbg(dbg, (i == 0) & (j == 0))
 
     @pl.when(j == 0)
     def _init():
-        out_ref[...] = jnp.full((SBLK,), identity, out_ref.dtype)
+        out_ref[...] = jnp.full(out_ref.shape, identity_of(kind),
+                                out_ref.dtype)
 
     seg0 = i * SBLK
     # level 1: sorted-edges range skip — chunk j covers [chunk_lo, chunk_hi]
@@ -342,517 +280,137 @@ def _kernel(chunk_lo_ref, chunk_hi_ref, chunk_act_ref,
 
     @pl.when(live)
     def _compute():
-        src = src_ref[...]                       # (EBLK,) int32
-        # fused frontier gather: the VMEM-resident value table is
-        # pre-masked so frontier-inactive sources read as the absorbing
-        # identity — relax(identity, w) == identity for every semiring
-        # here (inf+w=inf, 0*w=0), so no per-edge gchg gather is needed
-        src_val = jnp.take(gval_ref[...], src)
-        msg = _relax(relax_kind, src_val, w_ref[...])
-        msg = jnp.where(mask_ref[...] > 0, msg,
-                        jnp.asarray(identity, msg.dtype))
-
-        _seg_accumulate(out_ref, msg, ids_ref[...] - seg0, kind, identity)
-        _bump_dbg(dbg_ref, 0)        # pinned: no manual value-tile DMAs
+        _cell(ids_ref, msg_ref, out_ref, seg0, kind, laned)
+        bump()
 
 
-def _seg_contrib(msg, local, kind, identity, dtype):
-    """(SBLK,) block contribution of (EBLK,) messages (one grid cell)."""
-    cols = jax.lax.broadcasted_iota(jnp.int32, (EBLK, SBLK), 1)
-    hit = local[:, None] == cols                 # (EBLK, SBLK)
-    if kind == "sum":
-        # one-hot matmul -> MXU systolic reduction
-        return jnp.dot(
-            hit.astype(msg.dtype).T, msg,
-            preferred_element_type=jnp.float32,
-        ).astype(dtype)
-    padded = jnp.where(hit, msg[:, None],
-                       jnp.asarray(identity, msg.dtype))
-    return jnp.min(padded, axis=0)               # VPU reduction over edges
+def _kernel_wl(wl_i_ref, wl_j_ref, nlive_ref, ids_ref, msg_ref, out_ref,
+               *dbg, kind, laned):
+    """Worklist cell ``c``: edge chunk ``wl_j[c]`` against segment block
+    ``wl_i[c]``, into its own partial block."""
+    c = pl.program_id(0)
+    bump = _bump_dbg(dbg, c == 0)
+    out_ref[...] = jnp.full(out_ref.shape, identity_of(kind), out_ref.dtype)
+
+    @pl.when(c < nlive_ref[0])
+    def _compute():
+        _cell(ids_ref, msg_ref, out_ref, wl_i_ref[c] * SBLK, kind, laned)
+        bump()
 
 
-def _accumulate_block(out_ref, contrib, kind):
-    """Combine a cell contribution into the out block (the worklist
-    kernels' per-cell partial blocks carry a leading singleton)."""
-    contrib = contrib.reshape(out_ref.shape)
-    if kind == "sum":
-        out_ref[...] += contrib
+_DBG_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+_DBG_SHAPE = jax.ShapeDtypeStruct((1,), jnp.int32)
+
+
+def _launch(kernel, scalars, ids_p, msg, grid, edge_map, out_block,
+            out_map, out_rows, interpret, with_debug):
+    """One pallas_call over lane-dense edge rows; ``msg`` is (e_pad,)
+    unlaned or (q_pad, e_pad) laned.  Returns (out[, dbg])."""
+    if msg.ndim == 1:
+        msg_spec = pl.BlockSpec((ROWS, LANES), edge_map)
+        msg = edge_rows(msg)
     else:
-        out_ref[...] = jnp.minimum(out_ref[...], contrib)
-
-
-def _seg_accumulate(out_ref, msg, local, kind, identity):
-    """Accumulate (EBLK,) messages into the (SBLK,) out block."""
-    _accumulate_block(
-        out_ref, _seg_contrib(msg, local, kind, identity, out_ref.dtype),
-        kind)
-
-
-def _kernel_lanes(chunk_lo_ref, chunk_hi_ref, chunk_act_ref,
-                  ids_ref, src_ref, w_ref, mask_ref, unitw_ref, gval_ref,
-                  out_ref, *extras, relax_kind, kind):
-    """Lane-batched kernel body: the value table carries a trailing query
-    axis ``Q`` and every edge relaxes all lanes at once.  ``unitw_ref``
-    (Q,) selects, per lane, whether 'add_w' reads the edge weight or the
-    constant 1.0 — BFS lanes are SSSP lanes over unit weights, so one
-    launch serves a mixed BFS/SSSP batch with bit-identical per-lane math.
-    The frontier chunk skip uses the OR across lanes (``chunk_act``): a
-    grid cell is skipped only when its edge chunk is dead in EVERY lane."""
-    dbg_ref, _ = _split_dbg(extras)
-    i = pl.program_id(0)  # segment block
-    j = pl.program_id(1)  # edge chunk
-
-    identity = jnp.inf if kind == "min" else 0.0
-    _init_dbg(dbg_ref, i, j)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.full(out_ref.shape, identity, out_ref.dtype)
-
-    seg0 = i * SBLK
-    intersects = (chunk_hi_ref[j] >= seg0) & (chunk_lo_ref[j] < seg0 + SBLK)
-    live = intersects & (chunk_act_ref[j] > 0)
-
-    @pl.when(live)
-    def _compute():
-        src = src_ref[...]                       # (EBLK,) int32
-        src_val = jnp.take(gval_ref[...], src, axis=0)   # (EBLK, Q)
-        msg = _lane_msgs(relax_kind, src_val, w_ref[...], mask_ref[...],
-                         unitw_ref[...], identity)
-        _lane_accumulate(out_ref, msg, ids_ref[...] - seg0, kind, identity)
-        _bump_dbg(dbg_ref, 0)        # pinned: no manual value-tile DMAs
-
-
-def _lane_msgs(relax_kind, src_val, w, mask, unitw, identity):
-    """(EBLK, Q) relaxed + masked messages for the laned kernels."""
-    if relax_kind == "add_w":
-        w_eff = jnp.where(unitw[None, :] > 0,
-                          jnp.asarray(1.0, w.dtype), w[:, None])
-        msg = src_val + w_eff
-    else:                                        # 'mul_w'
-        msg = src_val * w[:, None]
-    return jnp.where(mask[:, None] > 0, msg,
-                     jnp.asarray(identity, msg.dtype))
-
-
-def _lane_contrib(msg, local, kind, identity, dtype):
-    """(SBLK, Q) block contribution of (EBLK, Q) messages."""
-    cols = jax.lax.broadcasted_iota(jnp.int32, (EBLK, SBLK), 1)
-    hit = local[:, None] == cols                 # (EBLK, SBLK)
-    if kind == "sum":
-        # one-hot matmul -> (SBLK, Q) MXU systolic reduction
-        return jnp.dot(
-            hit.astype(msg.dtype).T, msg,
-            preferred_element_type=jnp.float32,
-        ).astype(dtype)
-    # statically unrolled per-lane loop: peak in-cell memory stays
-    # (EBLK, SBLK) regardless of Q — a broadcast hit[:, :, None]
-    # against msg would materialize an (EBLK, SBLK, Q) intermediate
-    # per grid cell, which cannot fit VMEM for real batch widths
-    contribs = []
-    for lq in range(msg.shape[1]):
-        padded = jnp.where(hit, msg[:, lq][:, None],
-                           jnp.asarray(identity, msg.dtype))
-        contribs.append(jnp.min(padded, axis=0))  # (SBLK,) VPU
-    return jnp.stack(contribs, axis=-1)           # (SBLK, Q)
-
-
-def _lane_accumulate(out_ref, msg, local, kind, identity):
-    """Accumulate (EBLK, Q) messages into the (SBLK, Q) out block."""
-    _accumulate_block(
-        out_ref, _lane_contrib(msg, local, kind, identity, out_ref.dtype),
-        kind)
-
-
-# --------------------------------------------------------------------------
-# kernel bodies — tiled (value table in HBM, per-cell double-buffered DMA)
-# --------------------------------------------------------------------------
-
-def _tile_loop(j, n, chunk_tiles_ref, gval_hbm, scratch, sem, vblk,
-               tile_fn):
-    """Double-buffered DMA loop over this chunk's ``n`` slot tiles: start
-    the warm-up fetch, then per tile overlap tile t+1's async copy with
-    tile t's compute (``tile_fn(slot, tile)`` reads ``scratch[slot]``).
-    Every started DMA is waited; the caller guards on ``n >= 1``.
-    ``gval_hbm`` may be (v_pad,) or (v_pad, Q) — the slice rank follows."""
-    laned = len(gval_hbm.shape) == 2
-
-    def get_dma(slot, t):
-        tile = chunk_tiles_ref[j, t]
-        rows = pl.ds(tile * vblk, vblk)
-        src = gval_hbm.at[rows, :] if laned else gval_hbm.at[rows]
-        return pltpu.make_async_copy(src, scratch.at[slot], sem.at[slot])
-
-    get_dma(0, 0).start()                        # warm-up fetch
-
-    def body(t, _):
-        slot = jax.lax.rem(t, 2)
-
-        @pl.when(t + 1 < n)
-        def _prefetch():                         # overlap next tile's DMA
-            get_dma(jax.lax.rem(t + 1, 2), t + 1).start()
-
-        get_dma(slot, t).wait()
-        tile_fn(slot, chunk_tiles_ref[j, t])
-        return 0
-
-    jax.lax.fori_loop(0, n, body, 0)
-
-
-def _kernel_tiled(chunk_lo_ref, chunk_hi_ref, chunk_act_ref,
-                  chunk_ntiles_ref, chunk_tiles_ref,
-                  ids_ref, src_ref, w_ref, mask_ref, gval_hbm,
-                  out_ref, *extras, relax_kind, kind, vblk):
-    """Tiled cell: the value table stays in HBM; only the ``vblk``-wide
-    slot tiles listed for this edge chunk (``chunk_tiles`` — the tiles
-    its frontier-active sources live in) are async-copied into a
-    double-buffered VMEM scratch, tile t+1's DMA overlapping tile t's
-    relax+reduce.  Every edge contributes in exactly one tile (its
-    source's), so per-tile accumulation into the out block is exact."""
-    dbg_ref, (scratch, sem) = _split_dbg(extras)
-    i = pl.program_id(0)  # segment block
-    j = pl.program_id(1)  # edge chunk
-
-    identity = jnp.inf if kind == "min" else 0.0
-    _init_dbg(dbg_ref, i, j)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.full((SBLK,), identity, out_ref.dtype)
-
-    seg0 = i * SBLK
-    intersects = (chunk_hi_ref[j] >= seg0) & (chunk_lo_ref[j] < seg0 + SBLK)
-    # a live chunk has >= 1 active source, hence >= 1 tile to fetch
-    live = intersects & (chunk_act_ref[j] > 0)
-
-    @pl.when(live)
-    def _compute():
-        n = chunk_ntiles_ref[j]
-        src = src_ref[...]                       # (EBLK,) int32
-        w = w_ref[...]
-        msk = mask_ref[...]
-        local = ids_ref[...] - seg0
-
-        def tile_fn(slot, tile):
-            loc = src - tile * vblk
-            in_tile = (loc >= 0) & (loc < vblk)
-            # sources outside this tile read slot 0 and are masked off;
-            # frontier-inactive sources *inside* the tile read the
-            # pre-masked absorbing identity, exactly as on the pinned path
-            sval = jnp.take(scratch[slot], jnp.where(in_tile, loc, 0))
-            msg = _relax(relax_kind, sval, w)
-            msg = jnp.where((msk > 0) & in_tile, msg,
-                            jnp.asarray(identity, msg.dtype))
-            _seg_accumulate(out_ref, msg, local, kind, identity)
-
-        _tile_loop(j, n, chunk_tiles_ref, gval_hbm, scratch, sem, vblk,
-                   tile_fn)
-        _bump_dbg(dbg_ref, n)
-
-
-def _kernel_tiled_lanes(chunk_lo_ref, chunk_hi_ref, chunk_act_ref,
-                        chunk_ntiles_ref, chunk_tiles_ref,
-                        ids_ref, src_ref, w_ref, mask_ref, unitw_ref,
-                        gval_hbm, out_ref, *extras, relax_kind, kind, vblk):
-    """Laned tiled cell: (vblk, Q) value tiles ride the double-buffered
-    DMA; tile lists use the OR-across-lanes frontier (a tile is fetched
-    iff ANY lane has an active source in it — the gather is vectorized
-    over lanes, and inactive lanes read the pre-masked identity)."""
-    dbg_ref, (scratch, sem) = _split_dbg(extras)
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-
-    identity = jnp.inf if kind == "min" else 0.0
-    _init_dbg(dbg_ref, i, j)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.full(out_ref.shape, identity, out_ref.dtype)
-
-    seg0 = i * SBLK
-    intersects = (chunk_hi_ref[j] >= seg0) & (chunk_lo_ref[j] < seg0 + SBLK)
-    live = intersects & (chunk_act_ref[j] > 0)
-
-    @pl.when(live)
-    def _compute():
-        n = chunk_ntiles_ref[j]
-        src = src_ref[...]
-        w = w_ref[...]
-        msk = mask_ref[...]
-        unitw = unitw_ref[...]
-        local = ids_ref[...] - seg0
-
-        def tile_fn(slot, tile):
-            loc = src - tile * vblk
-            in_tile = (loc >= 0) & (loc < vblk)
-            sval = jnp.take(scratch[slot], jnp.where(in_tile, loc, 0),
-                            axis=0)              # (EBLK, Q)
-            msg = _lane_msgs(relax_kind, sval, w,
-                             msk * in_tile.astype(msk.dtype), unitw,
-                             identity)
-            _lane_accumulate(out_ref, msg, local, kind, identity)
-
-        _tile_loop(j, n, chunk_tiles_ref, gval_hbm, scratch, sem, vblk,
-                   tile_fn)
-        _bump_dbg(dbg_ref, n)
-
-
-# --------------------------------------------------------------------------
-# kernel bodies — worklist twins (1-D grid over live (i, j) cell pairs)
-# --------------------------------------------------------------------------
-#
-# Every worklist cell writes its own (1, SBLK[, Q]) partial block — the
-# launch's out is (l_pad, SBLK[, Q]) and a host-side scatter-combine by
-# ``wl_i`` folds the partials into the inbox (see ``_scatter_partials``).
-# Cells past ``nlive`` (the pad) and dead cells emit the combine
-# identity, which the scatter absorbs — no first-visit bookkeeping, no
-# out-block revisiting, and the 1-D grid is exactly as long as the
-# padded live count.
-
-
-def _kernel_wl(wl_i_ref, wl_j_ref, nlive_ref,
-               ids_ref, src_ref, w_ref, mask_ref, gval_ref,
-               out_ref, *extras, relax_kind, kind):
-    """Pinned worklist cell: cell ``c`` works edge chunk ``wl_j[c]``
-    against segment block ``wl_i[c]``; the full value table rides in."""
-    dbg_ref, _ = _split_dbg(extras)
-    c = pl.program_id(0)
-    identity = jnp.inf if kind == "min" else 0.0
-    _init_dbg(dbg_ref, c, 0)
-    out_ref[...] = jnp.full(out_ref.shape, identity, out_ref.dtype)
-
-    @pl.when(c < nlive_ref[0])
-    def _compute():
-        seg0 = wl_i_ref[c] * SBLK
-        src_val = jnp.take(gval_ref[...], src_ref[...])
-        msg = _relax(relax_kind, src_val, w_ref[...])
-        msg = jnp.where(mask_ref[...] > 0, msg,
-                        jnp.asarray(identity, msg.dtype))
-        _accumulate_block(
-            out_ref,
-            _seg_contrib(msg, ids_ref[...] - seg0, kind, identity,
-                         out_ref.dtype),
-            kind)
-        _bump_dbg(dbg_ref, 0)        # pinned: no manual value-tile DMAs
-
-
-def _kernel_wl_lanes(wl_i_ref, wl_j_ref, nlive_ref,
-                     ids_ref, src_ref, w_ref, mask_ref, unitw_ref,
-                     gval_ref, out_ref, *extras, relax_kind, kind):
-    dbg_ref, _ = _split_dbg(extras)
-    c = pl.program_id(0)
-    identity = jnp.inf if kind == "min" else 0.0
-    _init_dbg(dbg_ref, c, 0)
-    out_ref[...] = jnp.full(out_ref.shape, identity, out_ref.dtype)
-
-    @pl.when(c < nlive_ref[0])
-    def _compute():
-        seg0 = wl_i_ref[c] * SBLK
-        src_val = jnp.take(gval_ref[...], src_ref[...], axis=0)  # (EBLK, Q)
-        msg = _lane_msgs(relax_kind, src_val, w_ref[...], mask_ref[...],
-                         unitw_ref[...], identity)
-        _accumulate_block(
-            out_ref,
-            _lane_contrib(msg, ids_ref[...] - seg0, kind, identity,
-                          out_ref.dtype),
-            kind)
-        _bump_dbg(dbg_ref, 0)
-
-
-def _wl_tile_loop(c, n, cell_tile_ref, cell_slot_ref, cell_fetch_ref,
-                  gval_hbm, scratch, sem, vblk, tile_fn, t_max):
-    """Worklist DMA loop: the planner pre-assigned each of this cell's
-    ``n`` tiles a scratch slot and a fetch flag (0 = the tile is still
-    resident from an earlier cell of the same edge chunk — the j-major
-    reuse), so the kernel only issues the DMAs the host planned.  Tile
-    t+1's fetch overlaps tile t's relax+reduce: the planner alternates
-    fetch slots (a fetched tile never lands in the slot the previous
-    tile is being read from), which keeps the prefetch safe.  Returns
-    the number of DMAs issued (the ``with_debug`` counter)."""
-    laned = len(gval_hbm.shape) == 2
-
-    def get_dma(t):
-        slot = cell_slot_ref[c, t]
-        rows = pl.ds(cell_tile_ref[c, t] * vblk, vblk)
-        src = gval_hbm.at[rows, :] if laned else gval_hbm.at[rows]
-        return pltpu.make_async_copy(src, scratch.at[slot], sem.at[slot])
-
-    @pl.when((n >= 1) & (cell_fetch_ref[c, 0] > 0))
-    def _warmup():
-        get_dma(0).start()
-
-    def body(t, dmas):
-        # t + 1 is clamped for the table read only; the (t + 1 < n)
-        # predicate keeps the clamped duplicate from ever fetching
-        t1 = jnp.minimum(t + 1, t_max - 1)
-
-        @pl.when((t + 1 < n) & (cell_fetch_ref[c, t1] > 0))
-        def _prefetch():
-            get_dma(t1).start()
-
-        @pl.when(cell_fetch_ref[c, t] > 0)
-        def _wait():
-            get_dma(t).wait()
-
-        tile_fn(cell_slot_ref[c, t], cell_tile_ref[c, t])
-        return dmas + cell_fetch_ref[c, t]
-
-    return jax.lax.fori_loop(0, n, body, 0)
-
-
-def _kernel_wl_tiled(wl_i_ref, wl_j_ref, nlive_ref, cell_ntiles_ref,
-                     cell_tile_ref, cell_slot_ref, cell_fetch_ref,
-                     ids_ref, src_ref, w_ref, mask_ref, gval_hbm,
-                     out_ref, *extras, relax_kind, kind, vblk, t_max):
-    """Tiled worklist cell: only the tiles of frontier-active sources
-    whose edge lands in THIS cell's dst block (the per-cell dst-range
-    filter) ride the DMA, and tiles resident from the previous same-
-    chunk cell are reused instead of re-fetched."""
-    dbg_ref, (scratch, sem) = _split_dbg(extras)
-    c = pl.program_id(0)
-    identity = jnp.inf if kind == "min" else 0.0
-    _init_dbg(dbg_ref, c, 0)
-    out_ref[...] = jnp.full(out_ref.shape, identity, out_ref.dtype)
-
-    @pl.when(c < nlive_ref[0])
-    def _compute():
-        n = cell_ntiles_ref[c]
-        seg0 = wl_i_ref[c] * SBLK
-        src = src_ref[...]
-        w = w_ref[...]
-        msk = mask_ref[...]
-        local = ids_ref[...] - seg0
-
-        def tile_fn(slot, tile):
-            loc = src - tile * vblk
-            in_tile = (loc >= 0) & (loc < vblk)
-            sval = jnp.take(scratch[slot], jnp.where(in_tile, loc, 0))
-            msg = _relax(relax_kind, sval, w)
-            msg = jnp.where((msk > 0) & in_tile, msg,
-                            jnp.asarray(identity, msg.dtype))
-            _accumulate_block(
-                out_ref,
-                _seg_contrib(msg, local, kind, identity, out_ref.dtype),
-                kind)
-
-        dmas = _wl_tile_loop(c, n, cell_tile_ref, cell_slot_ref,
-                             cell_fetch_ref, gval_hbm, scratch, sem, vblk,
-                             tile_fn, t_max)
-        _bump_dbg(dbg_ref, dmas)
-
-
-def _kernel_wl_tiled_lanes(wl_i_ref, wl_j_ref, nlive_ref, cell_ntiles_ref,
-                           cell_tile_ref, cell_slot_ref, cell_fetch_ref,
-                           ids_ref, src_ref, w_ref, mask_ref, unitw_ref,
-                           gval_hbm, out_ref, *extras, relax_kind, kind,
-                           vblk, t_max):
-    dbg_ref, (scratch, sem) = _split_dbg(extras)
-    c = pl.program_id(0)
-    identity = jnp.inf if kind == "min" else 0.0
-    _init_dbg(dbg_ref, c, 0)
-    out_ref[...] = jnp.full(out_ref.shape, identity, out_ref.dtype)
-
-    @pl.when(c < nlive_ref[0])
-    def _compute():
-        n = cell_ntiles_ref[c]
-        seg0 = wl_i_ref[c] * SBLK
-        src = src_ref[...]
-        w = w_ref[...]
-        msk = mask_ref[...]
-        unitw = unitw_ref[...]
-        local = ids_ref[...] - seg0
-
-        def tile_fn(slot, tile):
-            loc = src - tile * vblk
-            in_tile = (loc >= 0) & (loc < vblk)
-            sval = jnp.take(scratch[slot], jnp.where(in_tile, loc, 0),
-                            axis=0)              # (EBLK, Q)
-            msg = _lane_msgs(relax_kind, sval, w,
-                             msk * in_tile.astype(msk.dtype), unitw,
-                             identity)
-            _accumulate_block(
-                out_ref,
-                _lane_contrib(msg, local, kind, identity, out_ref.dtype),
-                kind)
-
-        dmas = _wl_tile_loop(c, n, cell_tile_ref, cell_slot_ref,
-                             cell_fetch_ref, gval_hbm, scratch, sem, vblk,
-                             tile_fn, t_max)
-        _bump_dbg(dbg_ref, dmas)
-
-
-# --------------------------------------------------------------------------
-# scalar-prefetch table builders
-# --------------------------------------------------------------------------
-
-def _chunk_tables(ids_p, src_p, mask_i, gchg_i):
-    """Scalar-prefetch tables: per-chunk [lo, hi] id range + frontier bit.
-    Also returns the total active-edge count (the Fig-6 message counter) —
-    a free reduction of the gather the bitmap needs anyway — and the
-    per-edge active rows the tiled path's tile lists are built from."""
-    e_pad = ids_p.shape[0]
-    idc = ids_p.reshape(e_pad // EBLK, EBLK)
-    valid = mask_i.reshape(e_pad // EBLK, EBLK) > 0
-    chunk_lo = jnp.where(valid, idc, jnp.iinfo(jnp.int32).max).min(axis=1)
-    chunk_hi = jnp.where(valid, idc, -1).max(axis=1)
-    # "any active source" bitmap: gchg gather fused into a per-chunk any()
-    src_act = jnp.where(valid, jnp.take(gchg_i, src_p.reshape(valid.shape)), 0)
-    chunk_act = src_act.max(axis=1).astype(jnp.int32)
-    return chunk_lo, chunk_hi, chunk_act, src_act.sum(), src_act
-
-
-def _chunk_tile_tables(src_p, src_act, v_pad: int, vblk: int):
-    """Per-chunk slot-tile lists for the tiled kernels.
-
-    ``src_act``: (n_chunks, EBLK) nonzero where the edge is valid AND its
-    source is frontier-active (OR across lanes when laned).  Returns
-    ((n_chunks,) tile counts, (n_chunks, t_max) tile indices packed left
-    in ascending order; entries past the count are arbitrary in-range
-    tiles and never fetched — the kernel's fori_loop stops at the count).
-
-    Built by an in-chunk sort + adjacent-dedupe, so the work is
-    O(E log EBLK) and *independent of the tile count* — a dense
-    (n_chunks, n_tiles) hit matrix would be quadratic-ish at exactly the
-    paper-scale (R22+: ~131k chunks x ~33k tiles) regime this path
-    exists to serve.
-    """
-    n_chunks = src_act.shape[0]
-    n_tiles = v_pad // vblk
-    t_max = min(n_tiles, EBLK)   # a chunk of EBLK edges touches <= EBLK tiles
-    tile_of = src_p.reshape(n_chunks, EBLK) // vblk
-    # inactive edges carry the n_tiles sentinel so they sort past every
-    # real tile; first-occurrence flags then mark each distinct live tile
-    t = jnp.sort(jnp.where(src_act > 0, tile_of, n_tiles), axis=1)
-    first = jnp.concatenate(
-        [jnp.ones((n_chunks, 1), bool), t[:, 1:] != t[:, :-1]], axis=1)
-    is_tile = first & (t < n_tiles)
-    ntiles = is_tile.sum(axis=1).astype(jnp.int32)
-    # pack distinct tiles left (stable: ascending slot order -> the
-    # kernel's tile fetches walk HBM sequentially)
-    order = jnp.argsort(~is_tile, axis=1, stable=True)[:, :t_max]
-    tiles = jnp.take_along_axis(t, order, axis=1)
-    # slots past the count may hold the sentinel; clamp into range
-    # (never fetched, but keeps any address arithmetic in bounds)
-    return ntiles, jnp.minimum(tiles, n_tiles - 1).astype(jnp.int32)
-
-
-def _chunk_tables_lanes(ids_p, src_p, mask_i, gchg_iq):
-    """Laned scalar-prefetch tables. ``gchg_iq``: (v_pad, Q) int32 per-lane
-    frontier. The chunk-skip bit is the OR across lanes — a chunk is dead
-    only when no lane has an active source in it; the per-lane active-edge
-    counts (the Fig-6 message counters, one per query) ride along, as do
-    the OR-across-lanes per-edge active rows for the tiled tile lists."""
-    e_pad = ids_p.shape[0]
-    idc = ids_p.reshape(e_pad // EBLK, EBLK)
-    valid = mask_i.reshape(e_pad // EBLK, EBLK) > 0
-    chunk_lo = jnp.where(valid, idc, jnp.iinfo(jnp.int32).max).min(axis=1)
-    chunk_hi = jnp.where(valid, idc, -1).max(axis=1)
-    src_act = jnp.where(
-        valid[..., None],
-        jnp.take(gchg_iq, src_p.reshape(valid.shape), axis=0), 0)
-    chunk_act = src_act.max(axis=(1, 2)).astype(jnp.int32)
-    return (chunk_lo, chunk_hi, chunk_act, src_act.sum(axis=(0, 1)),
-            src_act.max(axis=2))
+        msg_spec = pl.BlockSpec(
+            (msg.shape[0], EBLK),
+            lambda *a: (0, edge_map(*a)[0]))
+    out_spec = pl.BlockSpec((pl.Squeezed(),) + out_block, out_map)
+    out_shape = jax.ShapeDtypeStruct((out_rows,) + out_block, jnp.float32)
+    if with_debug:
+        out_spec = [out_spec, _DBG_SPEC]
+        out_shape = [out_shape, _DBG_SHAPE]
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=grid,
+            in_specs=[pl.BlockSpec((ROWS, LANES), edge_map), msg_spec],
+            out_specs=out_spec,
+        ),
+        out_shape=out_shape,
+        interpret=interpret,
+    )(*scalars, edge_rows(ids_p), msg)
+
+
+def _launch_dense(ids_p, msg, valid, active, num_segments, kind, laned,
+                  interpret, with_debug):
+    """Dense (n_sblk, n_chunks) grid; returns the blocked (n_i, rows, SBLK)
+    inbox (rows = 1 or q_pad) [+ dbg]."""
+    n_i = _round_up(num_segments, SBLK) // SBLK
+    tables = _chunk_tables(ids_p, valid, active)
+    rows = msg.shape[0] if laned else 1
+    return _launch(
+        functools.partial(_kernel_dense, kind=kind, laned=laned), tables,
+        ids_p, msg, (n_i, ids_p.shape[0] // EBLK),
+        lambda i, j, *_: (j, 0), (rows, SBLK), lambda i, j, *_: (i, 0, 0),
+        n_i, interpret, with_debug)
+
+
+def _launch_wl(ids_p, msg, wl_i, wl_j, nlive, num_segments, kind, laned,
+               interpret, with_debug):
+    """1-D worklist grid; each cell writes its own partial, scatter-
+    combined by ``wl_i`` into the blocked (n_i, rows, SBLK) inbox (dead
+    and padded cells hold the identity, so every row scatters exactly)."""
+    n_i = _round_up(num_segments, SBLK) // SBLK
+    rows = msg.shape[0] if laned else 1
+    # one pallas_call per WL_LAUNCH_CELLS slice keeps every call's
+    # scalar-prefetched cell list within SMEM; l_pad and the slice are
+    # powers of two, so the slices tile the list exactly
+    step = min(wl_i.shape[0], WL_LAUNCH_CELLS)
+    parts, dbgs = [], []
+    for start in range(0, wl_i.shape[0], step):
+        cells = slice(start, start + step)
+        raw = _launch(
+            functools.partial(_kernel_wl, kind=kind, laned=laned),
+            (wl_i[cells], wl_j[cells], jnp.clip(nlive - start, 0, step)),
+            ids_p, msg, (step,), lambda c, wi, wj, nl: (wj[c], 0),
+            (rows, SBLK), lambda c, *_: (c, 0, 0), step, interpret,
+            with_debug)
+        part, dbg = raw if with_debug else (raw, None)
+        parts.append(part)
+        dbgs.append(dbg)
+    partials = jnp.concatenate(parts)
+    dbg = sum(dbgs[1:], dbgs[0]) if with_debug else None
+    init = jnp.full((n_i, rows, SBLK), identity_of(kind), jnp.float32)
+    folded = (init.at[wl_i].min(partials) if kind == "min"
+              else init.at[wl_i].add(partials))
+    return (folded, dbg) if with_debug else folded
+
+
+def _reduce_launch(ids_p, msg, valid, active, num_segments, kind, laned,
+                   interpret, with_debug, grid_mode, worklist):
+    """Dispatch one launch by grid mode.  ``device_worklist`` compacts the
+    live-cell list on device; when the live count outgrows the static
+    list length (possible only for edges not sorted by destination) the
+    dense grid runs instead, so no cell is ever dropped."""
+    dense = functools.partial(
+        _launch_dense, ids_p, msg, valid, active, num_segments, kind, laned,
+        interpret, with_debug)
+    if worklist is None and grid_mode == "device_worklist":
+        tables = _chunk_tables(ids_p, valid, active)
+        l_pad = device_worklist_pad(ids_p.shape[0], num_segments)
+        wl_i, wl_j, nlive = _compact_live_cells(
+            *tables, _round_up(num_segments, SBLK) // SBLK, l_pad)
+        return jax.lax.cond(
+            nlive[0] <= l_pad,
+            lambda: _launch_wl(ids_p, msg, wl_i, wl_j, nlive, num_segments,
+                               kind, laned, interpret, with_debug),
+            dense)
+    if worklist is not None:
+        return _launch_wl(ids_p, msg, jnp.asarray(worklist.wl_i),
+                          jnp.asarray(worklist.wl_j),
+                          jnp.asarray(worklist.nlive), num_segments, kind,
+                          laned, interpret, with_debug)
+    return dense()
+
+
+def _finish(raw, with_debug, unblock, count, with_count):
+    out, dbg = raw if with_debug else (raw, None)
+    res = (unblock(out),)
+    if with_count:
+        res += (count,)
+    if with_debug:
+        res += (dbg,)
+    return res[0] if len(res) == 1 else res
 
 
 # --------------------------------------------------------------------------
@@ -861,41 +419,28 @@ def _chunk_tables_lanes(ids_p, src_p, mask_i, gchg_iq):
 
 @jax.tree_util.register_pytree_node_class
 class Worklist:
-    """A planned sparse launch: the live (i, j) cell list plus, for the
-    tiled path, the per-cell dst-filtered tile/slot/fetch schedule.
+    """A planned sparse launch: the live (i, j) cell list.
 
     Registered as a pytree so drivers can pass a fresh per-round plan
     through one jitted round function — the arrays are leaves (jit
-    retraces only when the padded length bucket changes), the residency
-    decision (``path``/``vblk``) is static aux data.  ``nlive`` rides as
-    a (1,) int32 array for the same reason."""
+    retraces only when the padded length bucket changes).  ``nlive``
+    rides as a (1,) int32 array for the same reason."""
 
-    def __init__(self, wl_i, wl_j, nlive, cell_ntiles=None, cell_tile=None,
-                 cell_slot=None, cell_fetch=None, *, path="pinned",
-                 vblk=None):
+    def __init__(self, wl_i, wl_j, nlive):
         self.wl_i = wl_i
         self.wl_j = wl_j
         self.nlive = nlive
-        self.cell_ntiles = cell_ntiles
-        self.cell_tile = cell_tile
-        self.cell_slot = cell_slot
-        self.cell_fetch = cell_fetch
-        self.path = path
-        self.vblk = vblk
 
     @property
     def l_pad(self) -> int:
         return self.wl_i.shape[0]
 
     def tree_flatten(self):
-        return ((self.wl_i, self.wl_j, self.nlive, self.cell_ntiles,
-                 self.cell_tile, self.cell_slot, self.cell_fetch),
-                (self.path, self.vblk))
+        return (self.wl_i, self.wl_j, self.nlive), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        path, vblk = aux
-        return cls(*children, path=path, vblk=vblk)
+        return cls(*children)
 
 
 class WorklistInfo(typing.NamedTuple):
@@ -905,9 +450,6 @@ class WorklistInfo(typing.NamedTuple):
     cells: int           # live cells after the dst-range empty-cell drop
     launched: int        # padded 1-D grid length
     dense_live: int      # what the dense grid's two-level skip would run
-    tile_dmas: int       # DMAs the 2-slot reuse schedule actually issues
-    tile_needed: int     # tile visits before reuse (the no-reuse count)
-    dma_bytes: int
     smem_table_bytes: int
 
 
@@ -917,220 +459,130 @@ def _wl_pad_len(nlive: int, pad_to: int = WL_PAD) -> int:
 
 class WorklistPlanner:
     """Precomputes the frontier-independent parts of worklist planning
-    for one launch shape (one edge set + segment count [+ vblk]), so
-    per-round plans only pay the frontier-dependent work.
+    for one launch shape (one edge set + segment count), so per-round
+    plans only pay the frontier-dependent work.
 
     ``edge_dst``/``edge_mask``/``edge_src`` may be (S, E_max) stacked or
-    flat — flattened exactly as the kernels flatten them; ``num_slots``
-    (the value-table height) sizes the slot tiling when ``vblk`` is
-    given.  ``plan(gchg)`` returns (Worklist, WorklistInfo); for laned
-    launches pass the OR-across-lanes frontier."""
+    flat — flattened exactly as the kernels flatten them.  Each chunk's
+    intersecting segment blocks are the contiguous range
+    ``[lo // SBLK, hi // SBLK]`` of its valid ids, so planning works on
+    those ranges and never builds the (n_sblk, n_chunks) grid.
+    ``plan(gchg)`` returns (Worklist, WorklistInfo); for laned launches
+    pass the OR-across-lanes frontier."""
 
     def __init__(self, edge_dst, edge_mask, edge_src, num_segments: int,
-                 *, num_slots: int | None = None, path: str = "pinned",
-                 vblk: int | None = None, lane_width: int = 1,
-                 smem_budget_bytes: int | None = None):
+                 *, smem_budget_bytes: int | None = None):
         ids = np.asarray(edge_dst).reshape(-1)
         mask = np.asarray(edge_mask).reshape(-1)
         srcs = np.asarray(edge_src).reshape(-1)
         e = ids.shape[0]
         e_pad = _round_up(e, EBLK)
         self.num_segments = int(num_segments)
-        self.s_pad = _round_up(num_segments, SBLK)
-        self.n_i = self.s_pad // SBLK
+        self.n_i = _round_up(num_segments, SBLK) // SBLK
         self.n_chunks = e_pad // EBLK
-        self.path = path
-        self.vblk = int(vblk) if vblk is not None else None
-        self.lane_width = int(lane_width)
         self.smem_budget_bytes = smem_budget_bytes
         self._smem_warned = False
 
-        idc = np.zeros(e_pad, np.int64)
-        idc[:e] = ids
-        mkc = np.zeros(e_pad, bool)
-        mkc[:e] = mask
-        srcc = np.zeros(e_pad, np.int64)
-        srcc[:e] = srcs
-        self.ids = idc.reshape(self.n_chunks, EBLK)
-        self.mask = mkc.reshape(self.n_chunks, EBLK)
-        self.srcs = srcc.reshape(self.n_chunks, EBLK)
-        lo = np.where(self.mask, self.ids, np.iinfo(np.int64).max).min(axis=1)
-        hi = np.where(self.mask, self.ids, -1).max(axis=1)
-        seg0 = np.arange(self.n_i)[:, None] * SBLK
-        self.intersects = (hi[None, :] >= seg0) & (lo[None, :] < seg0 + SBLK)
-        self.blk_of = self.ids // SBLK           # dst block of each edge
-        if self.path == "tiled":
-            if self.vblk is None:
-                raise ValueError("tiled worklist planning needs vblk")
-            v_pad = _round_up(num_slots if num_slots is not None
-                              else int(srcc.max(initial=0)) + 1, self.vblk)
-            self.n_tiles = v_pad // self.vblk
-            self.t_max = min(self.n_tiles, EBLK)
-            self.tile_of = self.srcs // self.vblk
-        else:
-            self.t_max = 0
+        def chunked(x, dtype):
+            out = np.zeros(e_pad, dtype)
+            out[:e] = x
+            return out.reshape(self.n_chunks, EBLK)
+
+        ids_c = chunked(ids, np.int64)
+        self.mask = chunked(mask, bool)
+        self.srcs = chunked(srcs, np.int64)
+        lo = np.where(self.mask, ids_c, np.iinfo(np.int64).max).min(axis=1)
+        hi = np.where(self.mask, ids_c, -1).max(axis=1)
+        has = self.mask.any(axis=1)
+        self.lo_blk = np.where(has, lo // SBLK, 0)
+        self.span = np.where(has, hi // SBLK - self.lo_blk + 1, 0)
+        self.blk_of = ids_c // SBLK              # dst block of each edge
 
     @property
     def total_cells(self) -> int:
         return self.n_i * self.n_chunks
 
-    def _live_map(self, gchg):
+    def _active(self, gchg):
         gchg = np.asarray(gchg).reshape(-1)
         act = self.mask & gchg[self.srcs]        # (n_chunks, EBLK)
-        live = self.intersects & act.any(axis=1)[None, :]
-        return act, live
+        return act, act.any(axis=1)
+
+    def _cells(self, live_chunk):
+        """j-major (chunk, block) pairs of the dense grid's live cells."""
+        jj = np.nonzero(live_chunk)[0]
+        span = self.span[jj]
+        jj = np.repeat(jj, span)
+        start = np.repeat(np.cumsum(span) - span, span)
+        ii = self.lo_blk[jj] + (np.arange(jj.shape[0]) - start)
+        return jj, ii
 
     def live_fraction(self, gchg) -> float:
         """Fraction of the dense grid the two-level skip would execute —
         the signal ``grid_mode='auto'`` keys the dense/worklist choice on."""
-        _, live = self._live_map(gchg)
-        return live.sum() / max(self.total_cells, 1)
+        _, live_chunk = self._active(gchg)
+        return int(self.span[live_chunk].sum()) / max(self.total_cells, 1)
 
     def dense_mirror(self, gchg) -> dict:
         """Mirror of the DENSE grid's launch for this planner's edge set:
-        live cells under the two-level skip, and — on the tiled path —
-        the per-chunk tile-list DMA schedule (every live (i, j) cell
-        fetches its chunk's distinct active-source tiles), matching
-        ``fused_grid_cells``'s ``fused_live``/``fused_tile_dmas``/
-        ``dma_bytes`` columns exactly.  The flight recorder uses this for
-        rounds that kept the dense grid (grid_mode='dense', or 'auto'
-        above the live-fraction threshold)."""
-        act, live = self._live_map(gchg)
-        out = {"cells": int(live.sum()), "launched": self.total_cells,
-               "tile_dmas": 0, "dma_bytes": 0}
-        if self.path == "tiled":
-            # distinct tiles per chunk among frontier-active edges: the
-            # WorklistPlanner.plan first-occurrence trick, per chunk row
-            t = np.sort(np.where(act, self.tile_of, self.n_tiles), axis=1)
-            first = np.concatenate(
-                [np.ones((t.shape[0], 1), bool), t[:, 1:] != t[:, :-1]],
-                axis=1)
-            ntiles = (first & (t < self.n_tiles)).sum(axis=1)
-            out["tile_dmas"] = int((live * ntiles[None, :]).sum())
-            out["dma_bytes"] = out["tile_dmas"] * self.vblk \
-                * self.lane_width * 4
-        return out
+        live cells under the two-level skip, matching ``fused_grid_cells``'s
+        ``fused_live`` exactly.  The flight recorder uses this for rounds
+        that kept the dense grid (grid_mode='dense', or 'auto' above the
+        live-fraction threshold)."""
+        _, live_chunk = self._active(gchg)
+        return {"cells": int(self.span[live_chunk].sum()),
+                "launched": self.total_cells}
 
     def plan(self, gchg, pad_to: int = WL_PAD, dst_filter: bool = True,
              max_live_fraction: float | None = None):
         """Plan one round's launch from the (V,) bool frontier.
 
         j-major cell order (j outer, i inner); with ``dst_filter`` a
-        cell keeps only tiles of active sources whose edge's dst falls
-        in its block — cells left tileless contribute nothing and are
-        dropped.  Tile DMAs are scheduled against a 2-slot resident
-        model (fetches alternate slots; a needed tile already resident
-        is reused), exactly what ``_wl_tile_loop`` executes.
+        cell is kept only when some frontier-active edge of its chunk
+        lands in its block — the others contribute only the identity.
 
         ``max_live_fraction`` implements 'auto' cheaply: when the dense
         grid's live fraction is at/above it, return (None, None) BEFORE
         any per-cell work — a dense frontier gains nothing from the 1-D
-        launch, and skipping here also skips the planner's per-cell
-        cost, which is what degenerates on full frontiers.  Plans whose
-        scalar-prefetch tables exceed ``smem_budget_bytes`` warn once
-        per planner (the per-round cell count is frontier-dependent, so
-        only the plan itself can price the worklist tables —
-        ``select_kernel_path`` guards the static chunk/tile tables)."""
-        act, live = self._live_map(gchg)
-        dense_live = int(live.sum())
+        launch.  Plans whose scalar-prefetch tables exceed
+        ``smem_budget_bytes`` warn once per planner."""
+        act, live_chunk = self._active(gchg)
+        dense_live = int(self.span[live_chunk].sum())
         if max_live_fraction is not None \
                 and dense_live / max(self.total_cells, 1) \
                 >= max_live_fraction:
             return None, None
-        jj, ii = np.nonzero(live.T)              # j-major: sorted by j, then i
+        jj, ii = self._cells(live_chunk)
         if dst_filter:
-            # per-cell active-and-in-block edge mask; a cell with no such
-            # edge contributes only the identity — drop it outright
-            sel = act[jj] & (self.blk_of[jj] == ii[:, None])
-            keep = sel.any(axis=1)
-            jj, ii, sel = jj[keep], ii[keep], sel[keep]
-        else:
-            sel = act[jj]
+            keep = (act[jj] & (self.blk_of[jj] == ii[:, None])).any(axis=1)
+            jj, ii = jj[keep], ii[keep]
         nlive = int(ii.shape[0])
         l_pad = _wl_pad_len(nlive, pad_to)
         wl_i = np.zeros(l_pad, np.int32)
         wl_j = np.zeros(l_pad, np.int32)
         wl_i[:nlive] = ii
         wl_j[:nlive] = jj
-        nlive_arr = np.asarray([nlive], np.int32)
-
-        if self.path != "tiled":
-            wl = Worklist(wl_i, wl_j, nlive_arr, path="pinned")
-            info = WorklistInfo(
-                cells=nlive, launched=l_pad, dense_live=dense_live,
-                tile_dmas=0, tile_needed=0, dma_bytes=0,
-                smem_table_bytes=smem_table_bytes(self.n_chunks, 0, l_pad))
-            return wl, self._check_smem(info)
-
-        t_max = self.t_max
-        cell_ntiles = np.zeros(l_pad, np.int32)
-        cell_tile = np.zeros((l_pad, t_max), np.int32)
-        cell_slot = np.zeros((l_pad, t_max), np.int32)
-        cell_fetch = np.zeros((l_pad, t_max), np.int32)
-        # vectorized per-cell distinct-tile extraction: in-row sort with
-        # an out-of-range sentinel on filtered edges + first-occurrence
-        # flags (the _chunk_tile_tables trick, one row per live CELL) —
-        # only the inherently-sequential 2-slot schedule loops in Python
-        t = np.sort(np.where(sel, self.tile_of[jj], self.n_tiles), axis=1)
-        first = np.concatenate(
-            [np.ones((nlive, 1), bool), t[:, 1:] != t[:, :-1]], axis=1)
-        is_tile = first & (t < self.n_tiles)
-        cell_ntiles[:nlive] = is_tile.sum(axis=1)
-        resident = [-1, -1]                      # the kernel's 2-slot scratch
-        prev_slot = 1                            # first fetch lands in slot 0
-        fetches = needed = 0
-        for c in range(nlive):
-            tiles = t[c][is_tile[c]]             # distinct, ascending
-            needed += tiles.shape[0]
-            for k, tile in enumerate(tiles):
-                if tile == resident[0]:
-                    slot, fetch = 0, 0
-                elif tile == resident[1]:
-                    slot, fetch = 1, 0
-                else:
-                    slot, fetch = 1 - prev_slot, 1
-                    resident[slot] = tile
-                    fetches += 1
-                cell_tile[c, k] = tile
-                cell_slot[c, k] = slot
-                cell_fetch[c, k] = fetch
-                prev_slot = slot
-        wl = Worklist(wl_i, wl_j, nlive_arr, cell_ntiles, cell_tile,
-                      cell_slot, cell_fetch, path="tiled", vblk=self.vblk)
+        wl = Worklist(wl_i, wl_j, np.asarray([nlive], np.int32))
         info = WorklistInfo(
             cells=nlive, launched=l_pad, dense_live=dense_live,
-            tile_dmas=fetches, tile_needed=needed,
-            dma_bytes=fetches * self.vblk * self.lane_width * 4,
-            smem_table_bytes=smem_table_bytes(self.n_chunks, t_max, l_pad))
-        return wl, self._check_smem(info)
-
-    def _check_smem(self, info: WorklistInfo) -> WorklistInfo:
+            smem_table_bytes=smem_table_bytes(self.n_chunks, l_pad))
         if self.smem_budget_bytes is not None and not self._smem_warned \
                 and info.smem_table_bytes > self.smem_budget_bytes:
             self._smem_warned = True
             warnings.warn(
                 f"worklist scalar-prefetch tables ({info.launched} cells, "
-                f"{self.n_chunks} chunks, t_max={self.t_max}) weigh "
-                f"{info.smem_table_bytes} bytes — over smem_budget_bytes="
-                f"{self.smem_budget_bytes}; prefer grid_mode='auto' (dense "
-                "frontiers keep the dense grid) or a wider vblk",
-                stacklevel=3)
-        return info
+                f"{self.n_chunks} chunks) weigh {info.smem_table_bytes} "
+                f"bytes — over smem_budget_bytes={self.smem_budget_bytes}; "
+                "prefer grid_mode='auto' (dense frontiers keep the dense "
+                "grid)", stacklevel=2)
+        return wl, info
 
 
 def plan_worklist(edge_dst, edge_mask, edge_src, gchg, num_segments: int,
-                  *, num_slots=None, path="pinned", vblk=None,
-                  lane_width: int = 1, pad_to: int = WL_PAD,
-                  dst_filter: bool = True):
+                  *, pad_to: int = WL_PAD, dst_filter: bool = True):
     """One-shot worklist plan (see ``WorklistPlanner`` for the reusable
     form drivers amortize across rounds).  ``gchg`` is the (V,) frontier
-    (OR across lanes for laned launches); it also sizes the slot table
-    unless ``num_slots`` overrides."""
-    if num_slots is None:
-        num_slots = np.asarray(gchg).reshape(-1).shape[0]
-    planner = WorklistPlanner(
-        edge_dst, edge_mask, edge_src, num_segments, num_slots=num_slots,
-        path=path, vblk=vblk, lane_width=lane_width)
+    (OR across lanes for laned launches)."""
+    planner = WorklistPlanner(edge_dst, edge_mask, edge_src, num_segments)
     return planner.plan(gchg, pad_to=pad_to, dst_filter=dst_filter)
 
 
@@ -1139,35 +591,37 @@ def plan_worklist(edge_dst, edge_mask, edge_src, gchg, num_segments: int,
 # --------------------------------------------------------------------------
 # The traced twin of WorklistPlanner.plan: the live-cell list is built
 # from the same jnp chunk tables the dense kernels prefetch, compacted
-# j-major by a cumsum-scatter, and fed to the UNCHANGED worklist kernels
-# as scalar-prefetch operands (which are ordinary pallas_call inputs, so
-# traced values are fine — only host planning demands concreteness).
-# The launch length is the pow2-padded FULL cell grid, a static shape,
-# so whole fixpoints run inside one `lax.while_loop` / `shard_map` trace
-# with the tail masked by the kernels' `c < nlive` guard.  The device
-# list applies no dst filter and no cross-cell tile reuse (both are
-# inherently sequential host passes), so its exact host oracle is
-# ``WorklistPlanner.plan(gchg, dst_filter=False)``: cells == the dense
-# grid's live count, DMAs == ``tile_needed`` (the no-reuse schedule).
+# j-major by a cumsum-scatter, and fed to the worklist kernels as
+# scalar-prefetch operands (ordinary pallas_call inputs, so traced values
+# are fine — only host planning demands concreteness).  The launch length
+# is static, so whole fixpoints run inside one `lax.while_loop` /
+# `shard_map` trace with the tail masked by the kernels' `c < nlive`
+# guard.  The device list applies no dst filter, so its exact host oracle
+# is ``WorklistPlanner.plan(gchg, dst_filter=False)``: cells == the dense
+# grid's live count.
 
 
 def device_worklist_pad(num_edges: int, num_segments: int) -> int:
     """Static 1-D launch length of the device-compacted worklist: the
-    pow2-padded full (i, j) cell grid.  Round-invariant by construction,
-    so a whole fixpoint traces once."""
+    pow2-padded ``n_chunks + n_sblk``.  Destination-sorted edges put each
+    chunk's ids in one contiguous block range and the ranges of
+    consecutive chunks overlap in at most one block, so the live cells of
+    one sorted edge run never exceed that; other layouts fall back to the
+    dense grid when they do.  Round-invariant, so a fixpoint traces
+    once."""
     n_i = _round_up(num_segments, SBLK) // SBLK
     n_chunks = _round_up(num_edges, EBLK) // EBLK
-    return _wl_pad_len(n_i * n_chunks)
+    return _wl_pad_len(n_chunks + n_i)
 
 
 def _compact_live_cells(chunk_lo, chunk_hi, chunk_act, n_i: int,
                         l_pad: int):
     """Cumsum-scatter frontier compaction: the (n_i, n_chunks) live-cell
     matrix (the dense grid's two-level skip), flattened j-major — the
-    exact cell order ``WorklistPlanner.plan`` emits via
-    ``np.nonzero(live.T)`` — into fixed-shape ``wl_i``/``wl_j`` plus the
-    (1,) live count.  Dead cells scatter out of bounds and are dropped;
-    the padded tail keeps index 0 (cell (0, 0)), never executed."""
+    exact cell order ``WorklistPlanner.plan`` emits — into fixed-shape
+    ``wl_i``/``wl_j`` plus the (1,) live count.  Dead cells, and live
+    cells past ``l_pad``, scatter out of bounds and are dropped; the
+    padded tail keeps index 0 (cell (0, 0)), never executed."""
     n_chunks = chunk_lo.shape[0]
     seg0 = jnp.arange(n_i, dtype=jnp.int32)[:, None] * SBLK
     intersects = (chunk_hi[None, :] >= seg0) & (chunk_lo[None, :]
@@ -1184,327 +638,38 @@ def _compact_live_cells(chunk_lo, chunk_hi, chunk_act, n_i: int,
     return wl_i, wl_j, nlive
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_segments", "l_pad", "vblk", "num_slots"))
+@functools.partial(jax.jit, static_argnames=("num_segments", "l_pad"))
 def _device_worklist_arrays(gchg, edge_src, edge_mask, edge_dst,
-                            num_segments, l_pad, vblk=None,
-                            num_slots=None):
+                            num_segments, l_pad):
     """Jitted device-worklist builder.  ``gchg`` may be (V,) or laned
     (V, Q) — laned frontiers are OR'd across lanes exactly as the host
-    planner plans them.  With ``vblk`` also returns the per-cell tile
-    tables for the tiled kernels: each cell fetches its CHUNK's distinct
-    active-source tiles (the dense mirror's per-chunk lists), slots
-    alternating per position so the double-buffered prefetch stays safe,
-    every tile fetched (no cross-cell reuse)."""
-    e = edge_src.shape[0]
-    e_pad = _round_up(e, EBLK)
-    n_i = _round_up(num_segments, SBLK) // SBLK
-    gchg_i = jnp.asarray(gchg)
-    if gchg_i.ndim == 2:
-        gchg_i = gchg_i.any(axis=-1)
-    gchg_i = gchg_i.astype(jnp.int32)
-    ids_p = jnp.zeros((e_pad,), jnp.int32).at[:e].set(
-        edge_dst.astype(jnp.int32))
-    src_p = jnp.zeros((e_pad,), jnp.int32).at[:e].set(
-        edge_src.astype(jnp.int32))
-    mask_i = jnp.zeros((e_pad,), jnp.int32).at[:e].set(
-        edge_mask.astype(jnp.int32))
-    chunk_lo, chunk_hi, chunk_act, _, src_act = _chunk_tables(
-        ids_p, src_p, mask_i, gchg_i)
-    wl_i, wl_j, nlive = _compact_live_cells(chunk_lo, chunk_hi, chunk_act,
-                                            n_i, l_pad)
-    if vblk is None:
-        return wl_i, wl_j, nlive
-    v_pad = _round_up(num_slots, vblk)
-    ntiles, tiles = _chunk_tile_tables(src_p, src_act, v_pad, vblk)
-    t_max = tiles.shape[1]
-    cell_ntiles = jnp.take(ntiles, wl_j)
-    cell_tile = jnp.take(tiles, wl_j, axis=0)
-    tpos = jnp.arange(t_max, dtype=jnp.int32)[None, :]
-    cell_slot = jnp.broadcast_to(tpos % 2, cell_tile.shape)
-    cell_fetch = jnp.ones(cell_tile.shape, jnp.int32)
-    return (wl_i, wl_j, nlive, cell_ntiles, cell_tile, cell_slot,
-            cell_fetch)
+    planner plans them."""
+    e_pad = _round_up(edge_src.shape[0], EBLK)
+    gchg = jnp.asarray(gchg)
+    if gchg.ndim == 2:
+        gchg = gchg.any(axis=-1)
+    pad = (0, e_pad - edge_src.shape[0])
+    active = jnp.pad(edge_mask & jnp.take(gchg, edge_src), pad)
+    tables = _chunk_tables(jnp.pad(edge_dst.astype(jnp.int32), pad),
+                           jnp.pad(edge_mask, pad), active)
+    return _compact_live_cells(
+        *tables, _round_up(num_segments, SBLK) // SBLK, l_pad)
 
 
 def build_device_worklist(gchg, edge_src, edge_mask, edge_dst,
-                          num_segments: int, path: str, vblk, num_slots):
-    """The ``grid_mode='device_worklist'`` plan: a :class:`Worklist`
-    whose leaves are traced device arrays — works under jit/shard_map,
-    where host planning (``grid_mode='worklist'``) cannot."""
-    l_pad = device_worklist_pad(edge_src.shape[0], num_segments)
-    if path == "tiled":
-        (wl_i, wl_j, nlive, cell_ntiles, cell_tile, cell_slot,
-         cell_fetch) = _device_worklist_arrays(
-            gchg, edge_src, edge_mask, edge_dst,
-            num_segments=num_segments, l_pad=l_pad, vblk=vblk,
-            num_slots=num_slots)
-        return Worklist(wl_i, wl_j, nlive, cell_ntiles, cell_tile,
-                        cell_slot, cell_fetch, path="tiled", vblk=vblk)
-    wl_i, wl_j, nlive = _device_worklist_arrays(
+                          num_segments: int):
+    """The ``grid_mode='device_worklist'`` plan as a :class:`Worklist`
+    whose leaves are traced device arrays, ``device_worklist_pad`` long
+    — works under jit/shard_map, where host planning
+    (``grid_mode='worklist'``) cannot."""
+    return Worklist(*_device_worklist_arrays(
         gchg, edge_src, edge_mask, edge_dst, num_segments=num_segments,
-        l_pad=l_pad)
-    return Worklist(wl_i, wl_j, nlive, path="pinned")
+        l_pad=device_worklist_pad(edge_src.shape[0], num_segments)))
 
 
 # --------------------------------------------------------------------------
-# single-query launches
+# launches
 # --------------------------------------------------------------------------
-
-def _pad_edges(edge_src, edge_w, edge_mask, edge_dst, e_pad: int):
-    e = edge_src.shape[0]
-    ids_p = jnp.zeros((e_pad,), jnp.int32).at[:e].set(
-        edge_dst.astype(jnp.int32))
-    src_p = jnp.zeros((e_pad,), jnp.int32).at[:e].set(
-        edge_src.astype(jnp.int32))
-    w_p = jnp.zeros((e_pad,), edge_w.dtype).at[:e].set(edge_w)
-    mask_i = jnp.zeros((e_pad,), jnp.int32).at[:e].set(
-        edge_mask.astype(jnp.int32))
-    return ids_p, src_p, w_p, mask_i
-
-
-def _masked_value_tables(gval, gchg, identity, v_pad: int, q_pad=None):
-    """Frontier masking folded into the value table (absorbing identity):
-    relax(identity, w) == identity for all supported semirings, so an
-    inactive source can never contribute — bit-identical to the oracle's
-    explicit where(active, ...) mask, one fewer in-cell gather.  Pads
-    slots (and, when ``q_pad`` is given, lanes — tail lanes stay
-    frontier-dead identity columns) to the launch shape."""
-    gval_m = jnp.where(gchg, gval, jnp.asarray(identity, gval.dtype))
-    if q_pad is None:
-        v = gval.shape[0]
-        gval_p = jnp.full((v_pad,), identity, gval.dtype).at[:v].set(gval_m)
-        gchg_p = jnp.zeros((v_pad,), jnp.int32).at[:v].set(
-            gchg.astype(jnp.int32))
-    else:
-        v, q = gval.shape
-        gval_p = jnp.full((v_pad, q_pad), identity, gval.dtype) \
-            .at[:v, :q].set(gval_m)
-        gchg_p = jnp.zeros((v_pad, q_pad), jnp.int32).at[:v, :q].set(
-            gchg.astype(jnp.int32))
-    return gval_p, gchg_p
-
-
-def _pack_result(raw, slicer, msg_count, with_count, with_debug):
-    """Launch epilogue shared by all four wrappers: split off the debug
-    counters, strip the padding (``slicer``), then return out /
-    (out, count) / (out, dbg) / (out, count, dbg)."""
-    out, dbg = raw if with_debug else (raw, None)
-    res = (slicer(out),)
-    if with_count:
-        res += (msg_count,)
-    if with_debug:
-        res += (dbg,)
-    return res[0] if len(res) == 1 else res
-
-
-_DBG_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
-_DBG_SHAPE = jax.ShapeDtypeStruct((2,), jnp.int32)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_segments", "relax_kind", "kind", "interpret",
-                     "with_count", "with_debug"))
-def _fused_pinned(gval, gchg, edge_src, edge_w, edge_mask, edge_dst,
-                  num_segments, relax_kind, kind, interpret, with_count,
-                  with_debug):
-    e = edge_src.shape[0]
-    e_pad = _round_up(e, EBLK)
-    s_pad = _round_up(num_segments, SBLK)
-    v_pad = _round_up(gval.shape[0], 128)
-    identity = jnp.inf if kind == "min" else 0.0
-
-    gval_p, gchg_p = _masked_value_tables(gval, gchg, identity, v_pad)
-    ids_p, src_p, w_p, mask_i = _pad_edges(
-        edge_src, edge_w, edge_mask, edge_dst, e_pad)
-
-    chunk_lo, chunk_hi, chunk_act, msg_count, _ = _chunk_tables(
-        ids_p, src_p, mask_i, gchg_p)
-
-    grid = (s_pad // SBLK, e_pad // EBLK)
-    edge_spec = pl.BlockSpec((EBLK,), lambda i, j, lo, hi, act: (j,))
-    full_spec = pl.BlockSpec((v_pad,), lambda i, j, lo, hi, act: (0,))
-    out_spec = pl.BlockSpec((SBLK,), lambda i, j, lo, hi, act: (i,))
-    out_shape = jax.ShapeDtypeStruct((s_pad,), gval.dtype)
-    if with_debug:
-        out_spec = [out_spec, _DBG_SPEC]
-        out_shape = [out_shape, _DBG_SHAPE]
-    out = pl.pallas_call(
-        functools.partial(_kernel, relax_kind=relax_kind, kind=kind),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[edge_spec, edge_spec, edge_spec, edge_spec,
-                      full_spec],
-            out_specs=out_spec,
-        ),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(chunk_lo, chunk_hi, chunk_act,
-      ids_p, src_p, w_p, mask_i, gval_p)
-    return _pack_result(out, lambda o: o[:num_segments], msg_count,
-                        with_count, with_debug)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_segments", "relax_kind", "kind", "interpret",
-                     "with_count", "with_debug", "vblk"))
-def _fused_tiled(gval, gchg, edge_src, edge_w, edge_mask, edge_dst,
-                 num_segments, relax_kind, kind, interpret, with_count,
-                 with_debug, vblk):
-    e = edge_src.shape[0]
-    e_pad = _round_up(e, EBLK)
-    s_pad = _round_up(num_segments, SBLK)
-    v_pad = _round_up(gval.shape[0], vblk)   # uniform vblk-wide tiles
-    identity = jnp.inf if kind == "min" else 0.0
-
-    gval_p, gchg_p = _masked_value_tables(gval, gchg, identity, v_pad)
-    ids_p, src_p, w_p, mask_i = _pad_edges(
-        edge_src, edge_w, edge_mask, edge_dst, e_pad)
-
-    chunk_lo, chunk_hi, chunk_act, msg_count, src_act = _chunk_tables(
-        ids_p, src_p, mask_i, gchg_p)
-    chunk_ntiles, chunk_tiles = _chunk_tile_tables(
-        src_p, src_act, v_pad, vblk)
-
-    grid = (s_pad // SBLK, e_pad // EBLK)
-    edge_spec = pl.BlockSpec((EBLK,), lambda i, j, *sc: (j,))
-    out_spec = pl.BlockSpec((SBLK,), lambda i, j, *sc: (i,))
-    out_shape = jax.ShapeDtypeStruct((s_pad,), gval.dtype)
-    if with_debug:
-        out_spec = [out_spec, _DBG_SPEC]
-        out_shape = [out_shape, _DBG_SHAPE]
-    out = pl.pallas_call(
-        functools.partial(_kernel_tiled, relax_kind=relax_kind, kind=kind,
-                          vblk=vblk),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=grid,
-            in_specs=[edge_spec, edge_spec, edge_spec, edge_spec,
-                      pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=out_spec,
-            scratch_shapes=[pltpu.VMEM((2, vblk), gval.dtype),
-                            pltpu.SemaphoreType.DMA((2,))],
-        ),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(chunk_lo, chunk_hi, chunk_act, chunk_ntiles, chunk_tiles,
-      ids_p, src_p, w_p, mask_i, gval_p)
-    return _pack_result(out, lambda o: o[:num_segments], msg_count,
-                        with_count, with_debug)
-
-
-def _scatter_partials(partials, wl_i, n_i, kind, identity):
-    """Fold the (l_pad, SBLK[, Q]) per-cell worklist partials into the
-    (n_i, SBLK[, Q]) blocked inbox.  Dead and padded cells hold the
-    combine identity, so scattering every row is exact."""
-    init = jnp.full((n_i,) + partials.shape[1:], identity, partials.dtype)
-    if kind == "min":
-        return init.at[wl_i].min(partials)
-    return init.at[wl_i].add(partials)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_segments", "relax_kind", "kind", "interpret",
-                     "with_count", "with_debug"))
-def _fused_pinned_wl(gval, gchg, edge_src, edge_w, edge_mask, edge_dst,
-                     wl_i, wl_j, nlive, num_segments, relax_kind, kind,
-                     interpret, with_count, with_debug):
-    e = edge_src.shape[0]
-    e_pad = _round_up(e, EBLK)
-    s_pad = _round_up(num_segments, SBLK)
-    v_pad = _round_up(gval.shape[0], 128)
-    identity = jnp.inf if kind == "min" else 0.0
-
-    gval_p, gchg_p = _masked_value_tables(gval, gchg, identity, v_pad)
-    ids_p, src_p, w_p, mask_i = _pad_edges(
-        edge_src, edge_w, edge_mask, edge_dst, e_pad)
-    msg_count = (mask_i * jnp.take(gchg_p, src_p)).sum()
-
-    l_pad = wl_i.shape[0]
-    edge_spec = pl.BlockSpec((EBLK,), lambda c, wi, wj, nl: (wj[c],))
-    full_spec = pl.BlockSpec((v_pad,), lambda c, *sc: (0,))
-    out_spec = pl.BlockSpec((1, SBLK), lambda c, wi, wj, nl: (c, 0))
-    out_shape = jax.ShapeDtypeStruct((l_pad, SBLK), gval.dtype)
-    if with_debug:
-        out_spec = [out_spec, _DBG_SPEC]
-        out_shape = [out_shape, _DBG_SHAPE]
-    out = pl.pallas_call(
-        functools.partial(_kernel_wl, relax_kind=relax_kind, kind=kind),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(l_pad,),
-            in_specs=[edge_spec, edge_spec, edge_spec, edge_spec,
-                      full_spec],
-            out_specs=out_spec,
-        ),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(wl_i, wl_j, nlive, ids_p, src_p, w_p, mask_i, gval_p)
-
-    def slicer(partials):
-        folded = _scatter_partials(partials, wl_i, s_pad // SBLK, kind,
-                                   identity)
-        return folded.reshape(s_pad)[:num_segments]
-
-    return _pack_result(out, slicer, msg_count, with_count, with_debug)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_segments", "relax_kind", "kind", "interpret",
-                     "with_count", "with_debug", "vblk"))
-def _fused_tiled_wl(gval, gchg, edge_src, edge_w, edge_mask, edge_dst,
-                    wl_i, wl_j, nlive, cell_ntiles, cell_tile, cell_slot,
-                    cell_fetch, num_segments, relax_kind, kind, interpret,
-                    with_count, with_debug, vblk):
-    e = edge_src.shape[0]
-    e_pad = _round_up(e, EBLK)
-    s_pad = _round_up(num_segments, SBLK)
-    v_pad = _round_up(gval.shape[0], vblk)   # uniform vblk-wide tiles
-    identity = jnp.inf if kind == "min" else 0.0
-
-    gval_p, gchg_p = _masked_value_tables(gval, gchg, identity, v_pad)
-    ids_p, src_p, w_p, mask_i = _pad_edges(
-        edge_src, edge_w, edge_mask, edge_dst, e_pad)
-    msg_count = (mask_i * jnp.take(gchg_p, src_p)).sum()
-
-    l_pad = wl_i.shape[0]
-    t_max = cell_tile.shape[1]
-    edge_spec = pl.BlockSpec((EBLK,), lambda c, wi, wj, *sc: (wj[c],))
-    out_spec = pl.BlockSpec((1, SBLK), lambda c, wi, wj, *sc: (c, 0))
-    out_shape = jax.ShapeDtypeStruct((l_pad, SBLK), gval.dtype)
-    if with_debug:
-        out_spec = [out_spec, _DBG_SPEC]
-        out_shape = [out_shape, _DBG_SHAPE]
-    out = pl.pallas_call(
-        functools.partial(_kernel_wl_tiled, relax_kind=relax_kind,
-                          kind=kind, vblk=vblk, t_max=t_max),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=7,
-            grid=(l_pad,),
-            in_specs=[edge_spec, edge_spec, edge_spec, edge_spec,
-                      pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=out_spec,
-            scratch_shapes=[pltpu.VMEM((2, vblk), gval.dtype),
-                            pltpu.SemaphoreType.DMA((2,))],
-        ),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(wl_i, wl_j, nlive, cell_ntiles, cell_tile, cell_slot, cell_fetch,
-      ids_p, src_p, w_p, mask_i, gval_p)
-
-    def slicer(partials):
-        folded = _scatter_partials(partials, wl_i, s_pad // SBLK, kind,
-                                   identity)
-        return folded.reshape(s_pad)[:num_segments]
-
-    return _pack_result(out, slicer, msg_count, with_count, with_debug)
-
 
 def _require_concrete(x, what: str):
     if isinstance(x, jax.core.Tracer):
@@ -1514,26 +679,41 @@ def _require_concrete(x, what: str):
             "trace (WorklistPlanner.plan) and pass it via worklist=")
 
 
-def _launch_worklist(gval, gchg, edge_src, edge_w, edge_mask, edge_dst,
-                     num_segments, path, vblk, lane_width=1):
+def _launch_worklist(gchg, edge_src, edge_mask, edge_dst, num_segments):
     """Concrete-input convenience: plan the worklist at launch time (the
     differential tests drive this; round drivers pre-plan instead)."""
     _require_concrete(gchg, "the frontier")
     gchg_np = np.asarray(gchg)
     if gchg_np.ndim == 2:                        # laned: OR across lanes
         gchg_np = gchg_np.any(axis=-1)
-    wl, _ = plan_worklist(
-        np.asarray(edge_dst), np.asarray(edge_mask), np.asarray(edge_src),
-        gchg_np, num_segments, num_slots=gval.shape[0], path=path,
-        vblk=vblk, lane_width=lane_width)
+    wl, _ = plan_worklist(np.asarray(edge_dst), np.asarray(edge_mask),
+                          np.asarray(edge_src), gchg_np, num_segments)
     return wl
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_segments", "relax_kind", "kind", "interpret",
+                     "with_count", "with_debug", "grid_mode"))
+def _fused(gval, gchg, edge_src, edge_w, edge_mask, edge_dst, worklist, *,
+           num_segments, relax_kind, kind, interpret, with_count,
+           with_debug, grid_mode):
+    e_pad = _round_up(edge_src.shape[0], EBLK)
+    ids_p, msg, valid, active = _edge_messages(
+        gval, gchg, edge_src, edge_w, edge_mask, edge_dst, relax_kind,
+        kind, e_pad)
+    raw = _reduce_launch(ids_p, msg, valid, active, num_segments, kind,
+                         False, interpret, with_debug, grid_mode, worklist)
+    return _finish(
+        raw, with_debug,
+        lambda o: o.reshape(-1)[:num_segments].astype(gval.dtype),
+        active.sum(), with_count)
 
 
 def fused_relax_reduce_pallas(gval, gchg, edge_src, edge_w, edge_mask,
                               edge_dst, num_segments: int, relax_kind: str,
-                              kind: str, interpret: bool = True,
+                              kind: str, *, interpret: bool,
                               with_count: bool = False,
-                              vmem_budget_bytes=None, path=None, vblk=None,
                               with_debug: bool = False,
                               grid_mode: str = "dense", worklist=None,
                               smem_budget_bytes=None):
@@ -1544,309 +724,69 @@ def fused_relax_reduce_pallas(gval, gchg, edge_src, edge_w, edge_mask,
     [0, num_segments); edge_w: (E,) f32; edge_mask: (E,) bool (False on
     padding). Returns the (num_segments,) inbox partial — empty segments
     hold the combine identity.  ``with_count=True`` appends the
-    active-edge count (a byproduct of the frontier bitmap gather, not an
-    extra pass); ``with_debug=True`` appends the kernel-side (2,) int32
-    [executed grid cells, issued value-tile DMAs] counters that
-    ``fused_grid_cells`` mirrors on the host.  Edges should be sorted by
-    ``edge_dst`` for the range skip to bite; correctness never depends
-    on the sort.
-
-    Residency is selected by ``select_kernel_path`` from the slot count
-    against ``vmem_budget_bytes`` (pinned when the table fits, else
-    HBM-tiled with per-cell double-buffered DMA); ``path``/``vblk``
-    force it.  Both paths are bit-identical for min semirings.
+    active-edge count (a reduction of the frontier mask the launch builds
+    anyway); ``with_debug=True`` appends the kernel-side (1,) int32
+    executed-grid-cell counter that ``fused_grid_cells`` mirrors on the
+    host.  Edges should be sorted by ``edge_dst`` for the range skip to
+    bite; correctness never depends on the sort.  ``interpret`` is
+    required: ``kernels.ops`` makes the one backend-based choice.
 
     ``grid_mode='worklist'`` (or an explicit ``worklist=`` plan) swaps
-    the dense early-exit grid for the 1-D live-cell worklist launch —
-    the launch count, and on the tiled path the dst-filtered reuse-aware
-    DMA schedule, scale with the live frontier.  Bit-identical to the
-    dense grid for min semirings (sum differs only by the partial
-    scatter's reassociation).  ``smem_budget_bytes`` arms the
-    scalar-prefetch table guard in ``select_kernel_path``.
+    the dense early-exit grid for the 1-D live-cell worklist launch, and
+    ``grid_mode='device_worklist'`` compacts that list on device (traced
+    — works inside jit/shard_map loops).  Bit-identical to the dense grid
+    for min semirings (sum differs only by the partial scatter's
+    reassociation).  ``smem_budget_bytes`` arms the scalar-prefetch
+    table guard.
     """
     _check_pair(relax_kind, kind)
-    e_pad = _round_up(edge_src.shape[0], EBLK)
-    path, vblk = select_kernel_path(
-        gval.shape[0], 1, vmem_budget_bytes, path=path, vblk=vblk,
-        n_chunks=e_pad // EBLK, smem_budget_bytes=smem_budget_bytes)
+    n_chunks = _round_up(edge_src.shape[0], EBLK) // EBLK
     if worklist is None and grid_mode == "worklist":
-        worklist = _launch_worklist(
-            gval, gchg, edge_src, edge_w, edge_mask, edge_dst,
-            num_segments, path, vblk)
-    elif worklist is None and grid_mode == "device_worklist":
-        worklist = build_device_worklist(
-            gchg, edge_src, edge_mask, edge_dst, num_segments, path, vblk,
-            gval.shape[0])
-    args = (gval, gchg, edge_src, edge_w, edge_mask, edge_dst)
-    if worklist is not None:
-        wl = worklist
-        if wl.path == "pinned":
-            return _fused_pinned_wl(
-                *args, jnp.asarray(wl.wl_i), jnp.asarray(wl.wl_j),
-                jnp.asarray(wl.nlive), num_segments=num_segments,
-                relax_kind=relax_kind, kind=kind, interpret=interpret,
-                with_count=with_count, with_debug=with_debug)
-        return _fused_tiled_wl(
-            *args, jnp.asarray(wl.wl_i), jnp.asarray(wl.wl_j),
-            jnp.asarray(wl.nlive), jnp.asarray(wl.cell_ntiles),
-            jnp.asarray(wl.cell_tile), jnp.asarray(wl.cell_slot),
-            jnp.asarray(wl.cell_fetch), num_segments=num_segments,
-            relax_kind=relax_kind, kind=kind, interpret=interpret,
-            with_count=with_count, with_debug=with_debug, vblk=wl.vblk)
-    if path == "pinned":
-        return _fused_pinned(*args, num_segments=num_segments,
-                             relax_kind=relax_kind, kind=kind,
-                             interpret=interpret, with_count=with_count,
-                             with_debug=with_debug)
-    return _fused_tiled(*args, num_segments=num_segments,
-                        relax_kind=relax_kind, kind=kind,
-                        interpret=interpret, with_count=with_count,
-                        with_debug=with_debug, vblk=vblk)
-
-
-# --------------------------------------------------------------------------
-# lane-batched launches
-# --------------------------------------------------------------------------
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_segments", "relax_kind", "kind", "interpret",
-                     "with_count", "with_debug", "q_pad"))
-def _fused_lanes_pinned(gval, gchg, lane_unitw, edge_src, edge_w, edge_mask,
-                        edge_dst, num_segments, relax_kind, kind, interpret,
-                        with_count, with_debug, q_pad):
-    v, q = gval.shape
-    e = edge_src.shape[0]
-    e_pad = _round_up(e, EBLK)
-    s_pad = _round_up(num_segments, SBLK)
-    v_pad = _round_up(v, 128)
-    identity = jnp.inf if kind == "min" else 0.0
-
-    # lane padding: tail lanes hold the identity with an all-False
-    # frontier, so they relax to the identity everywhere and are sliced
-    # off the output — masked tail lanes, bit-identical to no padding
-    gval_p, gchg_p = _masked_value_tables(gval, gchg, identity, v_pad,
-                                          q_pad)
-    ids_p, src_p, w_p, mask_i = _pad_edges(
-        edge_src, edge_w, edge_mask, edge_dst, e_pad)
-    unitw = jnp.zeros((q_pad,), jnp.int32).at[:q].set(
-        jnp.asarray(lane_unitw, jnp.int32).reshape(q))
-
-    chunk_lo, chunk_hi, chunk_act, msg_counts, _ = _chunk_tables_lanes(
-        ids_p, src_p, mask_i, gchg_p)
-
-    grid = (s_pad // SBLK, e_pad // EBLK)
-    edge_spec = pl.BlockSpec((EBLK,), lambda i, j, lo, hi, act: (j,))
-    lane_spec = pl.BlockSpec((q_pad,), lambda i, j, lo, hi, act: (0,))
-    full_spec = pl.BlockSpec((v_pad, q_pad),
-                             lambda i, j, lo, hi, act: (0, 0))
-    out_spec = pl.BlockSpec((SBLK, q_pad), lambda i, j, lo, hi, act: (i, 0))
-    out_shape = jax.ShapeDtypeStruct((s_pad, q_pad), gval.dtype)
-    if with_debug:
-        out_spec = [out_spec, _DBG_SPEC]
-        out_shape = [out_shape, _DBG_SHAPE]
-    out = pl.pallas_call(
-        functools.partial(_kernel_lanes, relax_kind=relax_kind, kind=kind),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[edge_spec, edge_spec, edge_spec, edge_spec,
-                      lane_spec, full_spec],
-            out_specs=out_spec,
-        ),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(chunk_lo, chunk_hi, chunk_act,
-      ids_p, src_p, w_p, mask_i, unitw, gval_p)
-    return _pack_result(out, lambda o: o[:num_segments, :q],
-                        msg_counts[:q], with_count, with_debug)
+        worklist = _launch_worklist(gchg, edge_src, edge_mask, edge_dst,
+                                    num_segments)
+    _warn_smem(n_chunks, 0 if worklist is None else worklist.l_pad,
+               smem_budget_bytes)
+    return _fused(gval, gchg, edge_src, edge_w, edge_mask, edge_dst,
+                  worklist, num_segments=num_segments,
+                  relax_kind=relax_kind, kind=kind, interpret=interpret,
+                  with_count=with_count, with_debug=with_debug,
+                  grid_mode=grid_mode)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("num_segments", "relax_kind", "kind", "interpret",
-                     "with_count", "with_debug", "q_pad", "vblk"))
-def _fused_lanes_tiled(gval, gchg, lane_unitw, edge_src, edge_w, edge_mask,
-                       edge_dst, num_segments, relax_kind, kind, interpret,
-                       with_count, with_debug, q_pad, vblk):
-    v, q = gval.shape
-    e = edge_src.shape[0]
-    e_pad = _round_up(e, EBLK)
-    s_pad = _round_up(num_segments, SBLK)
-    v_pad = _round_up(v, vblk)
-    identity = jnp.inf if kind == "min" else 0.0
+                     "with_count", "with_debug", "grid_mode", "q_pad"))
+def _fused_lanes(gval, gchg, lane_unitw, edge_src, edge_w, edge_mask,
+                 edge_dst, worklist, *, num_segments, relax_kind, kind,
+                 interpret, with_count, with_debug, grid_mode, q_pad):
+    q = gval.shape[1]
+    e_pad = _round_up(edge_src.shape[0], EBLK)
+    ids_p, msg, valid, active, counts = _lane_messages(
+        gval, gchg, lane_unitw, edge_src, edge_w, edge_mask, edge_dst,
+        relax_kind, kind, e_pad, q_pad)
+    raw = _reduce_launch(ids_p, msg, valid, active, num_segments, kind,
+                         True, interpret, with_debug, grid_mode, worklist)
 
-    gval_p, gchg_p = _masked_value_tables(gval, gchg, identity, v_pad,
-                                          q_pad)
-    ids_p, src_p, w_p, mask_i = _pad_edges(
-        edge_src, edge_w, edge_mask, edge_dst, e_pad)
-    unitw = jnp.zeros((q_pad,), jnp.int32).at[:q].set(
-        jnp.asarray(lane_unitw, jnp.int32).reshape(q))
+    def unblock(out):                    # (n_i, q_pad, SBLK) -> (S, Q)
+        flat = out.transpose(0, 2, 1).reshape(-1, q_pad)
+        return flat[:num_segments, :q].astype(gval.dtype)
 
-    chunk_lo, chunk_hi, chunk_act, msg_counts, src_act = \
-        _chunk_tables_lanes(ids_p, src_p, mask_i, gchg_p)
-    chunk_ntiles, chunk_tiles = _chunk_tile_tables(
-        src_p, src_act, v_pad, vblk)
-
-    grid = (s_pad // SBLK, e_pad // EBLK)
-    edge_spec = pl.BlockSpec((EBLK,), lambda i, j, *sc: (j,))
-    lane_spec = pl.BlockSpec((q_pad,), lambda i, j, *sc: (0,))
-    out_spec = pl.BlockSpec((SBLK, q_pad), lambda i, j, *sc: (i, 0))
-    out_shape = jax.ShapeDtypeStruct((s_pad, q_pad), gval.dtype)
-    if with_debug:
-        out_spec = [out_spec, _DBG_SPEC]
-        out_shape = [out_shape, _DBG_SHAPE]
-    out = pl.pallas_call(
-        functools.partial(_kernel_tiled_lanes, relax_kind=relax_kind,
-                          kind=kind, vblk=vblk),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=grid,
-            in_specs=[edge_spec, edge_spec, edge_spec, edge_spec,
-                      lane_spec, pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=out_spec,
-            scratch_shapes=[pltpu.VMEM((2, vblk, q_pad), gval.dtype),
-                            pltpu.SemaphoreType.DMA((2,))],
-        ),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(chunk_lo, chunk_hi, chunk_act, chunk_ntiles, chunk_tiles,
-      ids_p, src_p, w_p, mask_i, unitw, gval_p)
-    return _pack_result(out, lambda o: o[:num_segments, :q],
-                        msg_counts[:q], with_count, with_debug)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_segments", "relax_kind", "kind", "interpret",
-                     "with_count", "with_debug", "q_pad"))
-def _fused_lanes_pinned_wl(gval, gchg, lane_unitw, edge_src, edge_w,
-                           edge_mask, edge_dst, wl_i, wl_j, nlive,
-                           num_segments, relax_kind, kind, interpret,
-                           with_count, with_debug, q_pad):
-    v, q = gval.shape
-    e = edge_src.shape[0]
-    e_pad = _round_up(e, EBLK)
-    s_pad = _round_up(num_segments, SBLK)
-    v_pad = _round_up(v, 128)
-    identity = jnp.inf if kind == "min" else 0.0
-
-    gval_p, gchg_p = _masked_value_tables(gval, gchg, identity, v_pad,
-                                          q_pad)
-    ids_p, src_p, w_p, mask_i = _pad_edges(
-        edge_src, edge_w, edge_mask, edge_dst, e_pad)
-    unitw = jnp.zeros((q_pad,), jnp.int32).at[:q].set(
-        jnp.asarray(lane_unitw, jnp.int32).reshape(q))
-    msg_counts = (mask_i[:, None] * jnp.take(gchg_p, src_p, axis=0)) \
-        .sum(axis=0)
-
-    l_pad = wl_i.shape[0]
-    edge_spec = pl.BlockSpec((EBLK,), lambda c, wi, wj, nl: (wj[c],))
-    lane_spec = pl.BlockSpec((q_pad,), lambda c, *sc: (0,))
-    full_spec = pl.BlockSpec((v_pad, q_pad), lambda c, *sc: (0, 0))
-    out_spec = pl.BlockSpec((1, SBLK, q_pad),
-                            lambda c, wi, wj, nl: (c, 0, 0))
-    out_shape = jax.ShapeDtypeStruct((l_pad, SBLK, q_pad), gval.dtype)
-    if with_debug:
-        out_spec = [out_spec, _DBG_SPEC]
-        out_shape = [out_shape, _DBG_SHAPE]
-    out = pl.pallas_call(
-        functools.partial(_kernel_wl_lanes, relax_kind=relax_kind,
-                          kind=kind),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(l_pad,),
-            in_specs=[edge_spec, edge_spec, edge_spec, edge_spec,
-                      lane_spec, full_spec],
-            out_specs=out_spec,
-        ),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(wl_i, wl_j, nlive, ids_p, src_p, w_p, mask_i, unitw, gval_p)
-
-    def slicer(partials):
-        folded = _scatter_partials(partials, wl_i, s_pad // SBLK, kind,
-                                   identity)
-        return folded.reshape(s_pad, q_pad)[:num_segments, :q]
-
-    return _pack_result(out, slicer, msg_counts[:q], with_count,
-                        with_debug)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_segments", "relax_kind", "kind", "interpret",
-                     "with_count", "with_debug", "q_pad", "vblk"))
-def _fused_lanes_tiled_wl(gval, gchg, lane_unitw, edge_src, edge_w,
-                          edge_mask, edge_dst, wl_i, wl_j, nlive,
-                          cell_ntiles, cell_tile, cell_slot, cell_fetch,
-                          num_segments, relax_kind, kind, interpret,
-                          with_count, with_debug, q_pad, vblk):
-    v, q = gval.shape
-    e = edge_src.shape[0]
-    e_pad = _round_up(e, EBLK)
-    s_pad = _round_up(num_segments, SBLK)
-    v_pad = _round_up(v, vblk)
-    identity = jnp.inf if kind == "min" else 0.0
-
-    gval_p, gchg_p = _masked_value_tables(gval, gchg, identity, v_pad,
-                                          q_pad)
-    ids_p, src_p, w_p, mask_i = _pad_edges(
-        edge_src, edge_w, edge_mask, edge_dst, e_pad)
-    unitw = jnp.zeros((q_pad,), jnp.int32).at[:q].set(
-        jnp.asarray(lane_unitw, jnp.int32).reshape(q))
-    msg_counts = (mask_i[:, None] * jnp.take(gchg_p, src_p, axis=0)) \
-        .sum(axis=0)
-
-    l_pad = wl_i.shape[0]
-    t_max = cell_tile.shape[1]
-    edge_spec = pl.BlockSpec((EBLK,), lambda c, wi, wj, *sc: (wj[c],))
-    lane_spec = pl.BlockSpec((q_pad,), lambda c, *sc: (0,))
-    out_spec = pl.BlockSpec((1, SBLK, q_pad),
-                            lambda c, wi, wj, *sc: (c, 0, 0))
-    out_shape = jax.ShapeDtypeStruct((l_pad, SBLK, q_pad), gval.dtype)
-    if with_debug:
-        out_spec = [out_spec, _DBG_SPEC]
-        out_shape = [out_shape, _DBG_SHAPE]
-    out = pl.pallas_call(
-        functools.partial(_kernel_wl_tiled_lanes, relax_kind=relax_kind,
-                          kind=kind, vblk=vblk, t_max=t_max),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=7,
-            grid=(l_pad,),
-            in_specs=[edge_spec, edge_spec, edge_spec, edge_spec,
-                      lane_spec, pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=out_spec,
-            scratch_shapes=[pltpu.VMEM((2, vblk, q_pad), gval.dtype),
-                            pltpu.SemaphoreType.DMA((2,))],
-        ),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(wl_i, wl_j, nlive, cell_ntiles, cell_tile, cell_slot, cell_fetch,
-      ids_p, src_p, w_p, mask_i, unitw, gval_p)
-
-    def slicer(partials):
-        folded = _scatter_partials(partials, wl_i, s_pad // SBLK, kind,
-                                   identity)
-        return folded.reshape(s_pad, q_pad)[:num_segments, :q]
-
-    return _pack_result(out, slicer, msg_counts[:q], with_count,
-                        with_debug)
+    return _finish(raw, with_debug, unblock, counts, with_count)
 
 
 def fused_relax_reduce_lanes_pallas(gval, gchg, lane_unitw, edge_src, edge_w,
                                     edge_mask, edge_dst, num_segments: int,
-                                    relax_kind: str, kind: str,
-                                    interpret: bool = True,
+                                    relax_kind: str, kind: str, *,
+                                    interpret: bool,
                                     with_count: bool = False,
-                                    vmem_budget_bytes=None, path=None,
-                                    vblk=None, lane_tile=None,
+                                    lane_tile=None,
                                     with_debug: bool = False,
                                     grid_mode: str = "dense",
                                     worklist=None, smem_budget_bytes=None):
-    """Lane-batched fused gather/relax/mask/segment-reduce (ISSUE 2).
+    """Lane-batched fused gather/relax/mask/segment-reduce.
 
-    The single-query kernel grown a trailing query-lane axis ``Q``:
+    The single-query kernel grown a query-lane axis ``Q``:
     ``gval``/``gchg`` are (V, Q) — per-lane values and per-lane frontiers
     over one shared edge structure — and the result is the (num_segments,
     Q) per-lane inbox partial (plus, with ``with_count=True``, the (Q,)
@@ -1854,71 +794,40 @@ def fused_relax_reduce_lanes_pallas(gval, gchg, lane_unitw, edge_src, edge_w,
     ``relax_kind='add_w'``: lanes with a nonzero flag relax with the
     constant weight 1.0 (BFS levels) instead of the edge weight (SSSP), so
     one launch serves a mixed BFS/SSSP batch.  A converged lane has an
-    all-False ``gchg`` column: its sources read as the absorbing identity,
-    so it contributes nothing while live lanes keep the round busy — and
-    the chunk-skip bitmap is the OR across lanes, so a grid cell is
-    skipped only when its edge chunk is frontier-dead in *every* lane.
+    all-False ``gchg`` column, so it contributes nothing while live lanes
+    keep the round busy — and the chunk-skip bitmap is the OR across
+    lanes, so a grid cell is skipped only when its edge chunk is
+    frontier-dead in *every* lane.
 
-    The lane axis is padded up to the TPU lane tile (``LANE_TILE=128``
-    when compiling; a sublane multiple under interpret mode — force with
-    ``lane_tile=``): tail lanes carry the identity with an all-False
-    frontier, so they are masked out of every reduction and sliced off
-    the output — a padded batch is bit-identical to the unpadded math.
-    Residency (pinned vs HBM-tiled with per-cell double-buffered DMA of
-    (vblk, Q) value tiles) follows ``select_kernel_path`` on the
-    lane-padded table, exactly as in the single-query kernel.
+    The lane axis is padded to a multiple of ``LANE_PAD`` (or of
+    ``lane_tile``): tail lanes carry the identity, so they are masked out
+    of every reduction and sliced off the output — a padded batch is
+    bit-identical to the unpadded math.  Grid modes as in
+    ``fused_relax_reduce_pallas``.
     """
     assert relax_kind in ("add_w", "mul_w"), relax_kind
     _check_pair(relax_kind, kind)
-    v, q = gval.shape
-    q_pad = _lane_pad(q, interpret, lane_tile)
-    e_pad = _round_up(edge_src.shape[0], EBLK)
-    path, vblk = select_kernel_path(
-        v, q_pad, vmem_budget_bytes, path=path, vblk=vblk,
-        n_chunks=e_pad // EBLK, smem_budget_bytes=smem_budget_bytes)
+    q_pad = _lane_pad(gval.shape[1], lane_tile)
+    n_chunks = _round_up(edge_src.shape[0], EBLK) // EBLK
     if worklist is None and grid_mode == "worklist":
-        worklist = _launch_worklist(
-            gval, gchg, edge_src, edge_w, edge_mask, edge_dst,
-            num_segments, path, vblk, lane_width=q_pad)
-    elif worklist is None and grid_mode == "device_worklist":
-        worklist = build_device_worklist(
-            gchg, edge_src, edge_mask, edge_dst, num_segments, path, vblk,
-            v)
-    args = (gval, gchg, lane_unitw, edge_src, edge_w, edge_mask, edge_dst)
-    if worklist is not None:
-        wl = worklist
-        if wl.path == "pinned":
-            return _fused_lanes_pinned_wl(
-                *args, jnp.asarray(wl.wl_i), jnp.asarray(wl.wl_j),
-                jnp.asarray(wl.nlive), num_segments=num_segments,
-                relax_kind=relax_kind, kind=kind, interpret=interpret,
-                with_count=with_count, with_debug=with_debug, q_pad=q_pad)
-        return _fused_lanes_tiled_wl(
-            *args, jnp.asarray(wl.wl_i), jnp.asarray(wl.wl_j),
-            jnp.asarray(wl.nlive), jnp.asarray(wl.cell_ntiles),
-            jnp.asarray(wl.cell_tile), jnp.asarray(wl.cell_slot),
-            jnp.asarray(wl.cell_fetch), num_segments=num_segments,
-            relax_kind=relax_kind, kind=kind, interpret=interpret,
-            with_count=with_count, with_debug=with_debug, q_pad=q_pad,
-            vblk=wl.vblk)
-    if path == "pinned":
-        return _fused_lanes_pinned(
-            *args, num_segments=num_segments, relax_kind=relax_kind,
-            kind=kind, interpret=interpret, with_count=with_count,
-            with_debug=with_debug, q_pad=q_pad)
-    return _fused_lanes_tiled(
-        *args, num_segments=num_segments, relax_kind=relax_kind, kind=kind,
-        interpret=interpret, with_count=with_count, with_debug=with_debug,
-        q_pad=q_pad, vblk=vblk)
+        worklist = _launch_worklist(gchg, edge_src, edge_mask, edge_dst,
+                                    num_segments)
+    _warn_smem(n_chunks, 0 if worklist is None else worklist.l_pad,
+               smem_budget_bytes)
+    return _fused_lanes(gval, gchg, lane_unitw, edge_src, edge_w, edge_mask,
+                        edge_dst, worklist, num_segments=num_segments,
+                        relax_kind=relax_kind, kind=kind,
+                        interpret=interpret, with_count=with_count,
+                        with_debug=with_debug, grid_mode=grid_mode,
+                        q_pad=q_pad)
 
 
 # --------------------------------------------------------------------------
-# host-side launch mirror (grid-cell and DMA accounting)
+# host-side launch mirror (grid-cell accounting)
 # --------------------------------------------------------------------------
 
 def fused_grid_cells(edge_dst, edge_mask, edge_src, gchg,
-                     num_segments: int, vblk: int | None = None,
-                     lane_width: int = 1, grid_mode: str = "dense",
+                     num_segments: int, grid_mode: str = "dense",
                      pad_to: int = WL_PAD, dst_filter: bool = True) -> dict:
     """Host-side mirror of both launch shapes for the dense exchange.
 
@@ -1930,47 +839,34 @@ def fused_grid_cells(edge_dst, edge_mask, edge_src, gchg,
     edges carrying id 0 widen chunk ranges) and which has no frontier
     skip.  Edge arrays are (S, E_max) host arrays — or 1-D for a single
     flat launch; ``gchg`` is the (V,) frontier (OR across lanes when
-    mirroring a laned launch).
-
-    With ``vblk`` the dict also mirrors the tiled path's DMA plan:
-    ``chunk_ntiles`` (per edge chunk, the number of distinct vblk-wide
-    slot tiles its frontier-active sources touch), ``fused_tile_dmas``
-    (tile fetches summed over live cells — every live (i, j) cell
-    fetches its chunk's tiles), and ``dma_bytes`` (those fetches *
-    vblk * lane_width * 4 bytes).  The cell/DMA *counts* must match the
-    kernels' ``with_debug`` counters exactly; for the byte column of a
-    laned launch, pass the lane-PADDED width (``_lane_pad`` of Q — the
-    kernel DMAs (vblk, q_pad) tiles), not the logical lane count.
+    mirroring a laned launch).  The cell counts must match the kernels'
+    ``with_debug`` counter exactly.
     """
     edge_dst = np.atleast_2d(np.asarray(edge_dst))
     edge_mask = np.atleast_2d(np.asarray(edge_mask))
     edge_src = np.atleast_2d(np.asarray(edge_src))
     gchg = np.asarray(gchg).reshape(-1)
     S, E_max = edge_dst.shape
-    s_pad = -(-num_segments // SBLK) * SBLK
+    s_pad = _round_up(num_segments, SBLK)
     seg0 = np.arange(s_pad // SBLK)[:, None] * SBLK        # (n_i, 1)
 
     # fused: one launch over the flattened edge stack
     e = S * E_max
-    e_pad = -(-e // EBLK) * EBLK
+    e_pad = _round_up(e, EBLK)
     ids = np.zeros(e_pad, np.int64)
     ids[:e] = edge_dst.reshape(-1)
     msk = np.zeros(e_pad, bool)
     msk[:e] = edge_mask.reshape(-1)
     act = np.zeros(e_pad, bool)
     act[:e] = edge_mask.reshape(-1) & gchg[edge_src.reshape(-1)]
-    srcs = np.zeros(e_pad, np.int64)
-    srcs[:e] = edge_src.reshape(-1)
     idc, mkc, acc = (x.reshape(e_pad // EBLK, EBLK) for x in (ids, msk, act))
     lo = np.where(mkc, idc, np.iinfo(np.int64).max).min(axis=1)
     hi = np.where(mkc, idc, -1).max(axis=1)
     intersects = (hi[None, :] >= seg0) & (lo[None, :] < seg0 + SBLK)
-    live_mat = intersects & acc.any(axis=1)[None, :]       # (n_i, n_j)
-    fused_live = int(live_mat.sum())
-    total_fused = int(intersects.size)
+    fused_live = int((intersects & acc.any(axis=1)[None, :]).sum())
 
     # unfused: S per-shard launches, positional validity, range skip only
-    ep = -(-E_max // EBLK) * EBLK
+    ep = _round_up(E_max, EBLK)
     ids_s = np.zeros((S, ep), np.int64)
     ids_s[:, :E_max] = edge_dst
     valid = np.zeros(ep, bool)
@@ -1982,54 +878,26 @@ def fused_grid_cells(edge_dst, edge_mask, edge_src, gchg,
     inter2 = (hi2[:, None, :] >= seg0[None, :, :]) \
         & (lo2[:, None, :] < seg0[None, :, :] + SBLK)        # (S, n_i, n_j)
     out = {
-        "total_fused": total_fused,
+        "total_fused": int(intersects.size),
         "total_unfused": int(inter2.size),
         "range_live": int(inter2.sum()),
         "fused_live": fused_live,
     }
-    if vblk is not None:
-        # tiled-path DMA mirror: distinct source tiles per chunk among
-        # frontier-active valid edges, fetched once per live (i, j) cell
-        tile_of = (srcs // vblk).reshape(e_pad // EBLK, EBLK)
-        ntiles = np.array([len(np.unique(t[a])) for t, a in
-                           zip(tile_of, acc)], np.int64)
-        tile_dmas = int((live_mat * ntiles[None, :]).sum())
-        out["chunk_ntiles"] = ntiles.tolist()
-        out["fused_tile_dmas"] = tile_dmas
-        out["dma_bytes"] = tile_dmas * int(vblk) * int(lane_width) * 4
     if grid_mode == "worklist":
         # worklist-launch mirror: the planner is the host-side oracle —
-        # cells launched (after the dst-filter empty-cell drop) and the
-        # reuse-aware DMA schedule, matched EXACTLY by the worklist
-        # kernels' with_debug counters
-        _, info = plan_worklist(
-            edge_dst, edge_mask, edge_src, gchg, num_segments,
-            num_slots=gchg.shape[0],
-            path="tiled" if vblk is not None else "pinned", vblk=vblk,
-            lane_width=lane_width, pad_to=pad_to, dst_filter=dst_filter)
+        # cells launched after the dst-filter empty-cell drop, matched
+        # EXACTLY by the worklist kernels' with_debug counter
+        _, info = plan_worklist(edge_dst, edge_mask, edge_src, gchg,
+                                num_segments, pad_to=pad_to,
+                                dst_filter=dst_filter)
         out["wl_cells"] = info.cells
         out["wl_launched"] = info.launched
-        out["wl_tile_dmas"] = info.tile_dmas
-        out["wl_tile_needed"] = info.tile_needed
-        out["wl_dma_bytes"] = info.dma_bytes
         out["smem_table_bytes"] = info.smem_table_bytes
     elif grid_mode == "device_worklist":
-        # device-compaction mirror: no dst filter, no cross-cell reuse —
-        # cells are exactly the dense grid's live count and DMAs the
-        # per-chunk tile lists summed over live cells (the no-reuse
-        # schedule), matched by the kernels' with_debug counters
+        # device-compaction mirror: no dst filter — cells are exactly the
+        # dense grid's live count (the dense fallback runs the same cells)
         out["wl_cells"] = fused_live
         out["wl_launched"] = device_worklist_pad(e, num_segments)
-        out["wl_tile_dmas"] = out.get("fused_tile_dmas", 0)
-        out["wl_tile_needed"] = out.get("fused_tile_dmas", 0)
-        out["wl_dma_bytes"] = out.get("dma_bytes", 0)
-        out["smem_table_bytes"] = smem_table_bytes(
-            e_pad // EBLK,
-            0 if vblk is None
-            else min(_round_up(int(gchg.shape[0]), vblk) // vblk, EBLK),
-            out["wl_launched"])
-    elif vblk is not None:
-        out["smem_table_bytes"] = smem_table_bytes(
-            e_pad // EBLK,
-            min(_round_up(int(gchg.shape[0]), vblk) // vblk, EBLK))
+        out["smem_table_bytes"] = smem_table_bytes(e_pad // EBLK,
+                                                   out["wl_launched"])
     return out

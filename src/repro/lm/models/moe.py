@@ -155,7 +155,6 @@ def apply_moe_shardmap(p, cfg, x, ctx: ShardCtx):
     produces a partial (Tg, d) token-sum, and one bf16 psum over tp
     finishes the combine. Expert weights stay FSDP'd over dp (manual
     all-gather inside; AD gives the reduce-scatter wgrad)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
@@ -215,14 +214,14 @@ def apply_moe_shardmap(p, cfg, x, ctx: ShardCtx):
         out = lax.psum(part, tp_axes)               # one bf16 (Tg,d) reduce
         return out[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         ffn, mesh=ctx.mesh,
         in_specs=(P(dp_axes, None, None), P(dp_axes, None, None),
                   P(dp_axes, None, None),
                   P(tp_axes, dp_axes, None), P(tp_axes, dp_axes, None),
                   P(tp_axes, None, dp_axes)),
         out_specs=P(dp_axes, None, None),
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(xg, eidx, gate_vals, p["w_gate"], p["w_up"], p["w_down"])
     out = out.reshape(B, S, d)

@@ -7,7 +7,7 @@ path is a single attribute read, adds no host↔device syncs, and leaves
 every jit trace untouched (pinned by the obs-off parity test).
 
 Enabled, the engine's host-driven round loops append one
-:class:`RoundRecord` per round whose grid-cell / DMA columns come from
+:class:`RoundRecord` per round whose grid-cell columns come from
 the same host planner mirror the differential harness asserts against
 the kernels' ``with_debug`` counters — so the telemetry itself is held
 to the PR 4/5 exact-counter bar.  ``save(path)`` writes a session JSON
@@ -28,7 +28,7 @@ from repro.obs.trace import Tracer
 class RoundRecord:
     """One engine round, as accounted by the host planner mirror.
 
-    ``cells``/``launched``/``tile_dmas``/``dma_bytes`` are the planner
+    ``cells``/``launched`` are the planner
     mirror of the actual launch (worklist: ``WorklistInfo``; dense grid:
     the two-level-skip live count) — zero on non-fused paths, where no
     Pallas grid exists.  ``shard_messages`` is the per-shard live-edge
@@ -38,7 +38,7 @@ class RoundRecord:
     K-round dispatch window: ``window`` is the 1-based window index
     (0 = host-driven per-round record), ``round`` the cumulative round
     count at window end, and the additive columns (messages, work,
-    cells, DMA…) are summed over the window's live rounds — so window
+    cells…) are summed over the window's live rounds — so window
     sums equal the per-round host-driven totals exactly."""
 
     run: str             # which runner/app emitted this round
@@ -48,11 +48,9 @@ class RoundRecord:
     work: int            # predicate-true slot updates
     pruned: int          # delivered but predicate-false
     grid: str            # 'dense' | 'worklist'
-    path: str            # 'pinned' | 'tiled' | 'reduce' | 'jnp'
+    path: str            # 'fused' | 'reduce' | 'jnp'
     cells: int           # live grid cells (planner mirror)
     launched: int        # launched cells (dense: total grid; wl: padded)
-    tile_dmas: int       # value-tile DMAs (tiled path only)
-    dma_bytes: int
     wall_s: float
     shard_messages: list | None = None
     window: int = 0      # dispatch-window index (0 = per-round record)
@@ -105,9 +103,6 @@ class FlightRecorder:
         m.counter("engine_grid_cells_total",
                   "live fused-grid cells (planner mirror)"
                   ).labels(run=run).inc(record.cells)
-        m.counter("engine_dma_bytes_total",
-                  "value-tile DMA bytes (planner mirror)"
-                  ).labels(run=run).inc(record.dma_bytes)
         m.gauge("engine_frontier",
                 "live slots entering the last round"
                 ).labels(run=run).set(record.frontier)

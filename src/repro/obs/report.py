@@ -2,8 +2,8 @@
 
 ``python -m repro.obs.report session.json`` prints, per recorded run:
 rounds, the live-frontier trajectory, messages (delivered / pruned),
-exact grid cells and DMA bytes (the planner mirror), the kernel path
-chosen (pinned/tiled × dense/worklist), wall time, and the per-shard
+exact grid cells (the planner mirror), the kernel path chosen
+(dense/worklist × fused/reduce/jnp), wall time, and the per-shard
 message skew (max/mean, 1.0 = perfectly balanced) — then the serving
 counters (request statuses, cache hits/misses/invalidations,
 preemptions, queue depth) when a server ran under the same recorder.
@@ -16,14 +16,6 @@ from __future__ import annotations
 import sys
 
 from repro.obs.record import _skew, load_session
-
-
-def _fmt_bytes(n: float) -> str:
-    for unit in ("B", "KiB", "MiB", "GiB"):
-        if abs(n) < 1024 or unit == "GiB":
-            return f"{n:.1f}{unit}" if unit != "B" else f"{int(n)}B"
-        n /= 1024
-    return f"{n:.1f}GiB"
 
 
 def _metric_series(session: dict, name: str) -> list:
@@ -54,14 +46,13 @@ def render(session: dict) -> str:
         msgs = sum(r["messages"] for r in rows)
         pruned = sum(r["pruned"] for r in rows)
         cells = sum(r["cells"] for r in rows)
-        dma = sum(r["dma_bytes"] for r in rows)
         wall = sum(r["wall_s"] for r in rows)
         paths = sorted({f"{r['grid']}/{r['path']}" for r in rows})
         lines.append(
             f"{run}: rounds={len(rows)} "
             f"frontier {rows[0]['frontier']}->{rows[-1]['frontier']} "
             f"messages={msgs} pruned={pruned} cells={cells} "
-            f"dma={_fmt_bytes(dma)} wall={wall * 1e3:.1f}ms "
+            f"wall={wall * 1e3:.1f}ms "
             f"path={','.join(paths)}")
         shard_rows = [r["shard_messages"] for r in rows
                       if r.get("shard_messages")]

@@ -32,12 +32,7 @@ converged lanes contribute the absorbing identity and add no message
 volume (``LaneStats.exchanged`` accounts the per-lane difference).
 
 Under ``use_pallas`` the laned fused kernel pads the lane axis to the
-TPU lane tile (masked tail lanes) and honors the same VMEM budget as
-the unlaned engine (``EngineConfig.vmem_budget_bytes``): when the
-(S*R_max, Q) lane table outgrows the budget — which happens Q× sooner
-than for a single query — the relax phase tiles it out of HBM with
-per-cell double-buffered DMA of (vblk, Q) value tiles, bit-identically
-for the min pool (``tests/test_fused_tiled.py``).
+sublane tile (masked tail lanes, bit-identical for the min pool).
 """
 from __future__ import annotations
 
@@ -189,14 +184,6 @@ def make_stacked_lanes_fn(part: Partition,
     return fn
 
 
-def _lane_q_pad(q: int) -> int:
-    """Lane-PADDED width of a laned fused launch (sizes the worklist
-    planner's residency choice and DMA byte mirror)."""
-    from repro.kernels import ops as kops
-    from repro.kernels.fused_relax_reduce import _lane_pad
-    return _lane_pad(q, interpret=kops._interpret())
-
-
 def _run_stacked_lanes_hostloop(part, arrays, cfg, sem, init_val,
                                 lane_unitw, init_chg, lane_budget=None):
     """Worklist-mode laned fixpoint: a Python round loop so the
@@ -207,7 +194,7 @@ def _run_stacked_lanes_hostloop(part, arrays, cfg, sem, init_val,
     lane as dead (its cells stop launching)."""
     S, R_max = part.S, part.R_max
     q = init_val.shape[-1]
-    planner = engine.launch_planner(part, cfg, q_pad=_lane_q_pad(q))
+    planner = engine.launch_planner(part, cfg)
     vol = _volume(part, cfg)
     budget = (None if lane_budget is None
               else np.asarray(lane_budget, np.int64).reshape(q))
@@ -327,7 +314,6 @@ def make_sharded_lanes_fn(S: int, R_max: int, Q: int, mesh: Mesh,
     cfg = engine._sharded_cfg(cfg, "make_sharded_lanes_fn")
     axis_names = exchange.axis_tuple(axis_names)
     spec = P(axis_names)
-    from jax.experimental.shard_map import shard_map
 
     in_specs = (
         DeviceArrays.specs(spec),
@@ -378,11 +364,11 @@ def make_sharded_lanes_fn(S: int, R_max: int, Q: int, mesh: Mesh,
             (val, init_chg, jnp.zeros((), jnp.int32), _zero_stats(Q)))
         return val[None], jax.tree.map(lambda x: x[None], stats)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=in_specs,
         out_specs=(spec, LaneStats(*([spec] * 4))),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn), NamedSharding(mesh, spec)
 
@@ -402,7 +388,6 @@ def make_sharded_min_round(S: int, R_max: int, mesh: Mesh,
     cfg = engine._sharded_cfg(cfg, "make_sharded_min_round")
     axis_names = exchange.axis_tuple(axis_names)
     spec = P(axis_names)
-    from jax.experimental.shard_map import shard_map
 
     in_specs = (
         DeviceArrays.specs(spec),
@@ -418,9 +403,9 @@ def make_sharded_min_round(S: int, R_max: int, mesh: Mesh,
         counts = lax.psum(counts, axis_names)
         return cand[None], new_chg[None], counts[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=in_specs,
-        out_specs=(spec, spec, spec), check_rep=False,
+        out_specs=(spec, spec, spec), check_vma=False,
     )
     return jax.jit(fn), NamedSharding(mesh, spec)
 
@@ -508,7 +493,6 @@ def make_sharded_ppr_round(S: int, R_max: int, mesh: Mesh,
     axis_names = exchange.axis_tuple(axis_names)
     sem = actions.PAGERANK
     spec = P(axis_names)
-    from jax.experimental.shard_map import shard_map
 
     in_specs = (
         DeviceArrays.specs(spec),
@@ -535,9 +519,9 @@ def make_sharded_ppr_round(S: int, R_max: int, mesh: Mesh,
         counts = lax.psum(counts, axis_names)
         return new[None], delta[None], counts[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=in_specs,
-        out_specs=(spec, spec, spec), check_rep=False,
+        out_specs=(spec, spec, spec), check_vma=False,
     )
     return jax.jit(fn), NamedSharding(mesh, spec)
 
@@ -560,7 +544,6 @@ def make_sharded_ppr_delta_round(S: int, R_max: int, mesh: Mesh,
     axis_names = exchange.axis_tuple(axis_names)
     sem = actions.PAGERANK
     spec = P(axis_names)
-    from jax.experimental.shard_map import shard_map
 
     in_specs = (
         DeviceArrays.specs(spec),
@@ -589,9 +572,9 @@ def make_sharded_ppr_delta_round(S: int, R_max: int, mesh: Mesh,
         return ((rank + new_delta)[None], new_delta[None], new_chg[None],
                 counts[None])
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=in_specs,
-        out_specs=(spec, spec, spec, spec), check_rep=False,
+        out_specs=(spec, spec, spec, spec), check_vma=False,
     )
     return jax.jit(fn), NamedSharding(mesh, spec)
 
@@ -695,7 +678,7 @@ def run_ppr_delta_lanes(part: Partition, seeds, dampings,
     base = ppr_base_table(part, seeds, dampings)
     rank = delta = jnp.asarray(base)
     round_fn = make_ppr_delta_round(part, cfg)
-    planner = (engine.launch_planner(part, cfg, q_pad=_lane_q_pad(q))
+    planner = (engine.launch_planner(part, cfg)
                if cfg.wants_worklist else None)
     vol = _volume(part, cfg)
     slot_valid = np.asarray(part.slot_vertex >= 0)

@@ -55,12 +55,6 @@ the default ``ServeConfig`` (unbounded queue, uniform priorities, no
 cache, no faults) the serving loop is trace-identical to the unpoliced
 server — the 8-device parity test in ``tests/test_exchange_unified.py``
 pins this down.
-
-The ``EngineConfig`` handed to the server also governs the fused
-kernel's value-table residency (``vmem_budget_bytes``): a served
-partition whose lane table exceeds the VMEM budget runs every pool
-round through the HBM-tiled DMA kernel with identical serving
-semantics — the continuous-batching loop never needs to know.
 """
 from __future__ import annotations
 
@@ -262,6 +256,7 @@ class _LanePool:
     single-round ticks exactly."""
 
     _sharding = None
+    _planner = None
 
     def _put(self, x):
         return x if self._sharding is None else jax.device_put(
@@ -271,6 +266,21 @@ class _LanePool:
         if k not in self._windows:
             self._windows[k] = jax.jit(self._build_window(k))
         return self._windows[k]
+
+    def _bind_planner(self):
+        """Host worklist planner for stacked pools under grid_mode
+        'worklist'/'auto' (sharded rounds compact on device instead)."""
+        self._planner = (engine.launch_planner(self.part, self._cfg)
+                         if self._mesh is None and self._cfg.wants_worklist
+                         else None)
+
+    def _plan(self):
+        """This tick's host-planned launch from the OR-across-lanes
+        frontier; None keeps the dense grid."""
+        if self._planner is None:
+            return None
+        gchg = np.asarray(jnp.any(self.chg, axis=-1)).reshape(-1)
+        return engine.plan_round_worklist(self._planner, self._cfg, gchg)
 
 
 class _MinPool(_LanePool):
@@ -295,11 +305,12 @@ class _MinPool(_LanePool):
     def _bind_rounds(self, arrays):
         part, cfg = self.part, self._cfg
         S, R_max = part.S, part.R_max
+        self._bind_planner()
         if self._mesh is None:
-            def round_fn(val, chg, unitw):
+            def round_fn(val, chg, unitw, worklist=None):
                 return exchange.fixpoint_round_stacked(
                     actions.SSSP, arrays, cfg, S, R_max, val, chg,
-                    lane_unitw=unitw)
+                    lane_unitw=unitw, worklist=worklist)
 
             self._round_raw = round_fn
             self._round = jax.jit(round_fn)
@@ -374,7 +385,7 @@ class _MinPool(_LanePool):
         """One shared round; returns (Q,) per-lane message counts."""
         if self._sharding is None:
             self.val, self.chg, counts = self._round(
-                self.val, self.chg, jnp.asarray(self.unitw))
+                self.val, self.chg, jnp.asarray(self.unitw), self._plan())
             return np.asarray(counts)
         arrays = self._arrays
         self.val, self.chg, counts = self._round(
@@ -451,6 +462,7 @@ class _PprPool(_LanePool):
 
     def _bind_rounds(self, arrays):
         part, cfg = self.part, self._cfg
+        self._bind_planner()
         if self._mesh is None:
             self._round = L.make_ppr_delta_round(part, cfg, arrays=arrays)
         else:
@@ -500,7 +512,7 @@ class _PprPool(_LanePool):
         if self._sharding is None:
             self.rank, self.delta, self.chg, counts = self._round(
                 self.rank, self.delta, jnp.asarray(self.damping),
-                jnp.asarray(self.tol))
+                jnp.asarray(self.tol), self._plan())
             return np.asarray(counts)
         self.rank, self.delta, self.chg, counts = self._round(
             self._arrays, self.rank, self.delta,
@@ -1014,8 +1026,9 @@ class QueryServer:
         """Rounds this tick may advance in one dispatch: ``tick_rounds``
         unless some occupied lane carries a per-round constraint
         (max_rounds / deadline_s / timeout_s), whose eviction point
-        must stay exact at round granularity."""
-        if self.tick_rounds == 1:
+        must stay exact at round granularity — or when the pool plans a
+        host worklist per round."""
+        if self.tick_rounds == 1 or pool._planner is not None:
             return 1
         for lane in occupied:
             r = pool.reqs[lane]

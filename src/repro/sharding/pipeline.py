@@ -17,7 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -72,11 +71,11 @@ def pipeline_apply(stage_fn, n_stages: int, n_micro: int, mesh: Mesh,
         return outs
 
     param_spec = P(pod_axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(param_spec, inner_spec),
         out_specs=inner_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn
 

@@ -38,8 +38,8 @@ CONFIGS = [
     ("compact", engine.EngineConfig(exchange="compact")),
     ("compact-fused-wl", engine.EngineConfig(
         exchange="compact", use_pallas=True, grid_mode="worklist")),
-    ("fused-wl-tiled", engine.EngineConfig(
-        use_pallas=True, grid_mode="worklist", vmem_budget_bytes=256)),
+    ("fused-device-wl", engine.EngineConfig(
+        use_pallas=True, grid_mode="device_worklist")),
 ]
 
 

@@ -251,35 +251,27 @@ def test_frontier_skip_fires_on_late_rounds():
     ("add_w", "min"), ("add_one", "min"), ("mul_w", "sum")])
 @pytest.mark.parametrize("v,e,nseg", SHAPES)
 def test_grid_cell_dma_oracle_matches_kernel(relax, kind, v, e, nseg):
-    """ISSUE-4 satellite: the host-side ``fused_grid_cells`` mirror
-    (extended with per-cell tile counts) must EXACTLY match the
-    kernel-side executed-cell / issued-DMA counters (``with_debug``) on
-    every kernel-parity case — pinned (cells only; the table rides in
-    via BlockSpec, no manual DMA) and tiled (cells + per-cell tile
-    fetches) alike.  Previously the mirror was only spot-checked in
-    benchmarks."""
+    """The host-side ``fused_grid_cells`` mirror must EXACTLY match the
+    kernel-side executed-cell counter (``with_debug``) on every
+    kernel-parity case, for the dense grid and the worklist launch
+    alike.  (The launches issue no manual DMA: the value gather runs in
+    XLA ahead of the kernel.)"""
     gval, gchg, src, w, mask, ids = _case(v, e, nseg, 0.4, seed=e + nseg)
-    vblk = 128
-    mirror = fused_grid_cells(np.asarray(ids), np.asarray(mask),
-                              np.asarray(src), np.asarray(gchg), nseg,
-                              vblk=vblk)
-    _, pin_dbg = fused_relax_reduce_pallas(
-        gval, gchg, src, w, mask, ids, nseg, relax, kind,
-        path="pinned", with_debug=True)
-    assert int(pin_dbg[0]) == mirror["fused_live"]
-    assert int(pin_dbg[1]) == 0
-    _, til_dbg = fused_relax_reduce_pallas(
-        gval, gchg, src, w, mask, ids, nseg, relax, kind,
-        path="tiled", vblk=vblk, with_debug=True)
-    assert int(til_dbg[0]) == mirror["fused_live"]
-    assert int(til_dbg[1]) == mirror["fused_tile_dmas"]
-    assert mirror["dma_bytes"] == mirror["fused_tile_dmas"] * vblk * 4
+    for mode, key in (("dense", "fused_live"), ("worklist", "wl_cells")):
+        mirror = fused_grid_cells(np.asarray(ids), np.asarray(mask),
+                                  np.asarray(src), np.asarray(gchg), nseg,
+                                  grid_mode=mode)
+        _, dbg = fused_relax_reduce_pallas(
+            gval, gchg, src, w, mask, ids, nseg, relax, kind,
+            interpret=True, grid_mode=mode, with_debug=True)
+        assert dbg.shape == (1,)
+        assert int(dbg[0]) == mirror[key], (mode, int(dbg[0]), mirror)
 
 
 @pytest.mark.parametrize("frontier_frac", [0.0, 0.05, 1.0])
 def test_grid_cell_dma_oracle_matches_kernel_lanes(frontier_frac):
     """Laned launch oracle: the mirror over the OR-across-lanes frontier
-    matches the laned kernels' executed-cell / issued-DMA counters."""
+    matches the laned kernels' executed-cell counter."""
     from repro.kernels.fused_relax_reduce import (
         fused_relax_reduce_lanes_pallas,
     )
@@ -292,21 +284,15 @@ def test_grid_cell_dma_oracle_matches_kernel_lanes(frontier_frac):
     w = jnp.asarray(rng.uniform(0.1, 2, e).astype(np.float32))
     mask = jnp.asarray(rng.random(e) < 0.9)
     ids = jnp.asarray(np.sort(rng.integers(0, nseg, e)).astype(np.int32))
-    vblk = 128
-    from repro.kernels.fused_relax_reduce import _lane_pad
-    mirror = fused_grid_cells(np.asarray(ids), np.asarray(mask),
-                              np.asarray(src),
-                              np.asarray(gchg).any(axis=-1), nseg,
-                              vblk=vblk,
-                              lane_width=_lane_pad(q, interpret=True))
-    for path in ("pinned", "tiled"):
+    for mode, key in (("dense", "fused_live"), ("worklist", "wl_cells")):
+        mirror = fused_grid_cells(np.asarray(ids), np.asarray(mask),
+                                  np.asarray(src),
+                                  np.asarray(gchg).any(axis=-1), nseg,
+                                  grid_mode=mode)
         _, dbg = fused_relax_reduce_lanes_pallas(
             gval, gchg, unitw, src, w, mask, ids, nseg, "add_w", "min",
-            path=path, vblk=vblk if path == "tiled" else None,
-            with_debug=True)
-        assert int(dbg[0]) == mirror["fused_live"]
-        assert int(dbg[1]) == (mirror["fused_tile_dmas"]
-                               if path == "tiled" else 0)
+            interpret=True, grid_mode=mode, with_debug=True)
+        assert int(dbg[0]) == mirror[key], (mode, int(dbg[0]), mirror)
 
 
 def test_grid_cell_counter_matches_kernel_semantics():

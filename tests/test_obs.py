@@ -7,11 +7,10 @@ The two acceptance bars pinned here:
   dispatches exactly as before (traced ``while_loop``, no host loop),
   and the traced round function's jaxpr is byte-identical whether or
   not a recorder exists in the process.
-* **Obs on is exact** — every recorded ``RoundRecord``'s grid-cell /
-  tile-DMA / DMA-byte columns equal a freshly recomputed
-  ``fused_grid_cells`` host mirror AND the fused kernel's
-  ``with_debug`` executed-cell / issued-DMA counters on that round's
-  actual frontier, across dense/worklist × pinned/tiled.
+* **Obs on is exact** — every recorded ``RoundRecord``'s grid-cell
+  columns equal a freshly recomputed ``fused_grid_cells`` host mirror
+  AND the fused kernel's ``with_debug`` executed-cell counter on that
+  round's actual frontier, across dense/worklist/auto grids.
 """
 import json
 import os
@@ -33,9 +32,6 @@ from repro.kernels.fused_relax_reduce import (
 )
 from repro.obs import report
 from repro.serve.admission import ResultCache
-
-TINY_BUDGET = 256   # bytes: forces the tiled path for every table
-
 
 # --------------------------------------------------------------------------
 # metrics registry units
@@ -218,53 +214,40 @@ def _assert_record_exact(part, cfg, rec, runs):
             part.edge_mask, part.edge_src_root_flat, gchg)
         assert r.shard_messages == [int(x) for x in shard]
         assert sum(r.shard_messages) == r.messages
-        assert r.path == planner.path
-        vblk = planner.vblk if planner.path == "tiled" else None
+        assert r.path == "fused"
         if r.grid == "worklist":
             wl, info = engine.plan_round_worklist(
                 planner, cfg, gchg, with_info=True)
             assert wl is not None
             # (a) the planner mirror of the replanned launch
             assert (r.cells, r.launched) == (info.cells, info.launched)
-            assert (r.tile_dmas, r.dma_bytes) \
-                == (info.tile_dmas, info.dma_bytes)
             mirror = fused_grid_cells(
                 np.asarray(part.edge_dst_flat), np.asarray(part.edge_mask),
                 np.asarray(part.edge_src_root_flat), gchg, total,
-                vblk=vblk, grid_mode="worklist")
+                grid_mode="worklist")
             assert r.cells == mirror["wl_cells"]
-            if planner.path == "tiled":
-                assert r.tile_dmas == mirror["wl_tile_dmas"]
             # (b) kernel-side counters
             _, dbg = fused_relax_reduce_pallas(
                 *_kernel_args(part, gval, gchg), total, sem.relax_kind,
-                sem.segment, worklist=wl, with_debug=True)
+                sem.segment, interpret=True, worklist=wl, with_debug=True)
         else:
             mirror = fused_grid_cells(
                 np.asarray(part.edge_dst_flat), np.asarray(part.edge_mask),
-                np.asarray(part.edge_src_root_flat), gchg, total, vblk=vblk)
+                np.asarray(part.edge_src_root_flat), gchg, total)
             assert r.cells == mirror["fused_live"]
             assert r.launched == mirror["total_fused"]
-            if planner.path == "tiled":
-                assert r.tile_dmas == mirror["fused_tile_dmas"]
-                assert r.dma_bytes == mirror["dma_bytes"]
-            else:
-                assert (r.tile_dmas, r.dma_bytes) == (0, 0)
             _, dbg = fused_relax_reduce_pallas(
                 *_kernel_args(part, gval, gchg), total, sem.relax_kind,
-                sem.segment, path=planner.path, vblk=vblk, with_debug=True)
+                sem.segment, interpret=True, with_debug=True)
         assert int(dbg[0]) == r.cells, (r.run, r.round)
-        assert int(dbg[1]) == (r.tile_dmas if planner.path == "tiled"
-                               else 0), (r.run, r.round)
     assert checked > 0
 
 
 @pytest.mark.parametrize("grid_mode", ["dense", "worklist", "auto"])
-@pytest.mark.parametrize("budget", [None, TINY_BUDGET])
-def test_round_records_equal_mirror_and_kernel_debug(grid_mode, budget):
-    _, part, root = _small_case()
-    cfg = engine.EngineConfig(use_pallas=True, grid_mode=grid_mode,
-                              vmem_budget_bytes=budget)
+@pytest.mark.parametrize("seed", [3, 11])
+def test_round_records_equal_mirror_and_kernel_debug(grid_mode, seed):
+    _, part, root = _small_case(seed)
+    cfg = engine.EngineConfig(use_pallas=True, grid_mode=grid_mode)
     for sem in (actions.BFS, actions.SSSP):
         with obs.recording(keep_frontiers=True) as rec:
             init = engine.init_values(part, sem, {root: 0.0})
@@ -300,7 +283,7 @@ def test_device_window_records_sum_to_host_rounds(k):
     K-round dispatch window; every additive column must sum to the
     host-driven per-round records' totals, window by window, and the
     planner mirror is recomputed post-hoc from the returned frontier
-    trajectory (the record's cells/tile_dmas/dma_bytes columns)."""
+    trajectory (the record's cells column)."""
     _, part, root = _small_case()
     cfg_h = engine.EngineConfig(use_pallas=True)
     cfg_dev = engine.EngineConfig(use_pallas=True,
@@ -326,8 +309,7 @@ def test_device_window_records_sum_to_host_rounds(k):
             # the window's cumulative round count and entering frontier
             assert dr.round == hw[-1].round
             assert dr.frontier == hw[0].frontier
-            for col in ("messages", "work", "pruned", "cells",
-                        "tile_dmas", "dma_bytes"):
+            for col in ("messages", "work", "pruned", "cells"):
                 assert getattr(dr, col) == \
                     sum(getattr(r, col) for r in hw), (sem.name, col)
             assert dr.shard_messages == [
@@ -367,8 +349,7 @@ def test_device_window_pagerank_delta_sums():
     dev = [r for r in rec_d.rounds if r.run == "pagerank_delta"]
     assert len(dev) == -(-len(host) // 3)
     for dr, hw in zip(dev, _window_slices(host, 3)):
-        for col in ("messages", "work", "pruned", "cells", "tile_dmas",
-                    "dma_bytes"):
+        for col in ("messages", "work", "pruned", "cells"):
             assert getattr(dr, col) == sum(getattr(r, col) for r in hw)
     assert sum(r.messages for r in dev) == int(st_h.messages)
     assert int(st_d.messages) == int(st_h.messages)

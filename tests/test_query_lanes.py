@@ -25,7 +25,7 @@ from repro.core import actions, engine
 from repro.core.partition import PartitionConfig, build_partition
 from repro.graph import generators, reference
 from repro.kernels.fused_relax_reduce import (
-    EBLK, _chunk_tables_lanes, fused_relax_reduce_lanes_pallas,
+    EBLK, _chunk_tables, _lane_messages, fused_relax_reduce_lanes_pallas,
 )
 from repro.kernels.ref import fused_relax_reduce_lanes_ref
 from repro.query.lanes import (
@@ -99,17 +99,25 @@ def test_lanes_chunk_bitmap_is_or_across_lanes():
     rng = np.random.default_rng(3)
     src_p = jnp.asarray(rng.integers(0, v, e_pad).astype(np.int32))
     ids_p = jnp.asarray(np.sort(rng.integers(0, 50, e_pad)).astype(np.int32))
-    mask_i = jnp.ones(e_pad, jnp.int32)
+    mask = jnp.ones(e_pad, bool)
+    gval = jnp.zeros((v, q), jnp.float32)
+    w = jnp.ones(e_pad, jnp.float32)
+    unitw = jnp.zeros(q, jnp.int32)
+
+    def tables(gchg):
+        ids, _, valid, active, counts = _lane_messages(
+            gval, gchg, unitw, src_p, w, mask, ids_p, "add_w", "min",
+            e_pad, 8)
+        return _chunk_tables(ids, valid, active)[2], counts
+
     # lane 0 active only in chunk 0's sources, lane 1 only in chunk 1's
-    gchg = np.zeros((v, q), np.int32)
-    gchg[np.asarray(src_p)[:EBLK], 0] = 1
-    gchg[np.asarray(src_p)[EBLK:], 1] = 1
-    _, _, chunk_act, counts, _ = _chunk_tables_lanes(
-        ids_p, src_p, mask_i, jnp.asarray(gchg))
+    gchg = np.zeros((v, q), bool)
+    gchg[np.asarray(src_p)[:EBLK], 0] = True
+    gchg[np.asarray(src_p)[EBLK:], 1] = True
+    chunk_act, counts = tables(jnp.asarray(gchg))
     assert np.asarray(chunk_act).tolist() == [1, 1]   # OR keeps both live
     assert int(counts[2]) == 0                        # lane 2 fully dead
-    dead = jnp.zeros((v, q), jnp.int32)
-    _, _, act_dead, _, _ = _chunk_tables_lanes(ids_p, src_p, mask_i, dead)
+    act_dead, _ = tables(jnp.zeros((v, q), bool))
     assert np.asarray(act_dead).tolist() == [0, 0]
 
 

@@ -166,3 +166,35 @@ def test_server_rejects_submit_into_empty_pool():
     srv = QueryServer(part, n_lanes=1, ppr_lanes=0)
     with pytest.raises(ValueError, match="no lanes"):
         srv.submit("ppr", 0)
+
+
+def test_server_worklist_mode_plans_each_tick():
+    """Under grid_mode='worklist' the stacked pools plan a host worklist
+    from the OR-across-lanes frontier every tick (single-round ticks),
+    with answers identical to the dense-grid server's."""
+    g = generators.rmat(7, edge_factor=5, seed=5).with_random_weights(seed=5)
+    from repro.apps.pagerank import _pr_graph
+    part = build_partition(_pr_graph(g),
+                           PartitionConfig(num_shards=4, rpvo_max=2))
+    deg = np.argsort(-g.out_degrees())
+    burst = [("bfs", int(deg[0])), ("sssp", int(deg[1])),
+             ("ppr", int(deg[2]))]
+    answers = {}
+    for grid_mode in ("dense", "worklist"):
+        srv = QueryServer(part, n_lanes=2, ppr_lanes=1, tick_rounds=4,
+                          cfg=engine.EngineConfig(use_pallas=True,
+                                                  grid_mode=grid_mode))
+        planned = grid_mode == "worklist"
+        assert (srv.min_pool._planner is not None) == planned
+        assert (srv.ppr_pool._planner is not None) == planned
+        qids = [srv.submit(kind, src, tol=1e-9) for kind, src in burst]
+        res = srv.run()
+        answers[grid_mode] = [res[q].values for q in qids]
+    for kind, got, want in zip([k for k, _ in burst], answers["worklist"],
+                               answers["dense"]):
+        if kind == "ppr":
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-9)
+        else:
+            np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(answers["worklist"][0],
+                                  reference.bfs_levels(g, int(deg[0])))

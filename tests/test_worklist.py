@@ -2,13 +2,14 @@
 
 The fused kernel's dense grid launches every (num_sblk, num_chunks) cell
 and early-exits the dead ones; ``grid_mode='worklist'`` launches a 1-D
-grid over just the live cells, with per-cell dst-filtered tile lists and
-2-slot tile reuse on the tiled path.  Every case here drives the
+grid over just the live cells, dropping cells whose chunk has no
+frontier-active edge in their block (the dst filter).  Every case here
+drives the
 worklist twins against the dense kernels and the jnp oracle — min
 semirings must agree **bit-identically**, sum up to the partial
 scatter's reassociation — and asserts the host-side planner mirror
 (``fused_grid_cells(grid_mode='worklist')``) EXACTLY equals the
-kernel-side ``with_debug`` executed-cell / issued-DMA counters.
+kernel-side ``with_debug`` executed-cell counter.
 ISSUE 8 extends the suite with the device-compaction differential leg:
 ``grid_mode='device_worklist'`` builds the same live-(i, j) cell list ON
 DEVICE (cumsum-scatter over the frontier chunk bitmap) — every case
@@ -36,7 +37,7 @@ from repro.kernels.fused_relax_reduce import (
     EBLK, SBLK, WL_PAD, WorklistPlanner, build_device_worklist,
     device_worklist_pad, fused_grid_cells,
     fused_relax_reduce_lanes_pallas, fused_relax_reduce_pallas,
-    plan_worklist, select_kernel_path, smem_table_bytes,
+    plan_worklist, smem_table_bytes,
 )
 from repro.kernels.ref import (
     fused_relax_reduce_lanes_ref, fused_relax_reduce_ref,
@@ -45,10 +46,9 @@ from repro.query.lanes import init_lane_values, run_stacked_lanes
 
 
 def _hub_case(v, e, nseg, frontier_frac, seed, q=None):
-    # NOT test_fused_tiled._skewed_case: sources here are drawn from a
-    # small permuted hub pool (max v//8 distinct sources), concentrating
-    # edges in few slot tiles so the per-cell dst filter and 2-slot
-    # reuse have structure to bite on
+    # sources drawn from a small permuted hub pool (max v//8 distinct
+    # sources), so frontier activity clusters and the per-cell dst
+    # filter has structure to bite on
     rng = np.random.default_rng(seed)
     shape = (v,) if q is None else (v, q)
     gval = rng.uniform(0.0, 10.0, shape).astype(np.float32)
@@ -61,13 +61,13 @@ def _hub_case(v, e, nseg, frontier_frac, seed, q=None):
     return tuple(jnp.asarray(x) for x in (gval, gchg, src, w, mask, ids))
 
 
-def _wl_mirror(src, mask, ids, gchg, nseg, vblk=128, lane_width=1):
+def _wl_mirror(src, mask, ids, gchg, nseg):
     gchg = np.asarray(gchg)
     if gchg.ndim == 2:
         gchg = gchg.any(axis=-1)
     return fused_grid_cells(np.asarray(ids), np.asarray(mask),
-                            np.asarray(src), gchg, nseg, vblk=vblk,
-                            lane_width=lane_width, grid_mode="worklist")
+                            np.asarray(src), gchg, nseg,
+                            grid_mode="worklist")
 
 
 # --------------------------------------------------------------------------
@@ -75,52 +75,40 @@ def _wl_mirror(src, mask, ids, gchg, nseg, vblk=128, lane_width=1):
 # --------------------------------------------------------------------------
 
 WL_SHAPES = [
-    # (v, e, nseg, vblk)
-    (1, 1, 1, 128),
-    (127, 300, 50, 128),
-    (129, 300, 50, 128),
-    (257, 2 * EBLK + 13, SBLK + 5, 128),
-    (500, 3 * EBLK + 9, 2 * SBLK + 1, 128),
-    (300, 1000, 400, 256),
+    # (v, e, nseg)
+    (1, 1, 1),
+    (127, 300, 50),
+    (129, 300, 50),
+    (257, 2 * EBLK + 13, SBLK + 5),
+    (500, 3 * EBLK + 9, 2 * SBLK + 1),
+    (300, 1000, 400),
 ]
 
 
 @pytest.mark.parametrize("relax,kind", [
     ("add_w", "min"), ("add_one", "min"), ("mul_w", "sum")])
-@pytest.mark.parametrize("v,e,nseg,vblk", WL_SHAPES)
-def test_worklist_matches_dense_and_ref(relax, kind, v, e, nseg, vblk):
+@pytest.mark.parametrize("v,e,nseg", WL_SHAPES)
+def test_worklist_matches_dense_and_ref(relax, kind, v, e, nseg):
     gval, gchg, src, w, mask, ids = _hub_case(v, e, nseg, 0.4,
-                                                 seed=v + e + nseg)
+                                              seed=v + e + nseg)
     want = fused_relax_reduce_ref(gval, gchg, src, w, mask, ids, nseg,
                                   relax, kind)
-    mirror = _wl_mirror(src, mask, ids, gchg, nseg, vblk=vblk)
-    wl_p, dbg_p = fused_relax_reduce_pallas(
-        gval, gchg, src, w, mask, ids, nseg, relax, kind,
-        grid_mode="worklist", path="pinned", with_debug=True)
-    wl_t, dbg_t = fused_relax_reduce_pallas(
-        gval, gchg, src, w, mask, ids, nseg, relax, kind,
-        grid_mode="worklist", path="tiled", vblk=vblk, with_debug=True)
+    mirror = _wl_mirror(src, mask, ids, gchg, nseg)
+    wl, dbg = fused_relax_reduce_pallas(
+        gval, gchg, src, w, mask, ids, nseg, relax, kind, interpret=True,
+        grid_mode="worklist", with_debug=True)
+    dense = fused_relax_reduce_pallas(gval, gchg, src, w, mask, ids, nseg,
+                                      relax, kind, interpret=True)
     if kind == "min":
-        dense = fused_relax_reduce_pallas(gval, gchg, src, w, mask, ids,
-                                          nseg, relax, kind, path="pinned")
-        np.testing.assert_array_equal(np.asarray(wl_p), np.asarray(want))
-        np.testing.assert_array_equal(np.asarray(wl_p), np.asarray(dense))
-        np.testing.assert_array_equal(np.asarray(wl_t), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(wl), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(wl), np.asarray(dense))
     else:
-        np.testing.assert_allclose(np.asarray(wl_p), np.asarray(want),
+        np.testing.assert_allclose(np.asarray(wl), np.asarray(want),
                                    rtol=1e-5, atol=1e-6)
-        np.testing.assert_allclose(np.asarray(wl_t), np.asarray(want),
-                                   rtol=1e-5, atol=1e-6)
-    # the planner IS the launch: kernel counters must mirror it exactly
-    assert int(dbg_p[0]) == mirror["wl_cells"]
-    assert int(dbg_p[1]) == 0
-    assert int(dbg_t[0]) == mirror["wl_cells"]
-    assert int(dbg_t[1]) == mirror["wl_tile_dmas"]
-    # dst filtering can only shrink the launch; reuse only the DMAs
+    # the planner IS the launch: the kernel counter must mirror it
+    assert int(dbg[0]) == mirror["wl_cells"]
+    # dst filtering can only shrink the launch
     assert mirror["wl_cells"] <= mirror["fused_live"]
-    assert mirror["wl_tile_dmas"] <= mirror["wl_tile_needed"]
-    assert mirror["wl_tile_needed"] <= mirror["fused_tile_dmas"]
-    assert mirror["wl_dma_bytes"] <= mirror["dma_bytes"]
 
 
 @pytest.mark.parametrize("frontier_frac", [0.0, 0.05, 1.0])
@@ -130,15 +118,15 @@ def test_worklist_frontier_densities(frontier_frac):
     want = fused_relax_reduce_ref(gval, gchg, src, w, mask, ids, 700,
                                   "add_w", "min")
     got, dbg = fused_relax_reduce_pallas(
-        gval, gchg, src, w, mask, ids, 700, "add_w", "min",
-        grid_mode="worklist", path="tiled", vblk=128, with_debug=True)
+        gval, gchg, src, w, mask, ids, 700, "add_w", "min", interpret=True,
+        grid_mode="worklist", with_debug=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     mirror = _wl_mirror(src, mask, ids, gchg, 700)
     if frontier_frac == 0.0:
         # an empty frontier launches only the WL_PAD dead pad cells
         assert mirror["wl_cells"] == 0
         assert mirror["wl_launched"] == WL_PAD
-        assert int(dbg[0]) == 0 and int(dbg[1]) == 0
+        assert int(dbg[0]) == 0
         assert np.all(np.asarray(got) == np.inf)
     else:
         assert int(dbg[0]) == mirror["wl_cells"] > 0
@@ -155,62 +143,44 @@ def test_worklist_padding_is_power_of_two_bucket():
 
 
 def test_dst_filter_drops_cells_and_tiles():
-    """Multi-dst-block case where a chunk's range spans blocks but each
-    block only needs some of the chunk's tiles: the per-cell filter must
-    strictly beat the per-chunk tile lists."""
-    v, nseg = 1024, 4 * SBLK
-    # hub sources in distinct vblk tiles, each aimed at ONE dst block
-    src = np.concatenate([np.full(64, t * 128, np.int32)
-                          for t in range(8)])
-    ids = np.concatenate([np.full(64, b * SBLK, np.int32)
-                          for b in range(4)] * 2)
-    order = np.argsort(ids, kind="stable")
-    src, ids = src[order], ids[order]
-    e = src.shape[0]
-    gval = jnp.asarray(np.random.default_rng(0)
-                       .uniform(0, 10, v).astype(np.float32))
-    gchg = jnp.ones(v, bool)
-    w = jnp.ones(e, jnp.float32)
-    mask = jnp.ones(e, bool)
-    mirror = _wl_mirror(src, mask, ids, np.ones(v, bool), nseg)
-    # every (block, tile) pairing is narrower than the chunk's union
-    assert mirror["wl_tile_needed"] < mirror["fused_tile_dmas"]
-    assert mirror["wl_dma_bytes"] < mirror["dma_bytes"]
-    want = fused_relax_reduce_ref(gval, gchg, jnp.asarray(src), w, mask,
-                                  jnp.asarray(ids), nseg, "add_w", "min")
-    got, dbg = fused_relax_reduce_pallas(
-        gval, gchg, jnp.asarray(src), w, mask, jnp.asarray(ids), nseg,
-        "add_w", "min", grid_mode="worklist", path="tiled", vblk=128,
-        with_debug=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    assert int(dbg[1]) == mirror["wl_tile_dmas"]
-
-
-def test_j_major_tile_reuse_across_cells():
-    """One edge chunk spanning several dst blocks, all edges from one
-    slot tile: consecutive worklist cells share chunk j, so only the
-    FIRST cell fetches the tile — the 2-slot reuse the planner schedules
-    and the kernel executes."""
-    v, nseg = 256, 4 * SBLK
-    rng = np.random.default_rng(1)
-    src = rng.integers(0, 100, 400).astype(np.int32)    # one 128-tile
-    ids = np.sort(rng.integers(0, nseg, 400)).astype(np.int32)
+    """One edge chunk spanning four dst blocks, frontier-active only on
+    the sources feeding block 0: the dense grid runs the chunk against
+    all four blocks, the dst-filtered worklist only against block 0."""
+    v, nseg = 512, 4 * SBLK
+    ids = np.repeat(np.arange(4, dtype=np.int32) * SBLK, 128)
+    src = np.arange(512, dtype=np.int32)          # block b <- srcs b*128..
+    gchg_np = np.zeros(v, bool)
+    gchg_np[:128] = True                          # only block 0's sources
+    rng = np.random.default_rng(0)
     gval = jnp.asarray(rng.uniform(0, 10, v).astype(np.float32))
-    gchg = jnp.ones(v, bool)
-    w = jnp.ones(400, jnp.float32)
-    mask = jnp.ones(400, bool)
-    mirror = _wl_mirror(src, mask, ids, np.ones(v, bool), nseg)
-    assert mirror["wl_cells"] > 1           # several dst blocks live
-    assert mirror["wl_tile_dmas"] == 1      # but the tile rides once
-    assert mirror["wl_tile_needed"] == mirror["wl_cells"]
-    got, dbg = fused_relax_reduce_pallas(
-        gval, gchg, jnp.asarray(src), w, mask, jnp.asarray(ids), nseg,
-        "add_w", "min", grid_mode="worklist", path="tiled", vblk=128,
-        with_debug=True)
-    assert int(dbg[1]) == 1
-    want = fused_relax_reduce_ref(gval, gchg, jnp.asarray(src), w, mask,
+    w = jnp.ones(512, jnp.float32)
+    mask = jnp.ones(512, bool)
+    mirror = _wl_mirror(src, mask, ids, gchg_np, nseg)
+    assert mirror["fused_live"] == 4
+    assert mirror["wl_cells"] == 1
+    want = fused_relax_reduce_ref(gval, jnp.asarray(gchg_np),
+                                  jnp.asarray(src), w, mask,
                                   jnp.asarray(ids), nseg, "add_w", "min")
+    got, dbg = fused_relax_reduce_pallas(
+        gval, jnp.asarray(gchg_np), jnp.asarray(src), w, mask,
+        jnp.asarray(ids), nseg, "add_w", "min", interpret=True,
+        grid_mode="worklist", with_debug=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert int(dbg[0]) == 1
+
+
+def test_worklist_cells_are_j_major():
+    """The planner emits cells chunk-major (j outer, block i inner), the
+    order the device compaction reproduces."""
+    gval, gchg, src, w, mask, ids = _hub_case(300, 3 * EBLK + 5,
+                                              3 * SBLK, 1.0, seed=12)
+    wl, info = plan_worklist(np.asarray(ids), np.asarray(mask),
+                             np.asarray(src), np.asarray(gchg), 3 * SBLK,
+                             dst_filter=False)
+    n = info.cells
+    pairs = list(zip(np.asarray(wl.wl_j)[:n].tolist(),
+                     np.asarray(wl.wl_i)[:n].tolist()))
+    assert n > 1 and pairs == sorted(pairs)
 
 
 def test_worklist_under_tracing_requires_plan():
@@ -220,7 +190,7 @@ def test_worklist_under_tracing_requires_plan():
     @jax.jit
     def f(gval, gchg):
         return fused_relax_reduce_pallas(gval, gchg, src, w, mask, ids,
-                                         40, "add_w", "min",
+                                         40, "add_w", "min", interpret=True,
                                          grid_mode="worklist")
 
     with pytest.raises(ValueError, match="host-side"):
@@ -240,14 +210,11 @@ def test_worklist_lanes_match_ref(q):
     want = fused_relax_reduce_lanes_ref(gval, gchg, unitw, src, w, mask,
                                         ids, nseg, "add_w", "min")
     mirror = _wl_mirror(src, mask, ids, gchg, nseg)
-    for path, vblk in (("pinned", None), ("tiled", 128)):
-        got, dbg = fused_relax_reduce_lanes_pallas(
-            gval, gchg, unitw, src, w, mask, ids, nseg, "add_w", "min",
-            grid_mode="worklist", path=path, vblk=vblk, with_debug=True)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-        assert int(dbg[0]) == mirror["wl_cells"]
-        assert int(dbg[1]) == (mirror["wl_tile_dmas"] if path == "tiled"
-                               else 0)
+    got, dbg = fused_relax_reduce_lanes_pallas(
+        gval, gchg, unitw, src, w, mask, ids, nseg, "add_w", "min",
+        interpret=True, grid_mode="worklist", with_debug=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert int(dbg[0]) == mirror["wl_cells"]
 
 
 def test_worklist_lanes_sum_semiring_close():
@@ -259,7 +226,7 @@ def test_worklist_lanes_sum_semiring_close():
                                         ids, 150, "mul_w", "sum")
     got = fused_relax_reduce_lanes_pallas(
         gval, gchg, unitw, src, w, mask, ids, 150, "mul_w", "sum",
-        grid_mode="worklist", path="tiled", vblk=128)
+        interpret=True, grid_mode="worklist")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
 
@@ -270,44 +237,40 @@ def test_worklist_lanes_sum_semiring_close():
 
 def test_smem_table_bytes_shapes():
     assert smem_table_bytes(10) == 10 * 3 * 4
-    assert smem_table_bytes(10, t_max=4) == (10 * 3 + 10 * 5) * 4
-    assert smem_table_bytes(10, t_max=0, wl_cells=8) == (30 + 17) * 4
-    dense_tiled = smem_table_bytes(10, t_max=4)
-    wl_tiled = smem_table_bytes(10, t_max=4, wl_cells=8)
-    assert wl_tiled == (10 * 3 + 2 * 8 + 1 + 8 * 13) * 4
-    assert wl_tiled > dense_tiled - 10 * 5 * 4   # chunk lists swap for cells
-
-
-def test_select_kernel_path_smem_guard_widens_vblk():
-    # 10k chunks of tile lists at vblk=128 overflow a 64 KiB SMEM budget;
-    # the guard must warn and widen the tile until the tables fit
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        path, vblk = select_kernel_path(
-            200_000, 1, 1024, n_chunks=10_000, smem_budget_bytes=64 * 1024)
-    assert path == "tiled" and vblk > 128
-    assert any("smem_budget_bytes" in str(w.message) for w in rec)
-    t_max = -(-200_000 // vblk)
-    assert smem_table_bytes(10_000, min(t_max, EBLK)) <= 64 * 1024 \
-        or vblk >= 200_000
-    # an ample budget leaves the decision untouched, silently
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        same = select_kernel_path(200_000, 1, 1024, n_chunks=10,
-                                  smem_budget_bytes=10**9)
-    assert same == ("tiled", 128) and not rec
-
-
-def test_select_kernel_path_returns_info():
-    path, vblk, info = select_kernel_path(
-        10_000, 1, 8192, n_chunks=100, smem_budget_bytes=10**9,
-        return_info=True)
-    assert (path, vblk) == ("tiled", 1024)
-    assert info["smem_table_bytes"] == smem_table_bytes(
-        100, min(-(-10_240 // 1024), EBLK))
-    assert fused_grid_cells(
+    assert smem_table_bytes(10, wl_cells=8) == (2 * 8 + 1) * 4
+    mirror = fused_grid_cells(
         np.zeros(10, np.int64), np.ones(10, bool), np.zeros(10, np.int64),
-        np.ones(16, bool), 8, vblk=128)["smem_table_bytes"] > 0
+        np.ones(16, bool), 8, grid_mode="worklist")
+    assert mirror["smem_table_bytes"] == smem_table_bytes(
+        1, mirror["wl_launched"])
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_planner_range_cells_equal_dense_grid(seed):
+    """The planner lists each live chunk's intersecting blocks from its
+    [lo, hi] id range; that must be exactly the dense grid's live-cell
+    matrix, in j-major order."""
+    gval, gchg, src, w, mask, ids = _hub_case(300, 4 * EBLK + 3,
+                                              5 * SBLK + 7, 0.3, seed=seed)
+    ids_np, mask_np = np.asarray(ids), np.asarray(mask)
+    act = mask_np & np.asarray(gchg)[np.asarray(src)]
+    e_pad = 5 * EBLK
+    pad = lambda x, fill: np.concatenate(  # noqa: E731
+        [x, np.full(e_pad - x.shape[0], fill, x.dtype)])
+    idc = pad(ids_np.astype(np.int64), 0).reshape(-1, EBLK)
+    mkc = pad(mask_np, False).reshape(-1, EBLK)
+    acc = pad(act, False).reshape(-1, EBLK)
+    lo = np.where(mkc, idc, np.iinfo(np.int64).max).min(axis=1)
+    hi = np.where(mkc, idc, -1).max(axis=1)
+    seg0 = np.arange(6)[:, None] * SBLK
+    live = (hi >= seg0) & (lo < seg0 + SBLK) & acc.any(axis=1)[None, :]
+    jj, ii = np.nonzero(live.T)
+    wl, info = plan_worklist(ids_np, mask_np, np.asarray(src),
+                             np.asarray(gchg), 5 * SBLK + 7,
+                             dst_filter=False)
+    assert info.cells == info.dense_live == jj.shape[0]
+    np.testing.assert_array_equal(np.asarray(wl.wl_i)[:info.cells], ii)
+    np.testing.assert_array_equal(np.asarray(wl.wl_j)[:info.cells], jj)
 
 
 def test_planner_warns_when_worklist_tables_exceed_smem_budget():
@@ -317,9 +280,7 @@ def test_planner_warns_when_worklist_tables_exceed_smem_budget():
     gval, gchg, src, w, mask, ids = _hub_case(300, 2 * EBLK, 400, 1.0,
                                                  seed=4)
     planner = WorklistPlanner(np.asarray(ids), np.asarray(mask),
-                              np.asarray(src), 400, num_slots=300,
-                              path="tiled", vblk=128,
-                              smem_budget_bytes=64)
+                              np.asarray(src), 400, smem_budget_bytes=64)
     with warnings.catch_warnings(record=True) as rec:
         warnings.simplefilter("always")
         _, info = planner.plan(np.asarray(gchg))
@@ -328,8 +289,7 @@ def test_planner_warns_when_worklist_tables_exceed_smem_budget():
     assert sum("smem_budget_bytes" in str(r.message) for r in rec) == 1
     # an unarmed planner stays silent
     quiet = WorklistPlanner(np.asarray(ids), np.asarray(mask),
-                            np.asarray(src), 400, num_slots=300,
-                            path="tiled", vblk=128)
+                            np.asarray(src), 400)
     with warnings.catch_warnings(record=True) as rec:
         warnings.simplefilter("always")
         quiet.plan(np.asarray(gchg))
@@ -372,22 +332,6 @@ def test_engine_worklist_matches_dense(exchange):
     np.testing.assert_array_equal(
         bfs(g, root, num_shards=8, rpvo_max=4, cfg=cfg_w)[0],
         reference.bfs_levels(g, root))
-
-
-def test_engine_worklist_tiled_budget_forced():
-    """worklist × tiled composition: the slot table over the VMEM budget
-    AND the sparse launch, bit-identical to the jnp path."""
-    g = generators.ba_skewed(260, m_per=4, seed=9).with_random_weights(
-        seed=9)
-    root = int(np.argmax(g.out_degrees()))
-    cfg_j = engine.EngineConfig()
-    cfg_wt = engine.EngineConfig(use_pallas=True, grid_mode="worklist",
-                                 vmem_budget_bytes=256)
-    for app in (bfs, sssp):
-        out_j, st_j, _ = app(g, root, num_shards=8, rpvo_max=4, cfg=cfg_j)
-        out_w, st_w, _ = app(g, root, num_shards=8, rpvo_max=4, cfg=cfg_wt)
-        np.testing.assert_array_equal(out_w, out_j)
-        assert int(st_w.messages) == int(st_j.messages)
 
 
 def test_worklist_launches_track_frontier_on_ring():
@@ -446,37 +390,35 @@ def test_laned_engine_worklist_matches_dense(exchange):
 # ISSUE 8: device-side frontier compaction == host planner, exactly
 # --------------------------------------------------------------------------
 
-def _device_cells(gchg, src, mask, ids, nseg, num_slots, path="pinned",
-                  vblk=None):
+def _device_cells(gchg, src, mask, ids, nseg):
     """Build the device worklist and return its live (i, j) list plus the
     static launch length — all leaves are traced-capable arrays."""
     wl = build_device_worklist(
         jnp.asarray(gchg).reshape(-1), jnp.asarray(src),
-        jnp.asarray(mask), jnp.asarray(ids), nseg, path, vblk, num_slots)
+        jnp.asarray(mask), jnp.asarray(ids), nseg)
     n = int(wl.nlive[0])
     cells = list(zip(np.asarray(wl.wl_i)[:n].tolist(),
                      np.asarray(wl.wl_j)[:n].tolist()))
     return cells, int(np.asarray(wl.wl_i).shape[0])
 
 
-def _host_cells(gchg, src, mask, ids, nseg, num_slots):
+def _host_cells(gchg, src, mask, ids, nseg):
     """The host oracle: ``plan_worklist`` without the dst filter (the
     device compaction keeps every live cell) — j-major dense order."""
     wl, info = plan_worklist(
         np.asarray(ids), np.asarray(mask), np.asarray(src),
-        np.asarray(gchg).reshape(-1), nseg, num_slots=num_slots,
-        dst_filter=False)
+        np.asarray(gchg).reshape(-1), nseg, dst_filter=False)
     n = int(wl.nlive[0])
     return list(zip(np.asarray(wl.wl_i)[:n].tolist(),
                     np.asarray(wl.wl_j)[:n].tolist())), info
 
 
-@pytest.mark.parametrize("v,e,nseg,vblk", WL_SHAPES)
-def test_device_compaction_equals_host_plan(v, e, nseg, vblk):
+@pytest.mark.parametrize("v,e,nseg", WL_SHAPES)
+def test_device_compaction_equals_host_plan(v, e, nseg):
     gval, gchg, src, w, mask, ids = _hub_case(v, e, nseg, 0.4,
                                               seed=v + e + nseg)
-    dev, launched = _device_cells(gchg, src, mask, ids, nseg, v)
-    host, _ = _host_cells(gchg, src, mask, ids, nseg, v)
+    dev, launched = _device_cells(gchg, src, mask, ids, nseg)
+    host, _ = _host_cells(gchg, src, mask, ids, nseg)
     # same j-major dense order, not merely the same set
     assert dev == host
     assert sorted(dev) == sorted(host)          # order-normalized too
@@ -512,8 +454,8 @@ def test_device_compaction_edge_cases(case):
         hub = int(np.bincount(src, minlength=v).argmax())
         gchg = np.zeros(v, bool)
         gchg[hub] = True
-    dev, launched = _device_cells(gchg, src, mask, ids, nseg, v)
-    host, _ = _host_cells(gchg, src, mask, ids, nseg, v)
+    dev, launched = _device_cells(gchg, src, mask, ids, nseg)
+    host, _ = _host_cells(gchg, src, mask, ids, nseg)
     assert dev == host
     assert launched == device_worklist_pad(e, nseg)
     if case == "empty":
@@ -523,10 +465,10 @@ def test_device_compaction_edge_cases(case):
         out, dbg = fused_relax_reduce_pallas(
             gval, jnp.asarray(gchg), jnp.asarray(src),
             jnp.ones(e, jnp.float32), jnp.asarray(mask),
-            jnp.asarray(ids), nseg, "add_w", "min",
+            jnp.asarray(ids), nseg, "add_w", "min", interpret=True,
             grid_mode="device_worklist", with_debug=True)
         assert np.all(np.asarray(out) == np.inf)
-        assert int(dbg[0]) == 0 and int(dbg[1]) == 0
+        assert int(dbg[0]) == 0
 
 
 def test_device_compaction_under_jit():
@@ -537,7 +479,7 @@ def test_device_compaction_under_jit():
     @jax.jit
     def f(gval, gchg):
         return fused_relax_reduce_pallas(gval, gchg, src, w, mask, ids,
-                                         40, "add_w", "min",
+                                         40, "add_w", "min", interpret=True,
                                          grid_mode="device_worklist")
 
     want = fused_relax_reduce_ref(gval, gchg, src, w, mask, ids, 40,
@@ -555,15 +497,13 @@ def test_device_compaction_lanes(q):
     want = fused_relax_reduce_lanes_ref(gval, gchg, unitw, src, w, mask,
                                         ids, nseg, "add_w", "min")
     or_chg = np.asarray(gchg).any(axis=-1)
-    host, _ = _host_cells(or_chg, src, mask, ids, nseg, v)
-    for path, vblk in (("pinned", None), ("tiled", 128)):
-        got, dbg = fused_relax_reduce_lanes_pallas(
-            gval, gchg, unitw, src, w, mask, ids, nseg, "add_w", "min",
-            grid_mode="device_worklist", path=path, vblk=vblk,
-            with_debug=True)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-        # kernel executed exactly the host-oracle live cells
-        assert int(dbg[0]) == len(host)
+    host, _ = _host_cells(or_chg, src, mask, ids, nseg)
+    got, dbg = fused_relax_reduce_lanes_pallas(
+        gval, gchg, unitw, src, w, mask, ids, nseg, "add_w", "min",
+        interpret=True, grid_mode="device_worklist", with_debug=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # kernel executed exactly the host-oracle live cells
+    assert int(dbg[0]) == len(host)
 
 
 try:
@@ -584,8 +524,8 @@ if HAVE_HYPOTHESIS:
         plan on arbitrary skew / frontier density / tile alignment."""
         gval, gchg, src, w, mask, ids = _hub_case(v, e, nseg, frontier,
                                                   seed=seed)
-        dev, launched = _device_cells(gchg, src, mask, ids, nseg, v)
-        host, _ = _host_cells(gchg, src, mask, ids, nseg, v)
+        dev, launched = _device_cells(gchg, src, mask, ids, nseg)
+        host, _ = _host_cells(gchg, src, mask, ids, nseg)
         assert dev == host
         assert launched == device_worklist_pad(e, nseg)
 
@@ -676,7 +616,7 @@ def test_planner_live_fraction_and_auto_threshold():
     gval, gchg, src, w, mask, ids = _hub_case(300, 1000, 400, 1.0,
                                                  seed=3)
     planner = WorklistPlanner(np.asarray(ids), np.asarray(mask),
-                              np.asarray(src), 400, num_slots=300)
+                              np.asarray(src), 400)
     dense_frac = planner.live_fraction(np.asarray(gchg))
     assert 0.0 < dense_frac <= 1.0
     assert planner.live_fraction(np.zeros(300, bool)) == 0.0
@@ -687,3 +627,32 @@ def test_planner_live_fraction_and_auto_threshold():
     if dense_frac >= engine.WORKLIST_AUTO_THRESHOLD:
         assert engine.plan_round_worklist(
             planner, cfg_auto, np.asarray(gchg)) is None
+
+
+@pytest.mark.parametrize("laned", [False, True])
+def test_worklist_launch_slices_match_one_launch(monkeypatch, laned):
+    """A worklist longer than ``WL_LAUNCH_CELLS`` runs as several
+    pallas_calls (each call's cell list must fit SMEM); the sliced launch
+    equals the oracle and counts every live cell once."""
+    from repro.kernels import fused_relax_reduce as FR
+    q = 3 if laned else None
+    gval, gchg, src, w, mask, ids = _hub_case(400, 6 * EBLK + 9, 700, 1.0,
+                                              seed=21, q=q)
+    mirror = _wl_mirror(src, mask, ids, gchg, 700)
+    monkeypatch.setattr(FR, "WL_LAUNCH_CELLS", 4)
+    assert mirror["wl_launched"] > FR.WL_LAUNCH_CELLS
+    if laned:
+        unitw = jnp.asarray([1, 0, 1], jnp.int32)
+        want = fused_relax_reduce_lanes_ref(gval, gchg, unitw, src, w, mask,
+                                            ids, 700, "add_w", "min")
+        got, dbg = fused_relax_reduce_lanes_pallas(
+            gval, gchg, unitw, src, w, mask, ids, 700, "add_w", "min",
+            interpret=True, grid_mode="worklist", with_debug=True)
+    else:
+        want = fused_relax_reduce_ref(gval, gchg, src, w, mask, ids, 700,
+                                      "add_w", "min")
+        got, dbg = fused_relax_reduce_pallas(
+            gval, gchg, src, w, mask, ids, 700, "add_w", "min",
+            interpret=True, grid_mode="worklist", with_debug=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert int(dbg[0]) == mirror["wl_cells"]
