@@ -1,0 +1,3 @@
+"""Compile seconds in the window of the cells that report ``teps``
+(``bench.readers.compile_s``)."""
+from bench.readers import compile_s as read  # noqa: F401
