@@ -1,0 +1,6 @@
+"""Seconds per PageRank iteration in the device scope ``collapse``: its
+seconds in the traced window over the window's iterations
+(``bench.spans``)."""
+from bench.spans import reader
+
+read = reader("scopes", "collapse")

@@ -1,0 +1,6 @@
+"""Seconds per PageRank iteration in the host span ``engine.sync``: its
+seconds in the traced window over the window's iterations
+(``bench.spans``)."""
+from bench.spans import reader
+
+read = reader("spans", "engine.sync")

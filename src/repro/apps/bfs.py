@@ -16,6 +16,7 @@ from repro.graph.graph import COOGraph
 UNREACHED = np.iinfo(np.int32).max
 
 
+@engine.app_span("bfs")
 def bfs(g: COOGraph, root: int, part: Partition | None = None,
         cfg: engine.EngineConfig = engine.EngineConfig(),
         num_shards: int = 16, rpvo_max: int = 1,

@@ -26,6 +26,7 @@ def _symmetrized_zero_weight(g: COOGraph) -> COOGraph:
     return COOGraph(g.n, src, dst, np.zeros(src.shape, np.float32)).dedup()
 
 
+@engine.app_span("cc")
 def cc(g: COOGraph, part: Partition | None = None,
        cfg: engine.EngineConfig = engine.EngineConfig(),
        num_shards: int = 16, rpvo_max: int = 1):

@@ -21,6 +21,7 @@ def _pr_graph(g: COOGraph) -> COOGraph:
     return COOGraph(g.n, g.src, g.dst, w)
 
 
+@engine.app_span("pagerank")
 def pagerank(g: COOGraph, damping: float = 0.85, iters: int = 30,
              part: Partition | None = None,
              cfg: engine.EngineConfig = engine.EngineConfig(),
@@ -40,6 +41,7 @@ def pagerank(g: COOGraph, damping: float = 0.85, iters: int = 30,
     return engine.vertex_values(part, val).astype(np.float64), part
 
 
+@engine.app_span("pagerank_delta")
 def pagerank_delta(g: COOGraph, damping: float = 0.85, tol=1e-7,
                    part: Partition | None = None,
                    cfg: engine.EngineConfig = engine.EngineConfig(),

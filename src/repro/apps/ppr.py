@@ -17,6 +17,7 @@ from repro.graph.graph import COOGraph
 from repro.query.lanes import run_ppr_lanes
 
 
+@engine.app_span("ppr")
 def personalized_pagerank(g: COOGraph, seeds, dampings=0.85,
                           part: Partition | None = None,
                           cfg: engine.EngineConfig = engine.EngineConfig(),

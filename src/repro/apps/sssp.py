@@ -12,6 +12,7 @@ from repro.core.partition import Partition, PartitionConfig, build_partition
 from repro.graph.graph import COOGraph
 
 
+@engine.app_span("sssp")
 def sssp(g: COOGraph, root: int, part: Partition | None = None,
          cfg: engine.EngineConfig = engine.EngineConfig(),
          num_shards: int = 16, rpvo_max: int = 1,

@@ -40,6 +40,8 @@ diffusions pruned.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import itertools
 import typing
 import warnings
 
@@ -171,20 +173,26 @@ class DeviceArrays(typing.NamedTuple):
 
     @classmethod
     def from_partition(cls, part: Partition) -> "DeviceArrays":
-        return cls(
-            edge_src_root_flat=jnp.asarray(part.edge_src_root_flat, jnp.int32),
-            edge_dst_flat=jnp.asarray(part.edge_dst_flat, jnp.int32),
-            edge_w=jnp.asarray(part.edge_w, jnp.float32),
-            edge_mask=jnp.asarray(part.edge_mask),
-            sibling_flat=jnp.asarray(part.sibling_flat, jnp.int32),
-            sibling_mask=jnp.asarray(part.sibling_mask),
-            slot_valid=jnp.asarray(part.slot_vertex >= 0),
-            edge_dst_compact=jnp.asarray(part.edge_dst_compact, jnp.int32),
-            inbox_slot_map=jnp.asarray(part.inbox_slot_map, jnp.int32),
-            rz_local=jnp.asarray(part.rz_local, jnp.int32),
-            rz_sibling_idx=jnp.asarray(part.rz_sibling_idx, jnp.int32),
-            rz_sibling_mask=jnp.asarray(part.rz_sibling_mask),
-        )
+        """Upload the partition's tables (an ``engine.upload`` span)."""
+        with obs.span("engine.upload") as sp:
+            arrays = cls(
+                edge_src_root_flat=jnp.asarray(part.edge_src_root_flat,
+                                               jnp.int32),
+                edge_dst_flat=jnp.asarray(part.edge_dst_flat, jnp.int32),
+                edge_w=jnp.asarray(part.edge_w, jnp.float32),
+                edge_mask=jnp.asarray(part.edge_mask),
+                sibling_flat=jnp.asarray(part.sibling_flat, jnp.int32),
+                sibling_mask=jnp.asarray(part.sibling_mask),
+                slot_valid=jnp.asarray(part.slot_vertex >= 0),
+                edge_dst_compact=jnp.asarray(part.edge_dst_compact,
+                                             jnp.int32),
+                inbox_slot_map=jnp.asarray(part.inbox_slot_map, jnp.int32),
+                rz_local=jnp.asarray(part.rz_local, jnp.int32),
+                rz_sibling_idx=jnp.asarray(part.rz_sibling_idx, jnp.int32),
+                rz_sibling_mask=jnp.asarray(part.rz_sibling_mask),
+            )
+            sp.note(bytes=sum(x.nbytes for x in arrays))
+        return arrays
 
 
 class RunStats(typing.NamedTuple):
@@ -232,17 +240,19 @@ def launch_planner(part: Partition, cfg: EngineConfig):
     id windows over ``S*S*P_t``."""
     from repro.kernels.fused_relax_reduce import WorklistPlanner
     S, R_max = part.S, part.R_max
-    if cfg.exchange == "compact":
-        P_t = part.P_t
-        offs = (np.arange(S, dtype=np.int64) * (S * P_t))[:, None]
-        ids = np.asarray(part.edge_dst_compact) + offs
-        num_segments = S * S * P_t
-    else:
-        ids = np.asarray(part.edge_dst_flat)
-        num_segments = S * R_max
-    return WorklistPlanner(
-        ids, np.asarray(part.edge_mask), np.asarray(part.edge_src_root_flat),
-        num_segments, smem_budget_bytes=cfg.smem_budget_bytes)
+    with obs.span("engine.plan"):
+        if cfg.exchange == "compact":
+            P_t = part.P_t
+            offs = (np.arange(S, dtype=np.int64) * (S * P_t))[:, None]
+            ids = np.asarray(part.edge_dst_compact) + offs
+            num_segments = S * S * P_t
+        else:
+            ids = np.asarray(part.edge_dst_flat)
+            num_segments = S * R_max
+        return WorklistPlanner(
+            ids, np.asarray(part.edge_mask),
+            np.asarray(part.edge_src_root_flat), num_segments,
+            smem_budget_bytes=cfg.smem_budget_bytes)
 
 
 def plan_round_worklist(planner, cfg: EngineConfig, gchg,
@@ -257,7 +267,10 @@ def plan_round_worklist(planner, cfg: EngineConfig, gchg,
     the launch actually uses."""
     thresh = (WORKLIST_AUTO_THRESHOLD if cfg.grid_mode == "auto"
               else None)
-    wl, info = planner.plan(gchg, max_live_fraction=thresh)
+    with obs.span("engine.plan") as sp:
+        wl, info = planner.plan(gchg, max_live_fraction=thresh)
+        if info is not None:
+            sp.note(cells=info.cells, launched=info.launched)
     return (wl, info) if with_info else wl
 
 
@@ -359,12 +372,11 @@ def run_stacked(sem: Semiring, part: Partition, init_val: np.ndarray,
         ) & arrays.slot_valid
     zero = jnp.zeros((), jnp.int64) if jax.config.jax_enable_x64 else jnp.zeros((), jnp.int32)
     stats0 = RunStats(zero, zero, zero, zero, zero)
-    val, chg, it, stats = lax.while_loop(
-        cond, body, (jnp.asarray(init_val), init_chg, zero, stats0)
-    )
-    # the whole fixpoint was ONE traced dispatch; reading the results
-    # below is its single host sync
-    _count_dispatches(sem.name, 1, 1)
+    # the whole fixpoint is ONE traced dispatch; the caller's read of its
+    # results (``vertex_values``) is its single host sync
+    with _dispatch(sem.name, 1):
+        val, chg, it, stats = lax.while_loop(
+            cond, body, (jnp.asarray(init_val), init_chg, zero, stats0))
     if cfg.collapse == "deferred":
         val = exchange.collapse(sem, val.reshape(-1), arrays.sibling_flat,
                                 arrays.sibling_mask)
@@ -386,6 +398,41 @@ def _count_dispatches(run: str, dispatches: int, host_syncs: int):
         "engine_host_syncs_total",
         "device->host sync points paid by engine fixpoint loops"
     ).labels(run=run).inc(host_syncs)
+
+
+def _dispatch(run: str, rnd: int) -> obs.ProgramSpan:
+    """``engine.dispatch`` span around the call that enqueues one device
+    program (tracing and compiling it first where no cached program
+    fits), counted in ``engine_dispatches_total``; ``rnd`` is the first
+    round the program runs."""
+    _count_dispatches(run, 1, 0)
+    return obs.span("engine.dispatch", track=f"engine/{run}", run=run,
+                    round=rnd)
+
+
+def _sync(run: str, rnd: int) -> obs.ProgramSpan:
+    """``engine.sync`` span around one device->host read, counted in
+    ``engine_host_syncs_total`` where it happens; ``rnd`` is the round
+    whose results it reads (0: the entering frontier)."""
+    _count_dispatches(run, 0, 1)
+    return obs.span("engine.sync", track=f"engine/{run}", run=run,
+                    round=rnd)
+
+
+_APP_CALLS = itertools.count(1)
+
+
+def app_span(name: str):
+    """Decorator of an app entry point: one ``app.<name>`` span per call,
+    whose ``call`` argument counts app calls process-wide; the engine's
+    spans of the call nest under it."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            with obs.span(f"app.{name}", call=next(_APP_CALLS)):
+                return fn(*args, **kwargs)
+        return call
+    return wrap
 
 
 def _host_stats(it, msgs, work, pruned):
@@ -427,7 +474,8 @@ def _run_stacked_hostloop(sem, part, arrays, cfg, init_val, init_changed):
     else:
         chg = sem.improved(val, jnp.full_like(val, sem.identity)) \
             & arrays.slot_valid
-    chg_h = np.asarray(chg)        # ONE frontier download per round:
+    with _sync(sem.name, 0):
+        chg_h = np.asarray(chg)    # ONE frontier download per round:
     it = msgs = work_total = pruned = 0   # reused for plan + accounting
     while it < cfg.max_iters:
         if not chg_h.any():
@@ -439,21 +487,24 @@ def _run_stacked_hostloop(sem, part, arrays, cfg, init_val, init_changed):
                                            with_info=True)
         frontier = int(gchg.sum()) if rec is not None else 0
         t0 = rec.tracer.now() if rec is not None else 0.0
-        span = (rec.tracer.span("round", track=f"engine/{sem.name}",
-                                round=it + 1) if rec is not None else None)
-        val, chg, mc = round_fn(val, chg, wl)
-        chg_h = np.asarray(chg)
-        mc, work = int(mc), int(chg_h.sum())
+        with obs.span("engine.round", track=f"engine/{sem.name}",
+                      round=it + 1) as rnd:
+            with _dispatch(sem.name, it + 1):
+                val, chg, mc = round_fn(val, chg, wl)
+            with _sync(sem.name, it + 1):
+                chg_h = np.asarray(chg)
+                mc = int(mc)
+            if rec is not None:
+                rnd.note(frontier=frontier, messages=mc)
+        work = int(chg_h.sum())
         it += 1
         msgs += mc
         work_total += work
         pruned += mc - min(work, mc)
         if rec is not None:
             wall = rec.tracer.now() - t0
-            span.end(frontier=frontier, messages=mc)
             _obs_record_round(rec, sem.name, part, cfg, planner, it, gchg,
                               frontier, mc, work, wl, info, wall)
-    _count_dispatches(sem.name, it, it)
     stats = _host_stats(it, msgs, work_total, pruned)
     if cfg.collapse == "deferred":
         val = exchange.collapse(sem, val.reshape(-1), arrays.sibling_flat,
@@ -543,33 +594,36 @@ def _run_stacked_deviceloop(sem, part, arrays, cfg, init_val, init_changed):
     else:
         chg = sem.improved(val, jnp.full_like(val, sem.identity)) \
             & arrays.slot_valid
-    chg_h = np.asarray(chg)
+    with _sync(sem.name, 0):
+        chg_h = np.asarray(chg)
     it = msgs = work_total = pruned = 0
     window = 0
     while it < cfg.max_iters and chg_h.any():
         k = min(cfg.device_window, cfg.max_iters - it)
         window += 1
         t0 = rec.tracer.now() if rec is not None else 0.0
-        span = (rec.tracer.span("window", track=f"engine/{sem.name}",
-                                window=window) if rec is not None else None)
-        val, chg, counts, frontiers = window_fn(k)(val, chg)
-        chg_h = np.asarray(chg)
-        wall = rec.tracer.now() - t0 if rec is not None else 0.0
-        ent = np.concatenate(
-            [np.asarray(frontiers).reshape(k, -1).astype(bool),
-             chg_h.reshape(1, -1)], axis=0)
-        live, w_msgs, w_work, w_pruned = _record_device_window(
-            rec, sem.name, part, planner, l_pad, window,
-            it + int((ent[:k].any(axis=1)).sum()), np.asarray(counts),
-            ent, wall)
+        with obs.span("engine.window", track=f"engine/{sem.name}",
+                      window=window) as win:
+            with _dispatch(sem.name, it + 1):
+                val, chg, counts, frontiers = window_fn(k)(val, chg)
+            with _sync(sem.name, it + 1):
+                chg_h = np.asarray(chg)
+                frontiers, counts = np.asarray(frontiers), \
+                    np.asarray(counts)
+            wall = rec.tracer.now() - t0 if rec is not None else 0.0
+            ent = np.concatenate(
+                [frontiers.reshape(k, -1).astype(bool),
+                 chg_h.reshape(1, -1)], axis=0)
+            live, w_msgs, w_work, w_pruned = _record_device_window(
+                rec, sem.name, part, planner, l_pad, window,
+                it + int((ent[:k].any(axis=1)).sum()), counts, ent, wall)
+            if rec is not None:
+                win.note(frontier=int(ent[0].sum()), messages=w_msgs,
+                         rounds=live)
         it += live
         msgs += w_msgs
         work_total += w_work
         pruned += w_pruned
-        if span is not None:
-            span.end(frontier=int(ent[0].sum()), messages=w_msgs,
-                     rounds=live)
-    _count_dispatches(sem.name, window, window)
     stats = _host_stats(it, msgs, work_total, pruned)
     if cfg.collapse == "deferred":
         val = exchange.collapse(sem, val.reshape(-1), arrays.sibling_flat,
@@ -593,9 +647,10 @@ def run_pagerank_stacked(part: Partition, damping: float, iters: int,
     val0 = jnp.where(arrays.slot_valid, 1.0 / part.n, 0.0)
     chg = arrays.slot_valid  # PR predicate is #t — always diffuse
     # the frontier never changes, so a host-planned worklist is planned
-    # once and reused by every traced round
+    # once (from the host's copy of it) and reused by every traced round
     wl = (plan_round_worklist(launch_planner(part, cfg), cfg,
-                              np.asarray(chg).reshape(-1))
+                              (np.asarray(part.slot_vertex) >= 0)
+                              .reshape(-1))
           if cfg.wants_worklist else None)
 
     def body(_, val):
@@ -604,7 +659,10 @@ def run_pagerank_stacked(part: Partition, damping: float, iters: int,
             worklist=wl)
         return new_val
 
-    val = lax.fori_loop(0, iters, body, val0)
+    # ONE dispatch for all ``iters`` rounds; the caller's read of the
+    # scores (``vertex_values``) is its single host sync
+    with _dispatch("pagerank", 1):
+        val = lax.fori_loop(0, iters, body, val0)
     return val
 
 
@@ -678,7 +736,8 @@ def run_pagerank_delta(part: Partition, damping: float = 0.85,
     rank, delta = rank0, delta0
     # each round returns next round's frontier — computed on device,
     # downloaded ONCE per round for planning + accounting alike
-    chg_h = np.asarray((jnp.abs(delta) > tol_t) & arrays.slot_valid)
+    with _sync("pagerank_delta", 0):
+        chg_h = np.asarray((jnp.abs(delta) > tol_t) & arrays.slot_valid)
     it = msgs = work_total = pruned = 0
     while it < max_rounds:
         if not chg_h.any():
@@ -690,21 +749,24 @@ def run_pagerank_delta(part: Partition, damping: float = 0.85,
                                            with_info=True)
         frontier = int(gchg.sum()) if rec is not None else 0
         t0 = rec.tracer.now() if rec is not None else 0.0
-        span = (rec.tracer.span("round", track="engine/pagerank_delta",
-                                round=it + 1) if rec is not None else None)
-        rank, delta, chg, mc = round_fn(rank, delta, wl)
-        chg_h = np.asarray(chg)
-        mc, work = int(mc), int(chg_h.sum())
+        with obs.span("engine.round", track="engine/pagerank_delta",
+                      round=it + 1) as rnd:
+            with _dispatch("pagerank_delta", it + 1):
+                rank, delta, chg, mc = round_fn(rank, delta, wl)
+            with _sync("pagerank_delta", it + 1):
+                chg_h = np.asarray(chg)
+                mc = int(mc)
+            if rec is not None:
+                rnd.note(frontier=frontier, messages=mc)
+        work = int(chg_h.sum())
         it += 1
         msgs += mc
         work_total += work
         pruned += mc - min(work, mc)
         if rec is not None:
             wall = rec.tracer.now() - t0
-            span.end(frontier=frontier, messages=mc)
             _obs_record_round(rec, "pagerank_delta", part, cfg, planner,
                               it, gchg, frontier, mc, work, wl, info, wall)
-    _count_dispatches("pagerank_delta", it, it)
     return rank, _host_stats(it, msgs, work_total, pruned)
 
 
@@ -746,10 +808,11 @@ def _run_pagerank_delta_deviceloop(sem, part, arrays, cfg, damping, tol_t,
             return lax.while_loop(
                 cond, body, (rank, delta, zero, zero, zero, zero))
 
-        rank, delta, it, msgs, work, pruned = fixpoint(rank, delta)
-        _count_dispatches("pagerank_delta", 1, 1)
-        return rank, _host_stats(int(it), int(msgs), int(work),
-                                 int(pruned))
+        with _dispatch("pagerank_delta", 1):
+            rank, delta, it, msgs, work, pruned = fixpoint(rank, delta)
+        with _sync("pagerank_delta", 1):
+            counts = [int(x) for x in (it, msgs, work, pruned)]
+        return rank, _host_stats(*counts)
 
     planner = launch_planner(part, cfg)
     l_pad = _device_l_pad(planner)
@@ -764,31 +827,36 @@ def _run_pagerank_delta_deviceloop(sem, part, arrays, cfg, damping, tol_t,
                     sem, arrays, cfg, S, R_max, _k, damping, tol_t, r, d))
         return window_fns[k]
 
-    chg_h = np.asarray((jnp.abs(delta) > tol_t) & arrays.slot_valid)
+    with _sync("pagerank_delta", 0):
+        chg_h = np.asarray((jnp.abs(delta) > tol_t) & arrays.slot_valid)
     it = msgs = work_total = pruned = 0
     window = 0
     while it < max_rounds and chg_h.any():
         k = min(cfg.device_window, max_rounds - it)
         window += 1
         t0 = rec.tracer.now()
-        span = rec.tracer.span("window", track="engine/pagerank_delta",
-                               window=window)
-        rank, delta, chg, counts, frontiers = window_fn(k)(rank, delta)
-        chg_h = np.asarray(chg)
-        wall = rec.tracer.now() - t0
-        ent = np.concatenate(
-            [np.asarray(frontiers).reshape(k, -1).astype(bool),
-             chg_h.reshape(1, -1)], axis=0)
-        live, w_msgs, w_work, w_pruned = _record_device_window(
-            rec, "pagerank_delta", part, planner, l_pad, window,
-            it + int((ent[:k].any(axis=1)).sum()), np.asarray(counts),
-            ent, wall)
+        with obs.span("engine.window", track="engine/pagerank_delta",
+                      window=window) as win:
+            with _dispatch("pagerank_delta", it + 1):
+                rank, delta, chg, counts, frontiers = \
+                    window_fn(k)(rank, delta)
+            with _sync("pagerank_delta", it + 1):
+                chg_h = np.asarray(chg)
+                frontiers, counts = np.asarray(frontiers), \
+                    np.asarray(counts)
+            wall = rec.tracer.now() - t0
+            ent = np.concatenate(
+                [frontiers.reshape(k, -1).astype(bool),
+                 chg_h.reshape(1, -1)], axis=0)
+            live, w_msgs, w_work, w_pruned = _record_device_window(
+                rec, "pagerank_delta", part, planner, l_pad, window,
+                it + int((ent[:k].any(axis=1)).sum()), counts, ent, wall)
+            win.note(frontier=int(ent[0].sum()), messages=w_msgs,
+                     rounds=live)
         it += live
         msgs += w_msgs
         work_total += w_work
         pruned += w_pruned
-        span.end(frontier=int(ent[0].sum()), messages=w_msgs, rounds=live)
-    _count_dispatches("pagerank_delta", window, window)
     return rank, _host_stats(it, msgs, work_total, pruned)
 
 
@@ -850,22 +918,29 @@ def run_pagerank_delta_sharded(part: Partition, damping: float = 0.85,
     it = msgs = work_total = pruned = 0
     rec = obs.get_recorder()
     rec_path = cfg.pallas_mode if cfg.use_pallas else "jnp"
+    run = "pagerank_delta_sharded"
     # the round's psum'd live-slot count IS the next round's frontier
     # size — only the initial frontier needs a host check
-    live = bool(((np.abs(np.asarray(delta)) > tol) & slot_valid).any())
+    with _sync(run, 0):
+        delta_h = np.asarray(delta)
+    live = bool(((np.abs(delta_h) > tol) & slot_valid).any())
     while live and it < max_rounds:
         if rec is not None:
             # recorder-only frontier download: the per-shard message
             # mirror needs the live-residual bitmap host-side
-            gchg = ((np.abs(np.asarray(delta)) > tol)
-                    & slot_valid).reshape(-1)
+            with _sync(run, it):
+                delta_h = np.asarray(delta)
+            gchg = ((np.abs(delta_h) > tol) & slot_valid).reshape(-1)
             frontier = int(gchg.sum())
             t0 = rec.tracer.now()
-            span = rec.tracer.span(
-                "round", track="engine/pagerank_delta_sharded",
-                round=it + 1)
-        rank, delta, counts, work = fn(arrays_dev, rank, delta)
-        mc, w = int(counts[0]), int(work[0])
+        with obs.span("engine.round", track=f"engine/{run}",
+                      round=it + 1) as rnd:
+            with _dispatch(run, it + 1):
+                rank, delta, counts, work = fn(arrays_dev, rank, delta)
+            with _sync(run, it + 1):
+                mc, w = int(counts[0]), int(work[0])
+            if rec is not None:
+                rnd.note(frontier=frontier, messages=mc)
         it += 1
         msgs += mc
         work_total += w
@@ -873,7 +948,6 @@ def run_pagerank_delta_sharded(part: Partition, damping: float = 0.85,
         live = w > 0
         if rec is not None:
             wall = rec.tracer.now() - t0
-            span.end(frontier=frontier, messages=mc)
             shard = exchange.shard_message_mirror(
                 part.edge_mask, part.edge_src_root_flat, gchg)
             rec.add_round(
@@ -885,7 +959,6 @@ def run_pagerank_delta_sharded(part: Partition, damping: float = 0.85,
                     shard_messages=[int(x) for x in shard]),
                 frontier_bitmap=gchg.copy() if rec.keep_frontiers
                 else None)
-    _count_dispatches("pagerank_delta_sharded", it, it)
     return rank, _host_stats(it, msgs, work_total, pruned)
 
 
@@ -996,8 +1069,10 @@ def run_sharded(sem: Semiring, part: Partition, init_val: np.ndarray,
     arrays = DeviceArrays.from_partition(part)
     arrays_dev = jax.tree.map(lambda x: jax.device_put(x, sharding), arrays)
     val_dev = jax.device_put(jnp.asarray(init_val), sharding)
-    val, stats = fn(arrays_dev, val_dev)
-    _count_dispatches(f"{sem.name}_sharded", 1, 1)
+    # ONE dispatch; the caller's read of the results (``vertex_values``)
+    # is its single host sync
+    with _dispatch(f"{sem.name}_sharded", 1):
+        val, stats = fn(arrays_dev, val_dev)
     stats = jax.tree.map(lambda x: x[0], stats)
     return val, stats
 
@@ -1051,7 +1126,8 @@ def run_pagerank_sharded(part: Partition, damping: float, iters: int,
         part.S, part.R_max, part.n, damping, iters, mesh, axis_names, cfg)
     arrays = DeviceArrays.from_partition(part)
     arrays_dev = jax.tree.map(lambda x: jax.device_put(x, sharding), arrays)
-    return fn(arrays_dev)
+    with _dispatch("pagerank_sharded", 1):
+        return fn(arrays_dev)
 
 
 # --------------------------------------------------------------------------
@@ -1074,6 +1150,10 @@ def init_values(part: Partition, sem: Semiring, sources: dict[int, float]):
 
 
 def vertex_values(part: Partition, val) -> np.ndarray:
-    """Extract the per-vertex (root-replica) values."""
+    """Extract the per-vertex (root-replica) values; reading a device
+    array back is an ``engine.sync``."""
+    if isinstance(val, jax.Array):
+        with _sync("vertex_values", 0):
+            val = np.asarray(val)
     gval = np.asarray(val).reshape(-1)
     return gval[part.root_flat]

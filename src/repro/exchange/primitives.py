@@ -10,8 +10,15 @@ The arrays argument is duck-typed against ``core.engine.DeviceArrays``
 (the static per-shard partition tables); ``cfg`` against
 ``core.engine.EngineConfig`` — this module must not import ``core.engine``
 (the engine imports *us*).
+
+The relax, the exchange and the collapse each run under a
+``jax.named_scope`` (``relax``, ``exchange``, ``collapse``), so their
+device operations carry that name in their ``op_name`` metadata; in a
+profile, the innermost of the three names an operation's layer.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -28,10 +35,22 @@ def _identity(sem: Semiring, dtype):
     return jnp.asarray(sem.identity, dtype)
 
 
+def scoped(name: str):
+    """Decorator: trace the function under ``jax.named_scope(name)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return call
+    return wrap
+
+
 # --------------------------------------------------------------------------
 # relax phase: gather frontier sources, build messages, partial-reduce
 # --------------------------------------------------------------------------
 
+@scoped("relax")
 def relax(sem: Semiring, cfg, edge_src, edge_w, edge_mask, ids, gval, gchg,
           num_segments: int, lane_unitw=None, worklist=None):
     """Relax phase over one edge set (flattened internally).
@@ -144,6 +163,7 @@ def relax(sem: Semiring, cfg, edge_src, edge_w, edge_mask, ids, gval, gchg,
 # stacked relax compositions (all shards resident on one device)
 # --------------------------------------------------------------------------
 
+@scoped("exchange")
 def stacked_dense_inbox(sem: Semiring, arrays, cfg, gval, gchg, total: int,
                         lane_unitw=None, worklist=None):
     """Stacked dense relax: the reduced (total[, Q]) global inbox + count.
@@ -163,6 +183,7 @@ def stacked_dense_inbox(sem: Semiring, arrays, cfg, gval, gchg, total: int,
     return reduce_axis0(sem, partial), counts.sum(axis=0)
 
 
+@scoped("exchange")
 def stacked_compact_partial(sem: Semiring, arrays, cfg, S: int, P_t: int,
                             gval, gchg, lane_unitw=None, worklist=None):
     """Stacked compact relax: (S_src, S_tgt, P_t[, Q]) partials + count.
@@ -192,6 +213,7 @@ def stacked_compact_partial(sem: Semiring, arrays, cfg, S: int, P_t: int,
 # inbox scatter + rhizome collapse
 # --------------------------------------------------------------------------
 
+@scoped("exchange")
 def scatter_inbox(sem: Semiring, recv_t, slot_map_t, R_max: int):
     """recv_t: (S_src, P_t[, Q]) contributions; slot_map_t: (S_src, P_t)
     local slots (R_max = pad).  Scatter-combine into (R_max[, Q])."""
@@ -204,6 +226,7 @@ def scatter_inbox(sem: Semiring, recv_t, slot_map_t, R_max: int):
     return out[:R_max]
 
 
+@scoped("collapse")
 def collapse(sem: Semiring, gx, sibling_flat, sibling_mask):
     """Rhizome collapse: AND-gate over all replicas of each slot's vertex.
 
@@ -228,6 +251,7 @@ def _sibling_reduce(sem: Semiring, g, sib_idx, sib_mask):
             else jnp.sum(sib, axis=0))
 
 
+@scoped("collapse")
 def compact_collapse(sem: Semiring, cand, rz_local, rz_sib_idx, rz_sib_mask,
                      gather_fn, R_max: int, R_rz_max: int):
     """Collapse only rhizome slots: compact-gather them, all-gather the

@@ -17,7 +17,7 @@ from jax import lax
 from repro.core.actions import Semiring
 from repro.exchange.primitives import (
     collapse, compact_collapse, reduce_axis0, relax, scatter_inbox,
-    stacked_compact_partial, stacked_dense_inbox,
+    scoped, stacked_compact_partial, stacked_dense_inbox,
 )
 
 
@@ -51,7 +51,8 @@ def stacked_inbox(sem: Semiring, arrays, cfg, S: int, R_max: int,
         P_t = arrays.inbox_slot_map.shape[-1]
         partial, counts = stacked_compact_partial(
             sem, arrays, cfg, S, P_t, gval, gchg, lane_unitw, worklist)
-        recv = jnp.swapaxes(partial, 0, 1)       # (S_tgt, S_src, P_t[, Q])
+        with jax.named_scope("exchange"):
+            recv = jnp.swapaxes(partial, 0, 1)   # (S_tgt, S_src, P_t[, Q])
         inbox = jax.vmap(lambda r, m: scatter_inbox(sem, r, m, R_max))(
             recv, arrays.inbox_slot_map)
         return inbox, counts
@@ -210,6 +211,7 @@ def delta_pagerank_window_stacked(sem: Semiring, arrays, cfg, S: int,
 # shard_map layout: one shard per device, real collectives
 # --------------------------------------------------------------------------
 
+@scoped("exchange")
 def shard_inbox(sem: Semiring, arrays_s, cfg, S: int, R_max: int,
                 axis_names, gval, gchg, lane_unitw=None):
     """Per-shard relax + real inbox exchange.

@@ -305,7 +305,12 @@ _DBG_SHAPE = jax.ShapeDtypeStruct((1,), jnp.int32)
 def _launch(kernel, scalars, ids_p, msg, grid, edge_map, out_block,
             out_map, out_rows, interpret, with_debug):
     """One pallas_call over lane-dense edge rows; ``msg`` is (e_pad,)
-    unlaned or (q_pad, e_pad) laned.  Returns (out[, dbg])."""
+    unlaned or (q_pad, e_pad) laned.  Returns (out[, dbg]).  The call is
+    named ``fused_relax_reduce``, with ``_wl`` for a worklist (1-D)
+    grid and ``_lanes`` for laned messages, so profiles name it the
+    same whatever the surrounding program."""
+    name = ("fused_relax_reduce" + ("_wl" if len(grid) == 1 else "")
+            + ("_lanes" if msg.ndim == 2 else ""))
     if msg.ndim == 1:
         msg_spec = pl.BlockSpec((ROWS, LANES), edge_map)
         msg = edge_rows(msg)
@@ -328,6 +333,7 @@ def _launch(kernel, scalars, ids_p, msg, grid, edge_map, out_block,
         ),
         out_shape=out_shape,
         interpret=interpret,
+        name=name,
     )(*scalars, edge_rows(ids_p), msg)
 
 

@@ -131,5 +131,6 @@ def segment_combine_pallas(data, segment_ids, num_segments: int, kind: str,
         ),
         out_shape=jax.ShapeDtypeStruct((n_i, 1, SBLK), jnp.float32),
         interpret=interpret,
+        name="segment_combine",
     )(chunk_lo, chunk_hi, edge_rows(ids_p), edge_rows(data_p))
     return out.reshape(s_pad)[:num_segments].astype(data.dtype)

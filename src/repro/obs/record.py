@@ -106,9 +106,6 @@ class FlightRecorder:
         m.gauge("engine_frontier",
                 "live slots entering the last round"
                 ).labels(run=run).set(record.frontier)
-        m.counter("engine_wall_seconds_total",
-                  "wall time inside engine rounds"
-                  ).labels(run=run).inc(record.wall_s)
         if record.shard_messages:
             m.gauge("engine_shard_message_skew",
                     "per-shard message balance, max/mean (1.0 = even)"

@@ -18,6 +18,16 @@ Spans nest naturally through the context manager::
 Distinct subsystems go on distinct "threads" of the trace via the
 ``track`` argument (engine rounds, serving ticks, per-request
 lifecycles each get a lane in the Perfetto UI).
+
+:func:`span` is the program's span primitive.  It always lands in
+``jax.profiler``'s trace (a ``TraceAnnotation``, on the same clock as
+the device's operations, recorded only while a profiler session runs)
+and, while a ``FlightRecorder`` is installed, also on the recorder's
+tracer::
+
+    with obs.span("engine.plan", run="bfs") as sp:
+        ...
+        sp.note(cells=12, launched=16)
 """
 from __future__ import annotations
 
@@ -150,3 +160,62 @@ class Tracer:
     def clear(self):
         with self._lock:
             self._events.clear()
+
+
+# -- the program's span primitive ---------------------------------------
+
+_annotation = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so that
+    importing ``repro.obs`` pulls in no jax."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class ProgramSpan:
+    """One span of :func:`span`: a profiler annotation, plus a recorder
+    span while a ``FlightRecorder`` is installed.  Adds no host sync."""
+
+    __slots__ = ("name", "track", "args", "_ann", "_rec_span")
+
+    def __init__(self, name: str, track: str, args: dict):
+        self.name = name
+        self.track = track
+        self.args = args
+        self._ann = None
+        self._rec_span = None
+
+    def __enter__(self):
+        from repro.obs.record import get_recorder
+        self._ann = _trace_annotation()(self.name, **self.args)
+        self._ann.__enter__()
+        rec = get_recorder()
+        if rec is not None:
+            self._rec_span = rec.tracer.span(self.name, track=self.track,
+                                             args=self.args)
+        return self
+
+    def note(self, **args):
+        """Arguments known only inside the span (counts, sizes): both
+        sinks record them with the span."""
+        self._ann.set_metadata(**args)
+        if self._rec_span is not None:
+            self._rec_span.args = dict(self._rec_span.args or {}, **args)
+
+    def __exit__(self, *exc):
+        if self._rec_span is not None:
+            self._rec_span.end()
+        self._ann.__exit__(*exc)
+        return False
+
+
+def span(name: str, track: str = "main", **args) -> ProgramSpan:
+    """Context manager naming one step of the program: ``name`` and
+    ``args`` go to the profiler's trace and, with a recorder installed,
+    to its tracer on lane ``track``."""
+    return ProgramSpan(name, track, args)

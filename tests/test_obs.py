@@ -503,3 +503,144 @@ def test_sharded_skew_recorded_subprocess():
         capture_output=True, text=True, timeout=420)
     assert out.returncode == 0, f"stdout={out.stdout}\nstderr={out.stderr}"
     assert "SHARDED_SKEW_OK" in out.stdout
+
+
+# --------------------------------------------------------------------------
+# program spans: obs.span on the profiler's clock and on the recorder
+# --------------------------------------------------------------------------
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under ``jax.profiler`` and return the host events of
+    the program's spans as (name, start_ns, end_ns, args) in start order."""
+    import glob
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(("app.", "engine.")):
+                    events.append((ev.name, ev.start_ns, ev.end_ns,
+                                   dict(ev.stats)))
+    return sorted(events, key=lambda ev: (ev[1], -ev[2]))
+
+
+def _inside(events, outer, name):
+    return [ev for ev in events if ev[0] == name
+            and outer[1] <= ev[1] and ev[2] <= outer[2]]
+
+
+def _syncs_and_dispatches():
+    reg = obs.registry()
+    return tuple(sum(reg.counter(c).snapshot_values().values())
+                 for c in ("engine_host_syncs_total",
+                           "engine_dispatches_total"))
+
+
+def test_program_spans_in_profiler_trace(tmp_path):
+    """An app call is one ``app.<name>`` span holding the engine's
+    upload, dispatch and sync spans; the BFS host loop dispatches once
+    and syncs at least once inside each round; every ``engine.sync`` and
+    ``engine.dispatch`` span is counted in the registry; and with no
+    recorder installed nothing is recorded on a tracer."""
+    from repro import apps
+    g, part, root = _small_case()
+    cfg = engine.EngineConfig(use_pallas=True, grid_mode="worklist")
+    idle = obs.FlightRecorder()           # never installed
+    before = _syncs_and_dispatches()
+    out = {}
+
+    def calls():
+        out["pr"] = apps.pagerank(g, iters=3, part=part, cfg=cfg)
+        out["bfs"] = apps.bfs(g, root, part=part, cfg=cfg)
+
+    events = _profiled(tmp_path, calls)
+    assert idle.tracer.events() == []
+    pr, = [ev for ev in events if ev[0] == "app.pagerank"]
+    bfs, = [ev for ev in events if ev[0] == "app.bfs"]
+    assert bfs[3]["call"] == pr[3]["call"] + 1
+    for name in ("engine.upload", "engine.plan", "engine.dispatch",
+                 "engine.sync"):
+        assert _inside(events, pr, name), name
+    assert len(_inside(events, pr, "engine.dispatch")) == 1
+
+    rounds = _inside(events, bfs, "engine.round")
+    assert len(rounds) == int(out["bfs"][1].iterations) > 1
+    for rnd in rounds:
+        assert len(_inside(events, rnd, "engine.dispatch")) == 1
+        assert len(_inside(events, rnd, "engine.sync")) >= 1
+    assert _inside(events, bfs, "engine.upload")
+
+    syncs, dispatches = (a - b for a, b in
+                         zip(_syncs_and_dispatches(), before))
+    assert syncs == sum(ev[0] == "engine.sync" for ev in events)
+    assert dispatches == sum(ev[0] == "engine.dispatch" for ev in events)
+
+
+def test_program_spans_on_recorder():
+    """With a recorder installed the same spans land on its tracer."""
+    from repro import apps
+    g, part, root = _small_case()
+    cfg = engine.EngineConfig(use_pallas=True, grid_mode="worklist")
+    with obs.recording() as rec:
+        apps.bfs(g, root, part=part, cfg=cfg)
+        apps.pagerank(g, iters=3, part=part, cfg=cfg)
+    evs = rec.tracer.to_chrome()["traceEvents"]
+    names = {e["name"] for e in evs if e["ph"] == "X"}
+    assert {"app.bfs", "app.pagerank", "engine.upload", "engine.plan",
+            "engine.round", "engine.dispatch", "engine.sync"} <= names
+    rnd = next(e for e in evs if e["name"] == "engine.round")
+    assert {"round", "frontier", "messages"} <= set(rnd["args"])
+    plan = [e for e in evs if e["name"] == "engine.plan"
+            and "cells" in e.get("args", {})]
+    assert plan and all(e["args"]["launched"] >= e["args"]["cells"]
+                        for e in plan)
+
+
+def test_import_obs_pulls_in_no_jax():
+    code = ("import sys, repro.obs; "
+            "assert 'jax' not in sys.modules, 'jax imported'")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+
+
+# --------------------------------------------------------------------------
+# stable device names: the exchange layer's named scopes
+# --------------------------------------------------------------------------
+
+def _op_names(text):
+    import re
+    return {part for loc in re.findall(r'loc\("([^"]*)"', text)
+            for part in loc.split("/")}
+
+
+@pytest.mark.parametrize("exchange_mode", ["dense", "compact"])
+@pytest.mark.parametrize("app", ["pagerank", "bfs"])
+def test_round_op_names_carry_scopes(app, exchange_mode):
+    """The lowered stacked PageRank and BFS rounds carry ``relax``,
+    ``exchange`` and ``collapse`` in their ``op_name`` metadata."""
+    _, part, root = _small_case()
+    cfg = engine.EngineConfig(use_pallas=True, exchange=exchange_mode)
+    arrays = engine.DeviceArrays.from_partition(part)
+    val = jnp.asarray(engine.init_values(part, actions.BFS, {root: 0.0}))
+    chg = arrays.slot_valid
+    if app == "pagerank":
+        def fn(v, c):
+            return exchange.pagerank_round_stacked(
+                actions.PAGERANK, arrays, cfg, part.S, part.R_max,
+                0.15 / part.n, 0.85, v, c)
+    else:
+        def fn(v, c):
+            return exchange.fixpoint_round_stacked(
+                actions.BFS, arrays, cfg, part.S, part.R_max, v, c)
+    text = jax.jit(fn).lower(val, chg).as_text(debug_info=True)
+    assert {"relax", "exchange", "collapse"} <= _op_names(text)

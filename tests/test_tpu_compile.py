@@ -114,6 +114,46 @@ def test_fused_launcher_compiles(one_chip, mosaic, laned, grid_mode, relax,
     assert "tpu_custom_call" in _compiled_text(fn, *args)
 
 
+@pytest.mark.parametrize("laned", [False, True], ids=["single", "lanes"])
+@pytest.mark.parametrize("worklist", [False, True], ids=["dense", "wl"])
+def test_kernels_carry_stable_names(one_chip, laned, worklist):
+    """Each pallas_call is named, so a chip profile shows the kernel by
+    that name: ``fused_relax_reduce`` (``_wl`` for the worklist grid,
+    ``_lanes`` for laned messages) and ``segment_combine``.  Lowered
+    only, at small shapes."""
+    v, e, q, cells = 4096, 8192, 8, 64
+    shape = (v, q) if laned else (v,)
+
+    def s(shp, dt):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    args = [s(shape, jnp.float32), s(shape, jnp.bool_)]
+    if laned:
+        args.append(s((q,), jnp.int32))
+    args += _edges(one_chip, e)
+    n_tables = len(args)
+    if worklist:
+        args += [s((cells,), jnp.int32)] * 2 + [s((1,), jnp.int32)]
+    launch = (fused_relax_reduce_lanes_pallas if laned
+              else fused_relax_reduce_pallas)
+
+    def fn(*a):
+        wl = Worklist(*a[n_tables:]) if worklist else None
+        return launch(*a[:n_tables], v, "add_w", "min", interpret=False,
+                      with_count=True, worklist=wl)
+
+    name = ("fused_relax_reduce" + ("_wl" if worklist else "")
+            + ("_lanes" if laned else ""))
+    text = jax.jit(fn).lower(*args).as_text()
+    assert f'kernel_name = "{name}"' in text
+    if not worklist and not laned:
+        text = jax.jit(
+            lambda d, i: segment_combine_pallas(d, i, v, "min",
+                                                interpret=False)
+        ).lower(s((e,), jnp.float32), s((e,), jnp.int32)).as_text()
+        assert 'kernel_name = "segment_combine"' in text
+
+
 @pytest.mark.parametrize("kind", ["min", "sum"])
 def test_segment_combine_compiles(one_chip, mosaic, kind):
     """The unfused reduce kernel (``pallas_mode='reduce'``)."""
