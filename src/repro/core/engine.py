@@ -243,9 +243,12 @@ def launch_planner(part: Partition, cfg: EngineConfig):
     with obs.span("engine.plan"):
         if cfg.exchange == "compact":
             P_t = part.P_t
-            offs = (np.arange(S, dtype=np.int64) * (S * P_t))[:, None]
-            ids = np.asarray(part.edge_dst_compact) + offs
             num_segments = S * S * P_t
+            # offset in int32 unless the id windows overflow it
+            ids = np.asarray(part.edge_dst_compact).astype(
+                np.int32 if num_segments <= np.iinfo(np.int32).max
+                else np.int64)
+            ids += (np.arange(S, dtype=ids.dtype) * (S * P_t))[:, None]
         else:
             ids = np.asarray(part.edge_dst_flat)
             num_segments = S * R_max

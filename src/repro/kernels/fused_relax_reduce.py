@@ -463,46 +463,80 @@ def _wl_pad_len(nlive: int, pad_to: int = WL_PAD) -> int:
     return max(pad_to, 1 << max(nlive - 1, 0).bit_length())
 
 
+def _index_dtype(n: int):
+    """The narrowest of int32/int64 that holds indices up to ``n``."""
+    return np.int32 if n <= np.iinfo(np.int32).max else np.int64
+
+
 class WorklistPlanner:
-    """Precomputes the frontier-independent parts of worklist planning
-    for one launch shape (one edge set + segment count), so per-round
-    plans only pay the frontier-dependent work.
+    """Worklist planning for one launch shape (one edge set + segment
+    count), split into the part built once and the part paid per
+    frontier.
 
     ``edge_dst``/``edge_mask``/``edge_src`` may be (S, E_max) stacked or
-    flat — flattened exactly as the kernels flatten them.  Each chunk's
-    intersecting segment blocks are the contiguous range
-    ``[lo // SBLK, hi // SBLK]`` of its valid ids, so planning works on
-    those ranges and never builds the (n_sblk, n_chunks) grid.
-    ``plan(gchg)`` returns (Worklist, WorklistInfo); for laned launches
-    pass the OR-across-lanes frontier."""
+    flat — flattened exactly as the kernels flatten them.  The planner
+    keeps ``edge_src`` and ``edge_mask`` as given (no copy when they are
+    already intp / bool), so they must not be written in place while it
+    is in use.
+
+    Built once, in O(E): each chunk's intersecting segment blocks are
+    the contiguous range ``[lo // SBLK, hi // SBLK]`` of its valid ids.
+    Numbering those ranges chunk after chunk gives every candidate cell
+    of the dense grid's two-level skip an index in the launch's j-major
+    order, and each valid edge keeps the index of the cell its
+    destination lands in, as int32 (int64 only when the ids or the cell
+    count overflow it).  The (n_sblk, n_chunks) grid is never built, nor
+    a padded copy of an edge table.
+
+    Per frontier, ``plan(gchg)`` is O(E + candidate cells): one gather
+    of the frontier at the edge sources, then one scatter of the active
+    edges into a bitmap over the candidate cells.  It returns
+    (Worklist, WorklistInfo); for laned launches pass the OR-across-lanes
+    frontier."""
 
     def __init__(self, edge_dst, edge_mask, edge_src, num_segments: int,
                  *, smem_budget_bytes: int | None = None):
         ids = np.asarray(edge_dst).reshape(-1)
-        mask = np.asarray(edge_mask).reshape(-1)
-        srcs = np.asarray(edge_src).reshape(-1)
         e = ids.shape[0]
-        e_pad = _round_up(e, EBLK)
+        # the frontier gather wants intp indices: any other dtype is
+        # converted on every plan
+        self.srcs = np.asarray(edge_src).reshape(-1).astype(np.intp,
+                                                            copy=False)
+        self.mask = np.asarray(edge_mask).reshape(-1).astype(bool,
+                                                             copy=False)
         self.num_segments = int(num_segments)
         self.n_i = _round_up(num_segments, SBLK) // SBLK
-        self.n_chunks = e_pad // EBLK
+        self.n_chunks = _round_up(e, EBLK) // EBLK
         self.smem_budget_bytes = smem_budget_bytes
         self._smem_warned = False
+        self._chunk0 = np.arange(0, e, EBLK)     # first edge of each chunk
 
-        def chunked(x, dtype):
-            out = np.zeros(e_pad, dtype)
-            out[:e] = x
-            return out.reshape(self.n_chunks, EBLK)
+        # masked-out edges may carry any id: they never reach a cell
+        blk = ids.astype(_index_dtype(self.num_segments), copy=False) \
+            // SBLK                              # dst block of each edge
+        lo = self._per_chunk(np.minimum,
+                             np.where(self.mask, blk,
+                                      np.iinfo(blk.dtype).max))
+        hi = self._per_chunk(np.maximum, np.where(self.mask, blk, -1))
+        has = self._per_chunk(np.logical_or, self.mask)
+        self.lo_blk = np.where(has, lo, 0)
+        self.span = np.where(has, hi - self.lo_blk + 1, 0)
+        self.cell_end = np.cumsum(self.span)     # candidate cells, j-major
+        self.cell0 = self.cell_end - self.span
+        self.n_cand = int(self.cell_end[-1]) if self.n_chunks else 0
+        cdt = _index_dtype(max(self.n_cand, self.n_i))
+        # each valid edge's candidate cell; a masked-out edge's entry is
+        # meaningless and never read
+        self.cell = blk.astype(cdt, copy=False)
+        self.cell += np.repeat((self.cell0 - self.lo_blk).astype(cdt),
+                               EBLK)[:e]
 
-        ids_c = chunked(ids, np.int64)
-        self.mask = chunked(mask, bool)
-        self.srcs = chunked(srcs, np.int64)
-        lo = np.where(self.mask, ids_c, np.iinfo(np.int64).max).min(axis=1)
-        hi = np.where(self.mask, ids_c, -1).max(axis=1)
-        has = self.mask.any(axis=1)
-        self.lo_blk = np.where(has, lo // SBLK, 0)
-        self.span = np.where(has, hi // SBLK - self.lo_blk + 1, 0)
-        self.blk_of = ids_c // SBLK              # dst block of each edge
+    def _per_chunk(self, ufunc, x):
+        """``ufunc`` reduced over each EBLK-edge chunk (the tail chunk
+        may be shorter)."""
+        if not self.n_chunks:
+            return np.zeros(0, x.dtype)
+        return ufunc.reduceat(x, self._chunk0)
 
     @property
     def total_cells(self) -> int:
@@ -510,17 +544,8 @@ class WorklistPlanner:
 
     def _active(self, gchg):
         gchg = np.asarray(gchg).reshape(-1)
-        act = self.mask & gchg[self.srcs]        # (n_chunks, EBLK)
-        return act, act.any(axis=1)
-
-    def _cells(self, live_chunk):
-        """j-major (chunk, block) pairs of the dense grid's live cells."""
-        jj = np.nonzero(live_chunk)[0]
-        span = self.span[jj]
-        jj = np.repeat(jj, span)
-        start = np.repeat(np.cumsum(span) - span, span)
-        ii = self.lo_blk[jj] + (np.arange(jj.shape[0]) - start)
-        return jj, ii
+        act = self.mask & np.take(gchg, self.srcs)   # (E,)
+        return act, self._per_chunk(np.logical_or, act)
 
     def live_fraction(self, gchg) -> float:
         """Fraction of the dense grid the two-level skip would execute —
@@ -540,11 +565,15 @@ class WorklistPlanner:
 
     def plan(self, gchg, pad_to: int = WL_PAD, dst_filter: bool = True,
              max_live_fraction: float | None = None):
-        """Plan one round's launch from the (V,) bool frontier.
+        """Plan one round's launch from the (V,) bool frontier, in
+        O(E + candidate cells) and with no (cells, EBLK) temporary.
 
         j-major cell order (j outer, i inner); with ``dst_filter`` a
         cell is kept only when some frontier-active edge of its chunk
         lands in its block — the others contribute only the identity.
+        The active edges mark their cells in a bitmap over the
+        candidate cells; without the filter every candidate cell of a
+        live chunk is kept.
 
         ``max_live_fraction`` implements 'auto' cheaply: when the dense
         grid's live fraction is at/above it, return (None, None) BEFORE
@@ -557,10 +586,14 @@ class WorklistPlanner:
                 and dense_live / max(self.total_cells, 1) \
                 >= max_live_fraction:
             return None, None
-        jj, ii = self._cells(live_chunk)
         if dst_filter:
-            keep = (act[jj] & (self.blk_of[jj] == ii[:, None])).any(axis=1)
-            jj, ii = jj[keep], ii[keep]
+            hit = np.zeros(self.n_cand, bool)
+            hit[self.cell[act]] = True
+        else:
+            hit = np.repeat(live_chunk, self.span)
+        k = np.flatnonzero(hit)
+        jj = np.searchsorted(self.cell_end, k, side="right")
+        ii = self.lo_blk[jj] + (k - self.cell0[jj])
         nlive = int(ii.shape[0])
         l_pad = _wl_pad_len(nlive, pad_to)
         wl_i = np.zeros(l_pad, np.int32)

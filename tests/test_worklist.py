@@ -656,3 +656,133 @@ def test_worklist_launch_slices_match_one_launch(monkeypatch, laned):
             interpret=True, grid_mode="worklist", with_debug=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert int(dbg[0]) == mirror["wl_cells"]
+
+
+# --------------------------------------------------------------------------
+# the O(E) planner == the per-cell destination filter, exactly
+# --------------------------------------------------------------------------
+
+def _per_cell_plan(ids, mask, src, gchg, nseg, dst_filter):
+    """Independent oracle: list every live chunk's candidate blocks, then
+    keep a cell when some active edge of its chunk lands in its block,
+    testing the chunk's EBLK edges cell by cell."""
+    ids = np.asarray(ids).reshape(-1).astype(np.int64)
+    e = ids.shape[0]
+    e_pad = -(-e // EBLK) * EBLK
+    n_chunks, n_i = e_pad // EBLK, -(-nseg // SBLK)
+
+    def chunked(x, dtype):
+        out = np.zeros(e_pad, dtype)
+        out[:e] = x
+        return out.reshape(n_chunks, EBLK)
+
+    ids_c = chunked(ids, np.int64)
+    mask_c = chunked(np.asarray(mask).reshape(-1), bool)
+    act = mask_c & np.asarray(gchg).reshape(-1)[
+        chunked(np.asarray(src).reshape(-1), np.int64)]
+    lo = np.where(mask_c, ids_c, np.iinfo(np.int64).max).min(axis=1)
+    hi = np.where(mask_c, ids_c, -1).max(axis=1)
+    has = mask_c.any(axis=1)
+    lo_blk = np.where(has, lo // SBLK, 0)
+    span = np.where(has, hi // SBLK - lo_blk + 1, 0)
+    live_chunk = act.any(axis=1)
+    jj = np.nonzero(live_chunk)[0]
+    jj = np.repeat(jj, span[jj])
+    start = np.repeat(np.cumsum(span[live_chunk]) - span[live_chunk],
+                      span[live_chunk])
+    ii = lo_blk[jj] + (np.arange(jj.shape[0]) - start)
+    if dst_filter:
+        keep = (act[jj] & (ids_c[jj] // SBLK == ii[:, None])).any(axis=1)
+        jj, ii = jj[keep], ii[keep]
+    n = int(ii.shape[0])
+    launched = max(WL_PAD, 1 << max(n - 1, 0).bit_length())
+    info = dict(cells=n, launched=launched,
+                dense_live=int(span[live_chunk].sum()),
+                smem_table_bytes=smem_table_bytes(n_chunks, launched))
+    return ii, jj, info
+
+
+def _planner_case(case, seed=0):
+    """(planner, ids, mask, src, nseg, V, q) for one edge layout; the
+    oracle reads the same ids the planner was built from."""
+    rng = np.random.default_rng(seed)
+    if case.startswith("engine_"):
+        exchange = case[len("engine_"):]
+        g = generators.rmat(9, edge_factor=6, seed=seed)
+        part = build_partition(g, PartitionConfig(num_shards=4,
+                                                  rpvo_max=4))
+        cfg = engine.EngineConfig(use_pallas=True, grid_mode="worklist",
+                                  exchange=exchange)
+        S = part.S
+        if exchange == "compact":
+            ids = part.edge_dst_compact + (
+                np.arange(S, dtype=np.int64) * (S * part.P_t))[:, None]
+            nseg = S * S * part.P_t
+        else:
+            ids, nseg = part.edge_dst_flat, S * part.R_max
+        return (engine.launch_planner(part, cfg), ids, part.edge_mask,
+                part.edge_src_root_flat, nseg, S * part.R_max, None)
+    v, nseg = 700, 6 * SBLK + 9
+    e = {"short_tail": 3 * EBLK + 77}.get(case, 4 * EBLK)
+    ids = rng.integers(0, nseg, e)
+    mask = rng.random(e) < 0.95
+    if case == "sorted_per_shard":
+        ids = np.sort(ids.reshape(4, -1), axis=1)
+    elif case == "mask_holes":
+        mask = rng.random(e) < 0.3
+        mask[EBLK:2 * EBLK] = False               # one wholly dead chunk
+        ids[~mask] = rng.integers(-5, 10 * nseg, int((~mask).sum()))
+    src = rng.integers(0, v, ids.shape).astype(np.int32)
+    mask = mask.reshape(ids.shape)
+    planner = WorklistPlanner(ids, mask, src, nseg)
+    return planner, ids, mask, src, nseg, v, (3 if case == "lanes_or"
+                                              else None)
+
+
+@pytest.mark.parametrize("dst_filter", [True, False])
+@pytest.mark.parametrize("frontier", ["empty", "sparse", "full"])
+@pytest.mark.parametrize("case", [
+    "unsorted", "sorted_per_shard", "mask_holes", "short_tail", "lanes_or",
+    "engine_dense", "engine_compact"])
+def test_planner_equals_per_cell_filter(case, frontier, dst_filter):
+    planner, ids, mask, src, nseg, v, q = _planner_case(case)
+    rng = np.random.default_rng(7)
+    shape = (v,) if q is None else (v, q)
+    gchg = {"empty": np.zeros(shape, bool),
+            "sparse": rng.random(shape) < 0.02,
+            "full": np.ones(shape, bool)}[frontier]
+    if q is not None:                            # laned: OR across lanes
+        gchg = gchg.any(axis=-1)
+    wl, info = planner.plan(gchg, dst_filter=dst_filter)
+    ii, jj, want = _per_cell_plan(ids, mask, src, gchg, nseg, dst_filter)
+    assert info._asdict() == want
+    assert int(wl.nlive[0]) == want["cells"]
+    n, launched = want["cells"], want["launched"]
+    np.testing.assert_array_equal(
+        wl.wl_i, np.concatenate([ii, np.zeros(launched - n)]))
+    np.testing.assert_array_equal(
+        wl.wl_j, np.concatenate([jj, np.zeros(launched - n)]))
+    if frontier != "empty":
+        assert n > 0
+
+
+def test_planner_memory_scales_with_edges_not_cells():
+    """Unsorted ids put every chunk against every block, so a per-cell
+    destination filter would gather (cells, EBLK) edges — tens of times
+    the edge tables.  Building and planning must stay within a small
+    multiple of the edge count."""
+    import tracemalloc
+    rng = np.random.default_rng(3)
+    e, nseg, v = 64 * EBLK, 64 * SBLK, 4096
+    ids = rng.integers(0, nseg, e).astype(np.int32)
+    mask = rng.random(e) < 0.9
+    src = rng.integers(0, v, e).astype(np.int32)
+    tracemalloc.start()
+    try:
+        planner = WorklistPlanner(ids, mask, src, nseg)
+        _, info = planner.plan(np.ones(v, bool))
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert info.dense_live == 64 * 64          # every cell a candidate
+    assert peak < 40 * e, peak
