@@ -17,7 +17,8 @@ UNREACHED = np.iinfo(np.int32).max
 
 
 @engine.app_span("bfs")
-def bfs(g: COOGraph, root: int, part: Partition | None = None,
+def bfs(g: COOGraph, root: int,
+        part: Partition | engine.ResidentPartition | None = None,
         cfg: engine.EngineConfig = engine.EngineConfig(),
         num_shards: int = 16, rpvo_max: int = 1,
         mesh=None, axis_names=("data", "model")):

@@ -27,7 +27,8 @@ def _symmetrized_zero_weight(g: COOGraph) -> COOGraph:
 
 
 @engine.app_span("cc")
-def cc(g: COOGraph, part: Partition | None = None,
+def cc(g: COOGraph,
+       part: Partition | engine.ResidentPartition | None = None,
        cfg: engine.EngineConfig = engine.EngineConfig(),
        num_shards: int = 16, rpvo_max: int = 1):
     """Returns (labels (n,) int64 — min vertex id per weakly connected
@@ -41,8 +42,8 @@ def cc(g: COOGraph, part: Partition | None = None,
             PartitionConfig(num_shards=num_shards, rpvo_max=rpvo_max))
     # vertex-id initial values on every replica (consistent view); every
     # vertex is initially changed, so labels flood from round one
-    init = np.where(part.slot_vertex >= 0,
-                    part.slot_vertex.astype(np.float32), np.inf)
+    slot_vertex = engine.partition_of(part).slot_vertex
+    init = np.where(slot_vertex >= 0, slot_vertex.astype(np.float32), np.inf)
     val, stats = run_stacked_lanes(part, init[..., None],
                                    lane_unitw=np.zeros(1, np.int32), cfg=cfg)
     labels = engine.vertex_values(part, val[..., 0]).astype(np.int64)

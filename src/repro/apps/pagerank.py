@@ -23,7 +23,7 @@ def _pr_graph(g: COOGraph) -> COOGraph:
 
 @engine.app_span("pagerank")
 def pagerank(g: COOGraph, damping: float = 0.85, iters: int = 30,
-             part: Partition | None = None,
+             part: Partition | engine.ResidentPartition | None = None,
              cfg: engine.EngineConfig = engine.EngineConfig(),
              num_shards: int = 16, rpvo_max: int = 1,
              mesh=None, axis_names=("data", "model")):
@@ -43,7 +43,7 @@ def pagerank(g: COOGraph, damping: float = 0.85, iters: int = 30,
 
 @engine.app_span("pagerank_delta")
 def pagerank_delta(g: COOGraph, damping: float = 0.85, tol=1e-7,
-                   part: Partition | None = None,
+                   part: Partition | engine.ResidentPartition | None = None,
                    cfg: engine.EngineConfig = engine.EngineConfig(),
                    num_shards: int = 16, rpvo_max: int = 1,
                    mesh=None, axis_names=("data", "model"),
@@ -53,6 +53,10 @@ def pagerank_delta(g: COOGraph, damping: float = 0.85, tol=1e-7,
     round and the engine's diffusion pruning (chunk skip, worklist
     launch, tile filter) finally fires for the sum semiring.  Converges
     to the ``pagerank`` fixpoint within O(tol / (1-damping)) per vertex.
+
+    ``part`` may be an ``engine.ResidentPartition`` of the 1/out-degree
+    weighted graph: its tables stay on the device and its round programs
+    stay compiled across calls.
 
     Returns (scores (n,) float64, RunStats, partition)."""
     if part is None:

@@ -13,7 +13,8 @@ from repro.graph.graph import COOGraph
 
 
 @engine.app_span("sssp")
-def sssp(g: COOGraph, root: int, part: Partition | None = None,
+def sssp(g: COOGraph, root: int,
+         part: Partition | engine.ResidentPartition | None = None,
          cfg: engine.EngineConfig = engine.EngineConfig(),
          num_shards: int = 16, rpvo_max: int = 1,
          mesh=None, axis_names=("data", "model")):

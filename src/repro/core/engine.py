@@ -258,6 +258,70 @@ def launch_planner(part: Partition, cfg: EngineConfig):
             smem_budget_bytes=cfg.smem_budget_bytes)
 
 
+def _round_program(run: str, step, sem, cfg, arrays, *state, **kw):
+    """One host-loop round as a program of its arguments: ``step`` (an
+    ``exchange`` round composition) over the tables ``arrays``, the
+    round's state and its worklist.  The body runs only while jit traces
+    it, so each run is one new round program, counted in
+    ``engine_round_programs_total``."""
+    obs.registry().counter(
+        "engine_round_programs_total",
+        "round programs traced (then compiled) by engine host loops"
+    ).labels(run=run).inc()
+    S, R_max = arrays.slot_valid.shape
+    return step(sem, arrays, cfg, S, R_max, *state, **kw)
+
+
+class ResidentPartition:
+    """A partition held on the device across calls: its tables uploaded
+    once (``DeviceArrays``), its ``WorklistPlanner`` built once per
+    ``EngineConfig``, and the host loops' round programs.  A round program
+    takes the tables, the round's state and its worklist as arguments, so
+    a second call traces and compiles nothing; only a worklist length
+    bucket not seen before adds a program.
+
+    Every runner takes one wherever it takes a ``Partition``; given a
+    plain ``Partition`` it builds one for that call (``resident``), whose
+    programs then live for that call only."""
+
+    def __init__(self, part: Partition):
+        with obs.span("engine.resident"):
+            self.part = part
+            self.arrays = DeviceArrays.from_partition(part)
+        self._planners: dict = {}
+        self._programs: dict = {}
+        obs.registry().counter(
+            "engine_resident_builds_total",
+            "device-resident partitions built (tables uploaded)").inc()
+
+    def planner(self, cfg: EngineConfig):
+        """The ``launch_planner`` of ``cfg``, built once."""
+        if cfg not in self._planners:
+            self._planners[cfg] = launch_planner(self.part, cfg)
+        return self._planners[cfg]
+
+    def round_program(self, run: str, step, sem, cfg: EngineConfig):
+        """The jitted ``_round_program`` of ``step`` under ``sem`` and
+        ``cfg``, called as ``fn(arrays, *state, worklist=wl)``."""
+        key = (run, step, sem, cfg)
+        if key not in self._programs:
+            self._programs[key] = jax.jit(
+                functools.partial(_round_program, run, step, sem, cfg))
+        return self._programs[key]
+
+
+def resident(part) -> ResidentPartition:
+    """``part`` if it is resident already, else a resident copy of it."""
+    if isinstance(part, ResidentPartition):
+        return part
+    return ResidentPartition(part)
+
+
+def partition_of(part) -> Partition:
+    """The plain ``Partition`` of ``part``, resident or not."""
+    return part.part if isinstance(part, ResidentPartition) else part
+
+
 def plan_round_worklist(planner, cfg: EngineConfig, gchg,
                         with_info: bool = False):
     """One round's launch decision for a host-driven loop: a ``Worklist``
@@ -311,8 +375,9 @@ def _obs_record_round(rec, run, part, cfg, planner, rnd, gchg, frontier,
 # fixpoint apps (BFS / SSSP)
 # --------------------------------------------------------------------------
 
-def run_stacked(sem: Semiring, part: Partition, init_val: np.ndarray,
-                cfg: EngineConfig = EngineConfig(), init_changed=None):
+def run_stacked(sem: Semiring, part: Partition | ResidentPartition,
+                init_val: np.ndarray, cfg: EngineConfig = EngineConfig(),
+                init_changed=None):
     """Single-device stacked execution. ``init_val``: (S, R_max) float32.
     ``init_changed`` (optional bool (S, R_max)) seeds the first frontier —
     used by incremental recompute to re-diffuse only mutation sites.
@@ -328,7 +393,8 @@ def run_stacked(sem: Semiring, part: Partition, init_val: np.ndarray,
             "run_stacked drives monotone min-semiring fixpoints; the "
             "collapse of a combined candidate is only sound there — use "
             "run_pagerank_stacked for counted sum-semiring rounds")
-    arrays = DeviceArrays.from_partition(part)
+    res = resident(part)
+    part, arrays = res.part, res.arrays
     S, R_max = part.S, part.R_max
     # an installed flight recorder also routes through the host-driven
     # loop (bit-identical values/stats for min semirings — the loop the
@@ -340,11 +406,10 @@ def run_stacked(sem: Semiring, part: Partition, init_val: np.ndarray,
     # frontier trajectory once per window instead of once per round
     if cfg.wants_device_worklist:
         if obs.get_recorder() is not None:
-            return _run_stacked_deviceloop(sem, part, arrays, cfg,
-                                           init_val, init_changed)
+            return _run_stacked_deviceloop(sem, res, cfg, init_val,
+                                           init_changed)
     elif cfg.wants_worklist or obs.get_recorder() is not None:
-        return _run_stacked_hostloop(sem, part, arrays, cfg, init_val,
-                                     init_changed)
+        return _run_stacked_hostloop(sem, res, cfg, init_val, init_changed)
 
     def body(carry):
         val, chg, it, stats = carry
@@ -446,30 +511,28 @@ def _host_stats(it, msgs, work, pruned):
                     diffusions=mk(work))
 
 
-def _run_stacked_hostloop(sem, part, arrays, cfg, init_val, init_changed):
+def _run_stacked_hostloop(sem, res, cfg, init_val, init_changed):
     """Worklist-mode fixpoint: the traced ``lax.while_loop`` becomes a
     Python loop so each round's frontier can plan its launch host-side.
-    One jitted round fn serves every round — jit retraces only when the
-    worklist's power-of-two length bucket changes (O(log cells) traces)
-    or a dense round passes ``worklist=None``.
+    One round program of the resident partition ``res`` serves every
+    round and every later call on ``res`` — jit traces again only for a
+    worklist power-of-two length bucket not seen before (O(log cells)
+    programs) or a dense round's ``worklist=None``.
 
     Also the flight-recorder path for ``grid_mode='dense'``: with a
     recorder installed each round appends a ``RoundRecord`` (frontier,
     messages, planner-mirror cells, path decision, wall time) —
     recorder-only host work, after the round's existing frontier
     download."""
-    S, R_max = part.S, part.R_max
+    part, arrays = res.part, res.arrays
     rec = obs.get_recorder()
-    planner = (launch_planner(part, cfg)
+    planner = (res.planner(cfg)
                if cfg.wants_worklist
                or (rec is not None and cfg.use_pallas
                    and cfg.pallas_mode == "fused")
                else None)
-
-    @jax.jit
-    def round_fn(val, chg, worklist):
-        return exchange.fixpoint_round_stacked(
-            sem, arrays, cfg, S, R_max, val, chg, worklist=worklist)
+    round_fn = res.round_program(sem.name, exchange.fixpoint_round_stacked,
+                                 sem, cfg)
 
     val = jnp.asarray(init_val)
     if init_changed is not None:
@@ -493,7 +556,7 @@ def _run_stacked_hostloop(sem, part, arrays, cfg, init_val, init_changed):
         with obs.span("engine.round", track=f"engine/{sem.name}",
                       round=it + 1) as rnd:
             with _dispatch(sem.name, it + 1):
-                val, chg, mc = round_fn(val, chg, wl)
+                val, chg, mc = round_fn(arrays, val, chg, worklist=wl)
             with _sync(sem.name, it + 1):
                 chg_h = np.asarray(chg)
                 mc = int(mc)
@@ -568,7 +631,7 @@ def _device_l_pad(planner) -> int:
                                planner.num_segments)
 
 
-def _run_stacked_deviceloop(sem, part, arrays, cfg, init_val, init_changed):
+def _run_stacked_deviceloop(sem, res, cfg, init_val, init_changed):
     """Recorder-visible device_worklist fixpoint: K-round windows
     (``cfg.device_window``), each ONE traced dispatch through
     ``exchange.fixpoint_window_stacked``.  The host sees the frontier
@@ -577,9 +640,10 @@ def _run_stacked_deviceloop(sem, part, arrays, cfg, init_val, init_changed):
     extra syncs inside the loop.  With no recorder installed
     ``run_stacked`` skips this loop entirely and runs the whole
     fixpoint as a single traced while_loop dispatch."""
+    part, arrays = res.part, res.arrays
     S, R_max = part.S, part.R_max
     rec = obs.get_recorder()
-    planner = launch_planner(part, cfg)
+    planner = res.planner(cfg)
     l_pad = _device_l_pad(planner)
 
     window_fns: dict = {}
@@ -638,11 +702,13 @@ def _run_stacked_deviceloop(sem, part, arrays, cfg, init_val, init_changed):
 # PageRank-style counted-iteration apps
 # --------------------------------------------------------------------------
 
-def run_pagerank_stacked(part: Partition, damping: float, iters: int,
+def run_pagerank_stacked(part: Partition | ResidentPartition,
+                         damping: float, iters: int,
                          cfg: EngineConfig = EngineConfig()):
     from repro.core.actions import PAGERANK as sem
 
-    arrays = DeviceArrays.from_partition(part)
+    res = resident(part)
+    part, arrays = res.part, res.arrays
     S, R_max = part.S, part.R_max
     base = (1.0 - damping) / part.n
 
@@ -651,7 +717,7 @@ def run_pagerank_stacked(part: Partition, damping: float, iters: int,
     chg = arrays.slot_valid  # PR predicate is #t — always diffuse
     # the frontier never changes, so a host-planned worklist is planned
     # once (from the host's copy of it) and reused by every traced round
-    wl = (plan_round_worklist(launch_planner(part, cfg), cfg,
+    wl = (plan_round_worklist(res.planner(cfg), cfg,
                               (np.asarray(part.slot_vertex) >= 0)
                               .reshape(-1))
           if cfg.wants_worklist else None)
@@ -684,8 +750,9 @@ def _tol_table(part: Partition, tol):
     return jnp.asarray(table, jnp.float32)
 
 
-def run_pagerank_delta(part: Partition, damping: float = 0.85,
-                       tol=1e-6, cfg: EngineConfig = EngineConfig(),
+def run_pagerank_delta(part: Partition | ResidentPartition,
+                       damping: float = 0.85, tol=1e-6,
+                       cfg: EngineConfig = EngineConfig(),
                        max_rounds: int = 256,
                        init_rank=None, init_delta=None):
     """Stacked **delta-PageRank**: push-based residual propagation with
@@ -700,7 +767,8 @@ def run_pagerank_delta(part: Partition, damping: float = 0.85,
     residuals bounds the rank error by O(tol / (1-d)) per vertex.
 
     Runs host-driven (the termination test and any worklist planning
-    need the frontier on host).  Returns ((S, R_max) ranks, RunStats
+    need the frontier on host); on a ``ResidentPartition`` a second call
+    traces and compiles nothing.  Returns ((S, R_max) ranks, RunStats
     with the Fig-6 accounting: messages delivered, slots whose residual
     stayed live (work), deliveries pruned below tolerance).
 
@@ -710,8 +778,8 @@ def run_pagerank_delta(part: Partition, damping: float = 0.85,
     affected region re-diffuses (frontier tests use ``|delta|``)."""
     from repro.core.actions import PAGERANK as sem
 
-    arrays = DeviceArrays.from_partition(part)
-    S, R_max = part.S, part.R_max
+    res = resident(part)
+    part, arrays = res.part, res.arrays
     base = (1.0 - damping) / part.n
     tol_t = _tol_table(part, tol)
     if init_rank is None:
@@ -721,20 +789,15 @@ def run_pagerank_delta(part: Partition, damping: float = 0.85,
         delta0 = jnp.asarray(init_delta, jnp.float32)
     if cfg.wants_device_worklist:
         return _run_pagerank_delta_deviceloop(
-            sem, part, arrays, cfg, damping, tol_t, rank0, delta0,
-            max_rounds)
+            sem, res, cfg, damping, tol_t, rank0, delta0, max_rounds)
     rec = obs.get_recorder()
-    planner = (launch_planner(part, cfg)
+    planner = (res.planner(cfg)
                if cfg.wants_worklist
                or (rec is not None and cfg.use_pallas
                    and cfg.pallas_mode == "fused")
                else None)
-
-    @jax.jit
-    def round_fn(rank, delta, worklist):
-        return exchange.delta_pagerank_round_stacked(
-            sem, arrays, cfg, S, R_max, damping, tol_t, rank, delta,
-            worklist=worklist)
+    round_fn = res.round_program(
+        "pagerank_delta", exchange.delta_pagerank_round_stacked, sem, cfg)
 
     rank, delta = rank0, delta0
     # each round returns next round's frontier — computed on device,
@@ -755,7 +818,8 @@ def run_pagerank_delta(part: Partition, damping: float = 0.85,
         with obs.span("engine.round", track="engine/pagerank_delta",
                       round=it + 1) as rnd:
             with _dispatch("pagerank_delta", it + 1):
-                rank, delta, chg, mc = round_fn(rank, delta, wl)
+                rank, delta, chg, mc = round_fn(arrays, damping, tol_t,
+                                                rank, delta, worklist=wl)
             with _sync("pagerank_delta", it + 1):
                 chg_h = np.asarray(chg)
                 mc = int(mc)
@@ -773,14 +837,15 @@ def run_pagerank_delta(part: Partition, damping: float = 0.85,
     return rank, _host_stats(it, msgs, work_total, pruned)
 
 
-def _run_pagerank_delta_deviceloop(sem, part, arrays, cfg, damping, tol_t,
-                                   rank0, delta0, max_rounds):
+def _run_pagerank_delta_deviceloop(sem, res, cfg, damping, tol_t, rank0,
+                                   delta0, max_rounds):
     """delta-PageRank under ``grid_mode='device_worklist'``: the
     residual-tolerance frontier test runs ON DEVICE, so with no flight
     recorder the whole fixpoint is ONE traced ``lax.while_loop``
     dispatch; with a recorder it runs in K-round windows
     (``cfg.device_window``) whose per-round accounting is recomputed
     post-hoc from the returned frontier trajectory."""
+    part, arrays = res.part, res.arrays
     S, R_max = part.S, part.R_max
     rec = obs.get_recorder()
     rank, delta = rank0, delta0
@@ -817,7 +882,7 @@ def _run_pagerank_delta_deviceloop(sem, part, arrays, cfg, damping, tol_t,
             counts = [int(x) for x in (it, msgs, work, pruned)]
         return rank, _host_stats(*counts)
 
-    planner = launch_planner(part, cfg)
+    planner = res.planner(cfg)
     l_pad = _device_l_pad(planner)
 
     window_fns: dict = {}
@@ -897,7 +962,8 @@ def make_sharded_pagerank_delta_fn(S: int, R_max: int, damping: float,
     return jax.jit(fn), NamedSharding(mesh, spec)
 
 
-def run_pagerank_delta_sharded(part: Partition, damping: float = 0.85,
+def run_pagerank_delta_sharded(part: Partition | ResidentPartition,
+                               damping: float = 0.85,
                                tol: float = 1e-6, mesh: Mesh = None,
                                axis_names=("data", "model"),
                                cfg: EngineConfig = EngineConfig(),
@@ -908,10 +974,12 @@ def run_pagerank_delta_sharded(part: Partition, damping: float = 0.85,
     a per-vertex table would need its own sharded layout."""
     if np.ndim(tol) != 0:
         raise ValueError("run_pagerank_delta_sharded takes a scalar tol")
+    res = resident(part)
+    part = res.part
     fn, sharding = make_sharded_pagerank_delta_fn(
         part.S, part.R_max, damping, float(tol), mesh, axis_names, cfg)
-    arrays = DeviceArrays.from_partition(part)
-    arrays_dev = jax.tree.map(lambda x: jax.device_put(x, sharding), arrays)
+    arrays_dev = jax.tree.map(lambda x: jax.device_put(x, sharding),
+                              res.arrays)
     slot_valid = np.asarray(part.slot_vertex) >= 0
     base = (1.0 - damping) / part.n
     if init_rank is None:
@@ -1062,15 +1130,17 @@ def make_sharded_fn(sem: Semiring, S: int, R_max: int,
     return jax.jit(fn), NamedSharding(mesh, spec)
 
 
-def run_sharded(sem: Semiring, part: Partition, init_val: np.ndarray,
-                mesh: Mesh, axis_names=("data", "model"),
+def run_sharded(sem: Semiring, part: Partition | ResidentPartition,
+                init_val: np.ndarray, mesh: Mesh,
+                axis_names=("data", "model"),
                 cfg: EngineConfig = EngineConfig()):
     """shard_map execution. Leading (shard) dim of every array is split over
     ``axis_names``; requires prod(mesh[axis_names]) == part.S."""
+    res = resident(part)
     fn, sharding = make_sharded_fn(
-        sem, part.S, part.R_max, mesh, axis_names, cfg)
-    arrays = DeviceArrays.from_partition(part)
-    arrays_dev = jax.tree.map(lambda x: jax.device_put(x, sharding), arrays)
+        sem, res.part.S, res.part.R_max, mesh, axis_names, cfg)
+    arrays_dev = jax.tree.map(lambda x: jax.device_put(x, sharding),
+                              res.arrays)
     val_dev = jax.device_put(jnp.asarray(init_val), sharding)
     # ONE dispatch; the caller's read of the results (``vertex_values``)
     # is its single host sync
@@ -1121,14 +1191,17 @@ def make_sharded_pagerank_fn(S: int, R_max: int, n: int, damping: float,
     return jax.jit(fn), NamedSharding(mesh, spec)
 
 
-def run_pagerank_sharded(part: Partition, damping: float, iters: int,
+def run_pagerank_sharded(part: Partition | ResidentPartition,
+                         damping: float, iters: int,
                          mesh: Mesh, axis_names=("data", "model"),
                          cfg: EngineConfig = EngineConfig()):
     """shard_map PageRank execution; see ``run_sharded`` for layout."""
+    res = resident(part)
+    part = res.part
     fn, sharding = make_sharded_pagerank_fn(
         part.S, part.R_max, part.n, damping, iters, mesh, axis_names, cfg)
-    arrays = DeviceArrays.from_partition(part)
-    arrays_dev = jax.tree.map(lambda x: jax.device_put(x, sharding), arrays)
+    arrays_dev = jax.tree.map(lambda x: jax.device_put(x, sharding),
+                              res.arrays)
     with _dispatch("pagerank_sharded", 1):
         return fn(arrays_dev)
 
@@ -1137,9 +1210,11 @@ def run_pagerank_sharded(part: Partition, damping: float, iters: int,
 # helpers
 # --------------------------------------------------------------------------
 
-def init_values(part: Partition, sem: Semiring, sources: dict[int, float]):
+def init_values(part: Partition | ResidentPartition, sem: Semiring,
+                sources: dict[int, float]):
     """(S, R_max) initial values: semiring identity everywhere except all
     replicas of each source vertex (consistent initial view)."""
+    part = partition_of(part)
     val = np.full((part.S, part.R_max), sem.identity, dtype=np.float32)
     if sem.segment == "sum":
         val[:] = 0.0
@@ -1152,9 +1227,10 @@ def init_values(part: Partition, sem: Semiring, sources: dict[int, float]):
     return val
 
 
-def vertex_values(part: Partition, val) -> np.ndarray:
+def vertex_values(part: Partition | ResidentPartition, val) -> np.ndarray:
     """Extract the per-vertex (root-replica) values; reading a device
     array back is an ``engine.sync``."""
+    part = partition_of(part)
     if isinstance(val, jax.Array):
         with _sync("vertex_values", 0):
             val = np.asarray(val)

@@ -71,6 +71,29 @@ def pagerank(g: COOGraph, damping: float = 0.85, iters: int = 30) -> np.ndarray:
     return score
 
 
+def pagerank_delta(g: COOGraph, damping: float = 0.85, tol: float = 1e-7,
+                   max_rounds: int = 256) -> tuple[np.ndarray, int]:
+    """Push delta-PageRank in float64: rank = delta = (1-d)/n; each round
+    the frontier ``|delta| > tol`` pushes ``d * delta / out_degree`` along
+    its out-edges, residuals at or below ``tol`` are dropped, and
+    dangling vertices' mass is dropped (Listing 10).  Returns (ranks,
+    rounds run)."""
+    out_deg = g.out_degrees().astype(np.float64)
+    w = 1.0 / np.maximum(out_deg, 1)[g.src]
+    rank = np.full(g.n, (1.0 - damping) / g.n)
+    delta = rank.copy()
+    rounds = 0
+    while rounds < max_rounds:
+        live = np.abs(delta) > tol
+        if not live.any():
+            break
+        push = np.where(live[g.src], delta[g.src] * w, 0.0)
+        delta = damping * np.bincount(g.dst, weights=push, minlength=g.n)
+        rank += delta
+        rounds += 1
+    return rank, rounds
+
+
 def connected_components(g: COOGraph) -> np.ndarray:
     """Weakly connected components: per-vertex label = min vertex id in
     the component (edges treated as undirected). Plain numpy BFS."""

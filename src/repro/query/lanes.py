@@ -122,7 +122,7 @@ def _lane_round_stacked(sem, arrays, cfg, S, R_max, lane_unitw, val, chg):
 # stacked laned fixpoint runner (BFS / SSSP / reachability / CC lanes)
 # --------------------------------------------------------------------------
 
-def make_stacked_lanes_fn(part: Partition,
+def make_stacked_lanes_fn(part: Partition | engine.ResidentPartition,
                           cfg: EngineConfig = EngineConfig(),
                           sem: Semiring = actions.SSSP):
     """Builds the stacked laned fixpoint as a jitted fn of ((S, R_max, Q)
@@ -139,7 +139,8 @@ def make_stacked_lanes_fn(part: Partition,
     query cannot pin the shared fixpoint past its budget."""
     _check_cfg(cfg)
     _check_min(sem)
-    arrays = DeviceArrays.from_partition(part)
+    res = engine.resident(part)
+    part, arrays = res.part, res.arrays
     S, R_max = part.S, part.R_max
     vol = _volume(part, cfg)
 
@@ -184,26 +185,23 @@ def make_stacked_lanes_fn(part: Partition,
     return fn
 
 
-def _run_stacked_lanes_hostloop(part, arrays, cfg, sem, init_val,
-                                lane_unitw, init_chg, lane_budget=None):
+def _run_stacked_lanes_hostloop(res, cfg, sem, init_val, lane_unitw,
+                                init_chg, lane_budget=None):
     """Worklist-mode laned fixpoint: a Python round loop so the
     OR-across-lanes frontier can plan each round's sparse launch —
     identical values and LaneStats to the traced ``while_loop``
     (min lanes are bit-identical).  ``lane_budget`` freezes a lane after
     its budgeted round count, and the worklist planner sees the frozen
-    lane as dead (its cells stop launching)."""
-    S, R_max = part.S, part.R_max
+    lane as dead (its cells stop launching).  The round program is the
+    resident partition's (``engine.ResidentPartition.round_program``),
+    so a later call on ``res`` compiles nothing."""
     q = init_val.shape[-1]
-    planner = engine.launch_planner(part, cfg)
-    vol = _volume(part, cfg)
+    planner = res.planner(cfg)
+    vol = _volume(res.part, cfg)
     budget = (None if lane_budget is None
               else np.asarray(lane_budget, np.int64).reshape(q))
-
-    @jax.jit
-    def round_fn(val, chg, worklist, lane_mask=None):
-        return exchange.fixpoint_round_stacked(
-            sem, arrays, cfg, S, R_max, val, chg, lane_unitw=lane_unitw,
-            worklist=worklist, lane_mask=lane_mask)
+    round_fn = res.round_program("lanes_min", exchange.fixpoint_round_stacked,
+                                 sem, cfg)
 
     val, chg = init_val, init_chg
     chg_h = np.asarray(chg).reshape(-1, q)   # ONE download per round
@@ -220,8 +218,8 @@ def _run_stacked_lanes_hostloop(part, arrays, cfg, sem, init_val,
             break
         wl = engine.plan_round_worklist(planner, cfg, eff_chg.any(axis=1))
         val, chg, counts = round_fn(
-            val, chg, wl,
-            None if mask is None else jnp.asarray(mask))
+            res.arrays, val, chg, lane_unitw=lane_unitw, worklist=wl,
+            lane_mask=None if mask is None else jnp.asarray(mask))
         chg_h = np.asarray(chg).reshape(-1, q)
         rounds += live
         messages += np.asarray(counts, np.int64)
@@ -234,7 +232,8 @@ def _run_stacked_lanes_hostloop(part, arrays, cfg, sem, init_val,
     return val, stats
 
 
-def run_stacked_lanes(part: Partition, init_val, lane_unitw=None,
+def run_stacked_lanes(part: Partition | engine.ResidentPartition, init_val,
+                      lane_unitw=None,
                       cfg: EngineConfig = EngineConfig(),
                       init_changed=None, sem: Semiring = actions.SSSP,
                       lane_budget=None):
@@ -265,7 +264,8 @@ def run_stacked_lanes(part: Partition, init_val, lane_unitw=None,
     if lane_budget is not None:
         lane_budget = jnp.broadcast_to(
             jnp.asarray(lane_budget, jnp.int32), (q,))
-    slot_valid = jnp.asarray(part.slot_vertex >= 0)
+    res = engine.resident(part)
+    slot_valid = res.arrays.slot_valid
     if init_changed is not None:
         init_chg = jnp.asarray(init_changed) & slot_valid[..., None]
     else:
@@ -275,11 +275,9 @@ def run_stacked_lanes(part: Partition, init_val, lane_unitw=None,
     if cfg.wants_worklist:
         _check_cfg(cfg)
         _check_min(sem)
-        arrays = DeviceArrays.from_partition(part)
         return _run_stacked_lanes_hostloop(
-            part, arrays, cfg, sem, init_val, lane_unitw, init_chg,
-            lane_budget)
-    fn = make_stacked_lanes_fn(part, cfg, sem)
+            res, cfg, sem, init_val, lane_unitw, init_chg, lane_budget)
+    fn = make_stacked_lanes_fn(res, cfg, sem)
     out = fn(init_val, lane_unitw, init_chg, lane_budget)
     # the traced while_loop (dense grid or device-compacted worklist)
     # was ONE dispatch with one result sync

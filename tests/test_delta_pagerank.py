@@ -202,3 +202,100 @@ def test_ppr_delta_lanes_prune_vs_full_rounds():
                                       tol=1e-8, max_rounds=200)
     assert (np.asarray(st_delta.messages)
             < np.asarray(st_full.messages)).all()
+
+
+# --------------------------------------------------------------------------
+# the resident partition: tables uploaded once, round programs compiled once
+# --------------------------------------------------------------------------
+
+RESIDENT_GRAPHS = {
+    "rmat9": lambda: generators.rmat(9, edge_factor=8, seed=11),
+    "er9": lambda: generators.erdos_renyi(512, avg_degree=8.0, seed=12),
+}
+
+
+def _resident(g, grid_mode):
+    part = build_partition(_pr_graph(g),
+                           PartitionConfig(num_shards=4, rpvo_max=4))
+    cfg = engine.EngineConfig(use_pallas=True, grid_mode=grid_mode)
+    return engine.ResidentPartition(part), cfg
+
+
+def _tol(g, rel=1e-5, damping=0.85):
+    return rel * (1.0 - damping) / g.n
+
+
+def _round_programs(run):
+    from repro import obs
+    return obs.registry().counter(
+        "engine_round_programs_total").labels(run=run).value
+
+
+@pytest.fixture
+def backend_compiles():
+    """Every backend compile JAX reports while the test runs."""
+    seen = []
+
+    def listen(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(duration)
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    yield seen
+    jax.monitoring.unregister_event_duration_listener(listen)
+
+
+@pytest.mark.parametrize("grid_mode", ["worklist", "dense"])
+@pytest.mark.parametrize("graph", list(RESIDENT_GRAPHS))
+def test_resident_delta_matches_float64_reference(graph, grid_mode):
+    g = RESIDENT_GRAPHS[graph]()
+    res, cfg = _resident(g, grid_mode)
+    want, rounds = reference.pagerank_delta(g, 0.85, _tol(g))
+    got, stats, out = pagerank_delta(g, tol=_tol(g), part=res, cfg=cfg)
+    assert out is res
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-7)
+    assert abs(int(stats.iterations) - rounds) <= 1, (stats, rounds)
+
+
+@pytest.mark.parametrize("grid_mode", ["worklist", "dense"])
+def test_second_delta_call_on_resident_compiles_nothing(grid_mode,
+                                                        backend_compiles):
+    g = RESIDENT_GRAPHS["rmat9"]()
+    res, cfg = _resident(g, grid_mode)
+    before = _round_programs("pagerank_delta")
+    first, st1, _ = pagerank_delta(g, tol=_tol(g), part=res, cfg=cfg)
+    traced = _round_programs("pagerank_delta")
+    assert traced > before and backend_compiles
+    compiles = len(backend_compiles)
+    second, st2, _ = pagerank_delta(g, tol=_tol(g), part=res, cfg=cfg)
+    assert _round_programs("pagerank_delta") == traced
+    assert len(backend_compiles) == compiles
+    np.testing.assert_array_equal(second, first)
+    assert tuple(map(int, st2)) == tuple(map(int, st1))
+
+
+def test_plain_and_resident_partitions_agree_bit_for_bit():
+    g = RESIDENT_GRAPHS["er9"]()
+    res, cfg = _resident(g, "worklist")
+    plain, st_plain, _ = pagerank_delta(g, tol=_tol(g), part=res.part,
+                                        cfg=cfg)
+    kept, st_kept, _ = pagerank_delta(g, tol=_tol(g), part=res, cfg=cfg)
+    np.testing.assert_array_equal(kept, plain)
+    assert tuple(map(int, st_kept)) == tuple(map(int, st_plain))
+
+
+def test_second_bfs_call_on_resident_compiles_nothing(backend_compiles):
+    from repro import apps
+    g = generators.rmat(9, edge_factor=8, seed=5)
+    part = build_partition(g, PartitionConfig(num_shards=4, rpvo_max=4))
+    res = engine.ResidentPartition(part)
+    cfg = engine.EngineConfig(use_pallas=True, grid_mode="worklist")
+    root = int(np.argmax(g.out_degrees()))
+    first, st1, _ = apps.bfs(g, root, part=res, cfg=cfg)
+    traced = _round_programs("bfs")
+    compiles = len(backend_compiles)
+    second, st2, _ = apps.bfs(g, root, part=res, cfg=cfg)
+    assert _round_programs("bfs") == traced
+    assert len(backend_compiles) == compiles
+    np.testing.assert_array_equal(second, first)
+    np.testing.assert_array_equal(first, reference.bfs_levels(g, root))
+    assert int(st2.iterations) == int(st1.iterations)

@@ -239,3 +239,29 @@ def test_stacked_round_compiles(one_chip, mosaic, lanes):
                           s((WL_CELLS,), jnp.int32), s((WL_CELLS,), jnp.int32),
                           s((1,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def test_delta_pagerank_round_compiles(one_chip, mosaic):
+    """The host loop's delta-PageRank round program
+    (``engine._round_program``: tables, damping, tolerance, residuals and
+    worklist all arguments) at the one-chip smoke's shapes and largest
+    worklist."""
+    from repro import exchange
+    arrays, S, R_max = _stacked_arrays(one_chip)
+    cfg = engine.EngineConfig(use_pallas=True, grid_mode="worklist")
+
+    def s(shp, dt):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    def fn(ar, damping, tol, rank, delta, wl_i, wl_j, nlive):
+        return engine._round_program(
+            "pagerank_delta", exchange.delta_pagerank_round_stacked,
+            actions.PAGERANK, cfg, ar, damping, tol, rank, delta,
+            worklist=Worklist(wl_i, wl_j, nlive))
+
+    text = _compiled_text(fn, arrays, s((), jnp.float32), s((), jnp.float32),
+                          s((S, R_max), jnp.float32),
+                          s((S, R_max), jnp.float32),
+                          s((WL_CELLS,), jnp.int32), s((WL_CELLS,), jnp.int32),
+                          s((1,), jnp.int32))
+    assert "tpu_custom_call" in text
